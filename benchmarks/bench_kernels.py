@@ -92,14 +92,16 @@ def run_compiled(rng, q_n: int = 512, n: int = 8192, p: int = 256,
                  m: int = 256, g: int = 256, kc: int = 1024, j: int = 512):
     """Time the *compiled* fused query-pipeline stages at realistic shapes.
 
-    On a TPU backend the Pallas kernels lower through Mosaic and are
+    On a TPU backend the rerank kernel lowers through Mosaic and is
     timed as such (``path: mosaic``); elsewhere the timed program is the
     jitted XLA twin that the fused pipeline actually dispatches off-TPU
-    (``path: xla``).  Either way the rows record what ``query_mode=
-    "fused"`` runs on this host, not an interpret-mode proxy.
+    (``path: xla``).  The scan is the XLA twin on every backend — Mosaic
+    cannot lower the select kernel's in-kernel sort.  Either way the rows
+    record what ``query_mode="fused"`` runs on this host, not an
+    interpret-mode proxy.
     """
     from repro.kernels.rerank import rerank_scores_xla
-    from repro.kernels.select import fused_scan_topm, scan_topm_xla
+    from repro.kernels.select import scan_topm_xla
 
     on_tpu = jax.default_backend() == "tpu"
     path = "mosaic" if on_tpu else "xla"
@@ -108,12 +110,10 @@ def run_compiled(rng, q_n: int = 512, n: int = 8192, p: int = 256,
     q = jnp.asarray(rng.normal(size=(q_n, p)).astype(np.float32))
     prox = jnp.asarray(rng.normal(size=(n, p)).astype(np.float32))
     q_ids = jnp.asarray(np.arange(q_n, dtype=np.int32))
-    scan = ((lambda: fused_scan_topm(q, prox, q_ids, m=m, interpret=False))
-            if on_tpu else
-            (lambda: scan_topm_xla(q, prox, q_ids, m=m)))
     rows.append({"name": f"compiled_scan_{q_n}x{n}_m{m}",
-                 "us_per_call": _time(scan),
-                 "path": path,
+                 "us_per_call": _time(
+                     lambda: scan_topm_xla(q, prox, q_ids, m=m)),
+                 "path": "xla",
                  "derived": f"flops={2 * q_n * n * p:.0f}"})
 
     vq = (rng.integers(1, 6, (g, j))

@@ -21,7 +21,7 @@ REPO = Path(__file__).resolve().parents[1]
 
 _CODE = """
     import time, numpy as np, jax, jax.numpy as jnp
-    from repro.core.engine import cpu_mesh, sharded_topk
+    from repro.core.engine import local_mesh, sharded_topk
     from repro.core.neighbors import topk_neighbors
     from repro.data import load_ml1m_synthetic
     n = {n_shards}
@@ -30,7 +30,7 @@ _CODE = """
     if n == 1:
         fit = lambda: topk_neighbors(r, 20, measure="pcc", block_size=256)
     else:
-        mesh = cpu_mesh(n)
+        mesh = local_mesh(n)
         fit = lambda: sharded_topk(r, 20, mesh, measure="pcc",
                                    block_size=256)
     s, i = fit()                                   # compile + warm

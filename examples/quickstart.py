@@ -7,9 +7,11 @@ import jax.numpy as jnp
 
 from repro.core import CFConfig, UserCF
 from repro.data import load_ml1m_synthetic
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # synthetic MovieLens-1M surrogate (offline container), 90/10 split
     train, test, spec = load_ml1m_synthetic(n_users=1024, n_items=768)
     tr, te = jnp.asarray(train), jnp.asarray(test)

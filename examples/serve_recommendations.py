@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core import CFEngine
 from repro.data import load_ml1m_synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import BatchingServer
 
 
@@ -53,6 +54,7 @@ def main():
                     help="approx mode: index query pipeline (auto picks "
                          "fused where the Pallas kernels run)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     train, _, _ = load_ml1m_synthetic(n_users=1024, n_items=512)
     index_cfg = None

@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import CFConfig, UserCF
-from repro.core.engine import cpu_mesh
+from repro.core.engine import local_mesh
 from repro.data import load_ml1m_synthetic
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -28,9 +29,10 @@ def main():
     ap.add_argument("--items", type=int, default=1024)
     ap.add_argument("--topn", type=int, nargs="+", default=[10, 20, 40])
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
-    mesh = cpu_mesh(n_dev) if args.engine != "sequential" else None
+    mesh = local_mesh(n_dev) if args.engine != "sequential" else None
     print(f"devices={n_dev} engine={args.engine}")
 
     train, test, _ = load_ml1m_synthetic(n_users=args.users,

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.data import lm_batch
 from repro.distributed.fault_tolerance import FaultInjector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tx
 from repro.training.optimizer import adamw
 from repro.training.train_loop import TrainLoopConfig, make_train_step, run
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
     ap.add_argument("--inject-fault-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_config()
     params = tx.init_params(cfg, jax.random.PRNGKey(0))

@@ -19,7 +19,7 @@ to closed jaxprs with tiny example inputs and walks the equations:
   dtypes, the provenance chain back to the origin argument, and the
   user-code line from the eqn's source info.
 
-Sub-jaxprs (``pjit``/``scan``/``cond``/custom-call wrappers) are walked
+Sub-jaxprs (``jit``/``scan``/``cond``/custom-call wrappers) are walked
 recursively so provenance crosses inlined jit boundaries; anything that
 cannot be mapped through (e.g. a ``pallas_call``'s ref-typed kernel
 jaxpr) falls back to the boundary rule — a narrow operand entering an
@@ -141,14 +141,14 @@ def _eqn_line(eqn) -> Tuple[str, int]:
     return "", 0
 
 
-_SUBJAXPR_1TO1 = {"pjit", "closed_call", "core_call", "remat", "remat2",
+_SUBJAXPR_1TO1 = {"jit", "closed_call", "core_call", "remat", "remat2",
                   "checkpoint", "custom_jvp_call", "custom_vjp_call",
                   "custom_jvp_call_jaxpr", "scan"}
 
 
 def _sub_jaxprs(eqn):
     """(closed_or_raw_jaxpr, invar_offset) candidates for recursion."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     ClosedJaxpr = jcore.ClosedJaxpr
     name = eqn.primitive.name
     out = []
@@ -168,7 +168,7 @@ def _sub_jaxprs(eqn):
 def _walk_jaxpr(jaxpr, prov: Dict[object, _Prov], hot_path: str,
                 path: str, out: List[Widening],
                 seen: Dict[str, Widening]) -> None:
-    import jax.core as jcore
+    import jax.extend.core as jcore
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         narrow_ins = []
@@ -292,7 +292,7 @@ def _build_fused_scan_pool():
         q_ids = jnp.asarray([0, 3], jnp.int32)
         return (proxies, q_ids)
 
-    call = functools.partial(fn, m=3, use_pallas=False, interpret=False)
+    call = functools.partial(fn, m=3)
     return fn, call, make_args, ("proxies", "q_ids")
 
 
@@ -308,7 +308,7 @@ def _build_fused_scan_restricted():
         q_ids = jnp.asarray([0, 3], jnp.int32)
         return (proxies, cand_pad, q_ids)
 
-    call = functools.partial(fn, m=3, use_pallas=False, interpret=False)
+    call = functools.partial(fn, m=3)
     return fn, call, make_args, ("proxies", "cand_pad", "q_ids")
 
 
@@ -387,8 +387,8 @@ def _build_fused_support_scores():
 
     def make_args():
         rng = np.random.default_rng(4)
-        dev = jnp.asarray(rng.normal(size=(8, 6)), jnp.float32)
-        msk = jnp.asarray((rng.random((8, 6)) > 0.5), jnp.float32)
+        dev = jnp.asarray(rng.normal(size=(8, 1, 8)), jnp.float32)
+        msk = jnp.asarray((rng.random((8, 1, 8)) > 0.5), jnp.float32)
         nb_idx = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
         nb_w = jnp.asarray([[0.5, 0.5], [1.0, 0.0]], jnp.float32)
         q_means = jnp.asarray([3.0, 2.5], jnp.float32)

@@ -59,7 +59,7 @@ def _on_duration(event, duration, **kw):
 
 def _install_listener() -> bool:
     """Install the module-level jax.monitoring listener exactly once
-    (there is no unregister in jax 0.4.x).  Returns availability."""
+    (``jax.monitoring`` has no public unregister).  Returns availability."""
     global _listener_installed
     if _listener_installed:
         return True

@@ -1,69 +1,17 @@
-"""JAX API compatibility layer.
+"""Thin wrappers over the installed JAX (0.9) where call sites gain from one.
 
-The repo targets the moving edge of JAX while CI / the dev container pin
-jax 0.4.37.  Three API families drifted between 0.4.x and ≥0.5:
-
-* ``jax.shard_map``            — 0.4.x only has
-  ``jax.experimental.shard_map.shard_map`` whose replication-check kwarg is
-  spelled ``check_rep`` instead of ``check_vma``.
-* ``jax.sharding.AxisType``    — absent on 0.4.x; ``jax.make_mesh`` there
-  does not accept ``axis_types``.
-* ``pltpu.CompilerParams``     — spelled ``TPUCompilerParams`` on 0.4.x.
-
-Everything in the repo that needs one of these goes through this module, so
-a version bump means updating exactly one file.  Supported versions are
-documented in README.md ("Engine API & JAX compatibility policy").
+``jax.make_mesh`` defaults every axis to ``AxisType.Explicit``; the engines
+here shard through ``jax.shard_map`` and jit and want Auto axes, so every
+mesh in the repo is built by :func:`make_mesh`.
 """
 
 from __future__ import annotations
 
 import jax
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-# ``jax.sharding.AxisType.Auto`` where it exists, else None (0.4.x meshes
-# are implicitly fully-auto, so dropping the kwarg is semantics-preserving).
-AXIS_TYPE_AUTO = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-
-
-if hasattr(jax, "shard_map"):            # jax ≥ 0.5
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:                                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        return _shard_map_04x(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(name):
-    """``jax.lax.axis_size`` (≥0.5); the classic psum-of-ones idiom on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis Auto, on any supported version."""
-    if AXIS_TYPE_AUTO is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names, devices=devices,
-                axis_types=(AXIS_TYPE_AUTO,) * len(axis_names))
-        except TypeError:                # signature drift safety net
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def pallas_tpu_compiler_params(*, dimension_semantics):
-    """``pltpu.CompilerParams`` (≥0.5) / ``pltpu.TPUCompilerParams`` (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

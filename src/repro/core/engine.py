@@ -1,7 +1,7 @@
 """The paper's multi-threaded engine, recast as mesh-sharded SPMD.
 
 The paper partitions query users across OS threads.  Here the partition is
-across mesh devices via ``compat.shard_map``; two engines are provided:
+across mesh devices via ``jax.shard_map``; two engines are provided:
 
 * ``sharded_topk``      — query users shard over an axis, every device holds
                           the full candidate rating matrix (the direct
@@ -62,7 +62,7 @@ def sharded_topk(ratings: jnp.ndarray, k: int, mesh: Mesh, *,
         return _block_topk_local(q_block, all_ratings, k, measure,
                                  i * shard, 0, n_users, block_size, beta)
 
-    f = compat.shard_map(per_shard, mesh=mesh,
+    f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None), P(None, None)),
                       out_specs=(P(axis, None), P(axis, None)),
                       check_vma=False)
@@ -107,7 +107,7 @@ def ring_sharded_topk(ratings: jnp.ndarray, k: int, mesh: Mesh, *,
             body, init, jnp.arange(axis_size))
         return best_s, best_i
 
-    f = compat.shard_map(per_shard, mesh=mesh,
+    f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None),),
                       out_specs=(P(axis, None), P(axis, None)),
                       check_vma=False)
@@ -127,7 +127,7 @@ def sharded_predict(ratings: jnp.ndarray, scores: jnp.ndarray,
         return pred_mod.predict_from_neighbors(
             all_ratings, scores_blk, idx_blk, means=all_means, query_means=qm)
 
-    f = compat.shard_map(per_shard, mesh=mesh,
+    f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None), P(axis, None),
                                 P(None, None), P(None)),
                       out_specs=P(axis, None), check_vma=False)
@@ -195,14 +195,15 @@ def ring_sharded_predict(ratings: jnp.ndarray, scores: jnp.ndarray,
         pred = jnp.where(den > 1e-8, pred, my_means[:, None])
         return jnp.clip(pred, 1.0, 5.0)
 
-    f = compat.shard_map(per_shard, mesh=mesh,
+    f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None), P(axis, None), P(axis, None)),
                       out_specs=P(axis, None), check_vma=False)
     return f(ratings, scores, idx)
 
 
 @functools.lru_cache(maxsize=None)
-def cpu_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
-    """Utility mesh over however many (possibly fake) local devices exist."""
+def local_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
+    """1-axis mesh over ``n_devices`` local devices (default: all) — the
+    chips of a TPU host, or fake CPU devices in tests."""
     n = n_devices or len(jax.devices())
     return compat.make_mesh((n,), (axis,))

@@ -200,7 +200,7 @@ class CFEngine:
     ----------
     ratings : (U, I) dense rating matrix, 0 = unrated.
     backend : one of ``BACKENDS``; ``sharded``/``ring`` need ``mesh`` (or use
-        ``cpu_mesh()`` over all local devices when none is given).
+        ``local_mesh()`` over all local devices when none is given).
     neighbor_mode : ``"exact"`` (default) computes true all-pairs top-k with
         the selected backend; ``"approx"`` fits a
         :class:`repro.index.ClusteredIndex` and fills the neighbor cache
@@ -269,7 +269,7 @@ class CFEngine:
         self.axis = axis
         self.block_size = int(block_size)
         if backend in ("sharded", "ring") and mesh is None:
-            mesh = dist_engine.cpu_mesh()
+            mesh = dist_engine.local_mesh()
         self.mesh = mesh
         if interpret is None:
             interpret = jax.default_backend() != "tpu"

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -83,7 +82,7 @@ def sharded_deviation(ratings: jnp.ndarray, mesh: Mesh, *,
         dev = (sum_i - sum_j) / jnp.maximum(counts, 1.0)
         return dev, counts
 
-    f = compat.shard_map(per_shard, mesh=mesh,
+    f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None), P(None, None)),
                       out_specs=(P(axis, None), P(axis, None)),
                       check_vma=False)

@@ -184,10 +184,12 @@ class IndexConfig:
     #               its probed clusters' member proxies through padded
     #               per-cluster tables (no per-block set algebra over
     #               member lists, no full-pool score matrix);
-    #   "kernel"  — accelerator path: the fused Pallas blockwise-select
-    #               kernel (kernels/select.py) scans the full pool and
-    #               selects top-M on device — scores never round-trip to
-    #               the host (the exact lax.top_k twin off-TPU);
+    #   "kernel"  — accelerator path: one device proxy GEMM over the
+    #               full pool and an on-device top-M — scores never
+    #               round-trip to the host.  The top-M is the exact
+    #               lax.top_k twin on every backend: Mosaic cannot lower
+    #               the Pallas select kernel's in-kernel sort
+    #               (kernels/select.py; QueryStats.select_mode);
     #   "auto"    — kernel where the fused kernels run (TPU), else by
     #               probe fraction: pool when n_probe·spill ≥ n_clusters
     #               (the probed union provably saturates), cluster below.
@@ -258,6 +260,11 @@ class QueryStats:
     scan_mode: str = ""              # resolved shortlist scan mode
     query_mode: str = ""             # resolved orchestration
                                      # ("staged" | "fused")
+    select_mode: str = ""            # shortlist top-M selection: "top_k"
+                                     # (device lax.top_k twin: the kernel
+                                     # scan and the fused chain) or
+                                     # "host" (staged host scans); ""
+                                     # when no scan stage exists
     scan_gate: str = ""              # resolved symmetric-scan gate:
                                      # "sym:on:level=…" when it ran,
                                      # "sym:off:<reason>" when another
@@ -672,35 +679,32 @@ def _rerank_shared(ratings, q_ids, cand_ids, allowed, *, k, measure,
 
 # -- fused query pipeline (device-resident stage chain) -----------------------
 
-@functools.partial(jax.jit, static_argnames=("m", "use_pallas", "interpret"))
-def _fused_scan_pool(proxies, q_ids, *, m, use_pallas, interpret):
+@functools.partial(jax.jit, static_argnames=("m",))
+def _fused_scan_pool(proxies, q_ids, *, m):
     """Device full-pool proxy scan of one query block.
 
     (Q,) padded global query ids → canonical top-``m`` ``(values,
     global shortlist ids)`` with the sentinel id ``U`` on every ``-inf``
-    slot.  The Pallas blockwise-select kernel where it runs, the exact
-    ``lax.top_k`` twin elsewhere — both the same selection the staged
-    kernel scan dispatches, so the fused path's shortlists are
-    bit-identical to the staged ones.  Padded query rows (id ``U``)
-    score garbage and are sliced off by the caller; proxy scores never
-    leave the device.
+    slot.  Selection is the exact ``lax.top_k`` twin on every backend
+    (Mosaic cannot lower the Pallas select kernel's in-kernel sort),
+    pinned bit-identical to ``ref.scan_topm_ref``.  The staged kernel
+    scan dispatches this same
+    function, so the fused path's shortlists are bit-identical to the
+    staged ones.  Padded query rows (id ``U``) score garbage and are
+    sliced off by the caller; proxy scores never leave the device.
     """
     n = proxies.shape[0]
     q = proxies[jnp.minimum(q_ids, n - 1)]
-    if use_pallas:
-        return sel_mod.fused_scan_topm(q, proxies, q_ids, m=m,
-                                       interpret=interpret)
     return sel_mod.scan_topm_xla(q, proxies, q_ids, m=m)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "use_pallas", "interpret"))
-def _fused_scan_restricted(proxies, cand_pad, q_ids, *, m, use_pallas,
-                           interpret):
+@functools.partial(jax.jit, static_argnames=("m",))
+def _fused_scan_restricted(proxies, cand_pad, q_ids, *, m):
     """Device cluster-restricted proxy scan of one query block.
 
     ``cand_pad``: (L,) *ascending* dup-free candidate ids out of the
     block's probed member-table union (padding ``U``) — ascending so the
-    block-local tie-break of both select paths is the canonical global-id
+    block-local tie-break of ``lax.top_k`` is the canonical global-id
     order.  Scores the block against the gathered candidate proxies, maps
     the block-local selection back to global ids on device, and returns
     ``(values, global shortlist ids)`` under the same sentinel contract
@@ -713,13 +717,8 @@ def _fused_scan_restricted(proxies, cand_pad, q_ids, *, m, use_pallas,
     sp = jnp.matmul(q, cp.T, precision=jax.lax.Precision.HIGHEST)
     invalid = (cand_pad[None, :] >= n) | (cand_pad[None, :] == q_ids[:, None])
     sp = jnp.where(invalid, -jnp.inf, sp)
-    if use_pallas:
-        v, sel = sel_mod.select_topm(
-            sp, jnp.full(q_ids.shape, -1, jnp.int32), m=m,
-            interpret=interpret)
-    else:
-        # reprolint: disable=canonical-selection -- exact lax.top_k twin of kernels/select.py: XLA ties break toward the lower index, same canonical (-score, id) order
-        v, sel = jax.lax.top_k(sp, m)
+    # reprolint: disable=canonical-selection -- exact lax.top_k twin of kernels/select.py: XLA ties break toward the lower index, same canonical (-score, id) order
+    v, sel = jax.lax.top_k(sp, m)
     # block-local → global, masking sentinels *before* the gather (the
     # select contract: -inf slots carry the local sentinel id L)
     shorts = jnp.where(jnp.isneginf(v), n,
@@ -1552,20 +1551,15 @@ class ClusteredIndex(_SpillClusterCore):
 
     def _scan_kernel_block(self, ids_pad: np.ndarray, nv: int,
                            max_rerank: int) -> np.ndarray:
-        """Device shortlist scan of one query block: the fused Pallas
-        blockwise-select kernel (proxy GEMM + canonical top-M in one VMEM
-        pass — scores never round-trip to the host) where the kernels
-        run, the exact ``lax.top_k`` twin elsewhere.  Both implement the
-        canonical ``(-score, id)`` selection, pinned against
-        ``ref.select_topm_ref``.  Dispatches the *same* jitted scan as
+        """Device shortlist scan of one query block: proxy GEMM plus the
+        canonical ``lax.top_k`` selection — scores never
+        round-trip to the host.  Dispatches the *same* jitted scan as
         the fused pipeline (``_fused_scan_pool``), so staged and fused
         shortlists are identical by construction — only this staged
         wrapper pulls them to the host."""
         m = min(max_rerank, self.n_users)
         v, i = _fused_scan_pool(
-            self.proxies, jnp.asarray(ids_pad), m=m,
-            use_pallas=self._use_kernel() or self.cfg.interpret,
-            interpret=self.cfg.interpret)
+            self.proxies, jnp.asarray(ids_pad), m=m)
         v = np.asarray(v)[:nv]
         short = np.where(np.isneginf(v), self.n_users,
                          np.asarray(i)[:nv]).astype(np.int32)
@@ -1882,6 +1876,10 @@ class ClusteredIndex(_SpillClusterCore):
             qspan.set_attr("query_mode", qmode)
             qspan.set_attr("scan_gate", scan_gate)
             qspan.set_attr("rerank_mode", mode)
+            select = ("" if not max_rerank else
+                      "top_k" if scan == "kernel" or qmode == "fused"
+                      else "host")
+            qspan.set_attr("select_mode", select)
 
             if qmode == "fused" and max_rerank:
                 n_probed, n_reranked, t_rerank = self._query_fused(
@@ -1913,6 +1911,7 @@ class ClusteredIndex(_SpillClusterCore):
                                      rerank_mode=mode,
                                      scan_mode=scan if max_rerank else "",
                                      query_mode=qmode,
+                                     select_mode=select,
                                      scan_gate=scan_gate)
         reg = obs.registry()
         reg.counter("index.query.count").inc()
@@ -2092,9 +2091,7 @@ class ClusteredIndex(_SpillClusterCore):
             if pool_all:
                 with obs.span("query.scan", scan="pool", fused=True,
                               block=lo // bq, candidates=n):
-                    _, shorts = _fused_scan_pool(self.proxies, ids_j, m=m,
-                                                 use_pallas=use_pallas,
-                                                 interpret=interpret)
+                    _, shorts = _fused_scan_pool(self.proxies, ids_j, m=m)
                 n_probed += nv * n
             else:
                 with obs.span("query.probe", block=lo // bq,
@@ -2136,8 +2133,7 @@ class ClusteredIndex(_SpillClusterCore):
                 with obs.span("query.scan", scan="restricted", fused=True,
                               block=lo // bq, candidates=len(cand)):
                     _, shorts = _fused_scan_restricted(
-                        self.proxies, jnp.asarray(cand_pad), ids_j, m=m,
-                        use_pallas=use_pallas, interpret=interpret)
+                        self.proxies, jnp.asarray(cand_pad), ids_j, m=m)
                 n_probed += nv * len(cand)
             # the count sync below also fences the scan, so its cost
             # lands in the shortlist stage (rerank timing starts after)

@@ -78,7 +78,7 @@ from repro import obs
 from repro.core import predict as pred_mod
 from repro.core import similarity as sim
 from repro.index.clustered import (_SpillClusterCore, _bucket, _project,
-                                   _svd_basis, _topm_rows)
+                                   _svd_basis)
 from repro.index.kmeans import normalize_rows
 
 
@@ -105,10 +105,11 @@ class ItemIndexConfig:
     spill: int = 2
     shortlist: int = 512
     shortlist_mode: str = "auto"          # "support" | "kernel" | "proxy" |
-                                          # "auto" (support on CPU, kernel —
-                                          # the fused Pallas segmented SpMM
-                                          # over the same exact num/den
-                                          # form — on TPU)
+                                          # "auto" (kernel — the fused Pallas
+                                          # segmented SpMM over the same
+                                          # exact num/den form — where
+                                          # use_kernel resolves true, TPU
+                                          # by default; support elsewhere)
     item_block: int = 512                 # rerank/predict tile width
     kmeans_block: int = 2048
     query_block: int = 256
@@ -134,6 +135,9 @@ class RecommendStats:
     n_items: int           # candidate population the fractions refer to
     n_probed: int          # probed-member items summed over queries
     n_reranked: int        # items exactly predicted (true rerank)
+    scorer: str = ""       # shortlist scorer that ran: "support" (host
+                           # CSR pass), "kernel" (Pallas support kernel)
+                           # or "proxy" (proxy GEMM + top-M selection)
 
     def _frac(self, total: int) -> float:
         return total / max(self.n_queries * max(self.n_items, 1), 1)
@@ -314,26 +318,19 @@ class ItemClusteredIndex(_SpillClusterCore):
     def _shortlist_mode(self) -> str:
         if self.cfg.shortlist_mode != "auto":
             return self.cfg.shortlist_mode
-        return "kernel" if jax.default_backend() == "tpu" else "support"
+        return "kernel" if self._use_kernel() else "support"
 
     def _support_dense(self, ratings, means):
-        """Dense device-resident (U, I) deviation/mask operands for the
-        fused support-scorer kernel (``repro.kernels.support``), padded
-        once to the kernel's tile width so the jitted call never re-pads
-        them.  Cached per ratings array, like every derived operand."""
+        """Device-resident ``(U, 1, W)`` deviation/mask tables for the
+        fused support-scorer kernel (``repro.kernels.support``), built
+        once at the kernel's tile width so the jitted call never re-pads
+        or re-lays them out.  Cached per ratings array, like every
+        derived operand."""
         if self._support_dense_cache is not None and \
                 self._support_dense_cache[0] is ratings:
             return self._support_dense_cache[1]
-        from repro.kernels.support import BT
-        mask = ratings > 0
-        dev = jnp.where(mask, ratings - means[:, None], 0.0
-                        ).astype(jnp.float32)
-        msk = mask.astype(jnp.float32)
-        pad = (-ratings.shape[1]) % min(BT, ratings.shape[1])
-        if pad:         # zero columns: den 0 → mean fallback, sliced off
-            dev = jnp.pad(dev, ((0, 0), (0, pad)))
-            msk = jnp.pad(msk, ((0, 0), (0, pad)))
-        pair = (dev, msk)
+        from repro.kernels.support import support_rows, support_width
+        pair = support_rows(ratings, means, support_width(ratings.shape[1]))
         self._support_dense_cache = (ratings, pair)
         return pair
 
@@ -523,25 +520,17 @@ class ItemClusteredIndex(_SpillClusterCore):
                               _shortlist_scores(prof, self.proxies,
                                                 jnp.asarray(cand_pad),
                                                 seen_rows))
-                    if self._use_kernel() or self.cfg.interpret:
-                        # device top-M through the shared blockwise-select
-                        # kernel — proxy scores never round-trip to the
-                        # host (the scores already carry the seen-item
-                        # knockout, so no q_ids self-knockout is needed)
-                        from repro.kernels.select import select_topm
-                        v, sel = select_topm(
-                            sp_dev, jnp.full((sp_dev.shape[0],), -1,
-                                             jnp.int32),
-                            m=min(m_short, sp_dev.shape[1]),
-                            interpret=self.cfg.interpret)
-                        selv = np.asarray(v)[:nv]
-                        sel = np.asarray(sel)[:nv]
-                    else:
-                        # np.array: jax hands back a read-only view and
-                        # the torch topk fast path wants a writable buffer
-                        sp = np.array(np.asarray(sp_dev)[:nv])
-                        selv, sel = _topm_rows(sp, m_short,
-                                               col_ids=cand_pad)
+                    # device top-M on every backend — proxy scores never
+                    # round-trip to the host: the exact lax.top_k twin
+                    # (Mosaic cannot lower the Pallas select kernel).
+                    # cand_pad is ascending, so lower-index ties are
+                    # lower-id ties; the scores already carry the
+                    # seen-item knockout, so no self-knockout is needed
+                    # reprolint: disable=canonical-selection -- exact lax.top_k twin of kernels/select.py: XLA ties break toward the lower index, same canonical (-score, id) order
+                    v, sel = jax.lax.top_k(
+                        sp_dev, min(m_short, sp_dev.shape[1]))
+                    selv = np.asarray(v)[:nv]
+                    sel = np.asarray(sel)[:nv]
                     # sel uses the sentinel id len(cand_pad) for -inf
                     # slots; clamp before the gather, then mask — never
                     # index a member table through a dead slot
@@ -569,7 +558,7 @@ class ItemClusteredIndex(_SpillClusterCore):
 
         self.last_recommend = RecommendStats(
             n_queries=len(uids), n_items=self.n_items,
-            n_probed=n_probed, n_reranked=n_reranked)
+            n_probed=n_probed, n_reranked=n_reranked, scorer="proxy")
         return jnp.asarray(out_s), jnp.asarray(out_i)
 
     def _score_select_rows(self, stacked, w, safe_idx, q_means, seen_rows,
@@ -743,7 +732,8 @@ class ItemClusteredIndex(_SpillClusterCore):
 
         self.last_recommend = RecommendStats(
             n_queries=len(uids), n_items=n_items,
-            n_probed=len(uids) * n_items, n_reranked=n_reranked)
+            n_probed=len(uids) * n_items, n_reranked=n_reranked,
+            scorer=scorer)
         return jnp.asarray(out_s), jnp.asarray(out_i)
 
     # -- delta-aware cache maintenance -------------------------------------
@@ -777,17 +767,11 @@ class ItemClusteredIndex(_SpillClusterCore):
             self._support_cache = None
         if self._support_dense_cache is not None and \
                 self._support_dense_cache[0] is old and means is not None:
+            from repro.kernels.support import support_rows
             dev, msk = self._support_dense_cache[1]
             t_j = jnp.asarray(touched)
-            rows = ratings[t_j]
-            mask = rows > 0
-            d_rows = jnp.where(mask, rows - means[t_j][:, None], 0.0
-                               ).astype(jnp.float32)
-            m_rows = mask.astype(jnp.float32)
-            pad = dev.shape[1] - rows.shape[1]
-            if pad:
-                d_rows = jnp.pad(d_rows, ((0, 0), (0, pad)))
-                m_rows = jnp.pad(m_rows, ((0, 0), (0, pad)))
+            d_rows, m_rows = support_rows(ratings[t_j], means[t_j],
+                                          dev.shape[2])
             self._support_dense_cache = (
                 ratings, (dev.at[t_j].set(d_rows),
                           msk.at[t_j].set(m_rows)))

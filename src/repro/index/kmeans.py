@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, obs
+from repro import obs
 from repro.kernels.cluster import centroid_distances
 
 
@@ -130,10 +130,10 @@ def _sharded_sweep(mesh, axis: str, *, block_size: int, n_clusters: int,
         return (jax.lax.psum(sums, axis), jax.lax.psum(counts, axis),
                 assign, best_d)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
-        out_specs=(P(), P(), P(axis), P(axis))))
+        out_specs=(P(), P(), P(axis), P(axis)), check_vma=False))
 
 
 def kmeans(z: jnp.ndarray, n_clusters: int, *, seed: int = 0, iters: int = 8,

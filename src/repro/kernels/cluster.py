@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 # default MXU-aligned tile sizes (v5e: 128×128 MXU, 8×128 VREG lanes)
 BM, BN, BK = 256, 256, 512
@@ -95,7 +94,7 @@ def fused_centroid_distances(x: jnp.ndarray, c: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32),
                         pltpu.VMEM((bm_, 1), jnp.float32),
                         pltpu.VMEM((1, bn_), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_p, c_p)

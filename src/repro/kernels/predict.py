@@ -27,8 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels.similarity import _pad_to
 
 # default tile sizes: bm·k·bt f32 must sit comfortably in VMEM
@@ -83,7 +83,7 @@ def fused_tile_predict(nbr: jnp.ndarray, w: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm_, bt_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, tp), jnp.float32),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(nbr_p, w_p, nbm_p, qm_p)

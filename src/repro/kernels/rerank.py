@@ -48,7 +48,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core import similarity as sim
 
 _EPS = 1e-8
@@ -174,7 +173,7 @@ def fused_rerank_scores(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((gp, kp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)] * 6
         + [pltpu.VMEM((bm_, 1), jnp.float32)] * 2,
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_p, c_p, cn_p, cc_p)

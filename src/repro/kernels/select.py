@@ -14,13 +14,14 @@ keeps selection on the device:
   running top-``m`` buffer via one canonical ``(-score, id)`` sort over
   ``m_pad + bn`` lanes.  The (Q, N) score matrix is never materialised —
   not even in HBM.  The merge uses ``jax.lax.sort`` inside the kernel
-  body; that is exact and runs under interpret mode (this repo's kernel
-  validation vehicle — see ``kernels/cluster.py``), while Mosaic lowering
-  of in-kernel sorts is unproven and tracked in ROADMAP.md.  Production
-  TPU paths that cannot lower it use :func:`scan_topm_xla`.
+  body; that is exact and runs under interpret mode, but Mosaic has no
+  TPU lowering for ``sort`` (compiling for a v5e is refused).  So the
+  served paths select with :func:`scan_topm_xla` / ``lax.top_k`` on
+  every backend, TPU included (``QueryStats.select_mode`` reads
+  ``top_k``); this kernel is kept, oracle-tested in interpret mode, for
+  a lowered merge to replace the twin.
 * :func:`select_topm` — the same running merge over a precomputed score
-  matrix (the item index's proxy scorer feeds it device scores that
-  already carry the seen-item knockout).
+  matrix.
 * :func:`scan_topm_xla` — the XLA twin: one jnp GEMM plus
   ``jax.lax.top_k`` (exact; XLA's top_k breaks ties toward the lower
   index, which *is* the canonical ``(-score, id)`` policy), or
@@ -44,7 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 # MXU-aligned defaults (v5e: 128×128 MXU, 8×128 VREG lanes); bn bounds the
 # per-step sort width (m_pad + bn lanes resident in VMEM)
@@ -153,7 +153,7 @@ def fused_scan_topm(q: jnp.ndarray, proxies: jnp.ndarray,
                    jax.ShapeDtypeStruct((q_p.shape[0], mp), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((bq_, mp), jnp.float32),
                         pltpu.VMEM((bq_, mp), jnp.int32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q_p, prox_p, qid_p)
@@ -190,7 +190,7 @@ def select_topm(scores: jnp.ndarray, q_ids: jnp.ndarray, *, m: int,
                    jax.ShapeDtypeStruct((s_p.shape[0], mp), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((bq_, mp), jnp.float32),
                         pltpu.VMEM((bq_, mp), jnp.int32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(s_p, qid_p)

@@ -15,16 +15,25 @@ dominated by items with a *median of one* supporting neighbor, which
 profile geometry cannot see).
 
 TPU formulation via *scalar prefetch* (the embedding-bag pattern): the
-(b, k) neighbor-id matrix is prefetched to SMEM so each grid step's
-BlockSpec index map can select which table row tile to DMA — the (U, I)
-deviation/mask tables never leave HBM except for the touched rows, and
-each gathered tile is consumed by one VMEM multiply-accumulate with the
-division/fallback/clip epilogue in-register.
+(b, k) neighbor ids and weights and the (b,) query means are prefetched
+to SMEM, so each grid step's BlockSpec index map selects which table row
+tile to DMA — the deviation/mask tables never leave HBM except for the
+touched rows, and each gathered tile is consumed by one VMEM
+multiply-accumulate with the division/fallback/clip epilogue in-register.
 
-Grid: (b, I/bt, k) with the neighbor axis innermost (it carries the
-num/den accumulators).  Interpret mode runs on CPU and is validated
-against ``repro.kernels.ref.support_scores_ref``; the scipy CSR pass
-remains the production CPU path.
+Layout: the tables are ``(U, 1, I)`` (:func:`support_rows` builds them).
+Mosaic requires the last two block dims to divide by (8, 128) or equal
+the array dims; a one-row tile of a ``(U, I)`` table satisfies neither,
+while a ``(1, bt)`` tile of a ``(U, 1, I)`` table does, with the row dim
+squeezed.  The tables are stored in that layout once per ratings array
+(a per-call reshape would copy both, ~198 MB at the ML-1M shape).  SMEM
+holds 1 MiB and pads a 2-D operand's last dim to 128 lanes, so one call
+takes at most ``BB`` query rows; larger batches loop over row blocks.
+
+Grid per row block: (b, I/bt, k) with the neighbor axis innermost (it
+carries the num/den accumulators).  Interpret mode runs on CPU and is
+validated against ``repro.kernels.ref.support_scores_ref``; the scipy CSR
+pass remains the production CPU path.
 """
 
 from __future__ import annotations
@@ -39,6 +48,27 @@ from jax.experimental.pallas import tpu as pltpu
 _DEN_EPS = 1e-8
 
 BT = 512            # item-tile width: 2 tables · (1, bt) f32 per step
+# query rows per pallas_call: two (BB, k) SMEM operands padded to 128
+# lanes stay at 256 KiB of the 1 MiB SMEM
+BB = 256
+
+
+def support_width(n_items: int, bt: int = BT) -> int:
+    """Stored table width: ``n_items`` padded to a whole number of tiles."""
+    bt_ = min(bt, n_items)
+    return -(-n_items // bt_) * bt_
+
+
+def support_rows(rows: jnp.ndarray, row_means: jnp.ndarray,
+                 width: int) -> jnp.ndarray:
+    """(n, I) rating rows → ``(dev, msk)`` kernel table rows, each
+    ``(n, 1, width)`` f32: mean-centred ratings and the rated mask, zero
+    in the pad columns (den 0 there → mean fallback, sliced off)."""
+    mask = rows > 0
+    dev = jnp.where(mask, rows - row_means[:, None], 0.0).astype(jnp.float32)
+    msk = mask.astype(jnp.float32)
+    pad = ((0, 0), (0, width - rows.shape[1]))
+    return jnp.pad(dev, pad)[:, None, :], jnp.pad(msk, pad)[:, None, :]
 
 
 def _support_kernel(idx_ref, w_ref, qm_ref, dev_ref, msk_ref, out_ref,
@@ -50,18 +80,41 @@ def _support_kernel(idx_ref, w_ref, qm_ref, dev_ref, msk_ref, out_ref,
         acc_num[...] = jnp.zeros_like(acc_num)
         acc_den[...] = jnp.zeros_like(acc_den)
 
-    del b
-    w = w_ref[0, kk]
+    del idx_ref
+    w = w_ref[b, kk]
     acc_num[...] += w * dev_ref[...].astype(jnp.float32)
     acc_den[...] += w * msk_ref[...].astype(jnp.float32)
 
     @pl.when(kk == k_len - 1)
     def _epilogue():
-        qm = qm_ref[0, 0]
+        qm = qm_ref[b]
         num, den = acc_num[...], acc_den[...]
         pred = qm + num / jnp.maximum(den, _DEN_EPS)
         pred = jnp.where(den > _DEN_EPS, pred, qm)
         out_ref[...] = jnp.clip(pred, 1.0, 5.0)
+
+
+def _support_call(dev, msk, nb_idx, nb_w, q_means, *, bt, interpret):
+    b, k_len = nb_idx.shape
+    width = dev.shape[2]
+    row = lambda bb, j, kk, idx_ref, w_ref, qm_ref: (idx_ref[bb, kk], 0, j)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, width // bt, k_len),
+        in_specs=[pl.BlockSpec((None, 1, bt), row),
+                  pl.BlockSpec((None, 1, bt), row)],
+        out_specs=pl.BlockSpec(
+            (None, 1, bt), lambda bb, j, kk, *_: (bb, 0, j)),
+        scratch_shapes=[pltpu.VMEM((1, bt), jnp.float32),
+                        pltpu.VMEM((1, bt), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_support_kernel, k_len=k_len),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, width), jnp.float32),
+        interpret=interpret,
+    )(nb_idx, nb_w, q_means, dev, msk)
+    return out[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -69,42 +122,24 @@ def fused_support_scores(dev: jnp.ndarray, msk: jnp.ndarray,
                          nb_idx: jnp.ndarray, nb_w: jnp.ndarray,
                          q_means: jnp.ndarray, *, bt: int = BT,
                          interpret: bool = False) -> jnp.ndarray:
-    """(U, I) deviation/mask tables × (b, k) neighbors → (b, I) scores.
+    """(U, 1, W) deviation/mask tables × (b, k) neighbors → (b, W) scores.
 
     ``nb_w`` must be the masked weights (invalid/negative-score neighbors
     at 0 — a zero weight cancels both accumulators) and ``nb_idx`` must be
     clipped into ``[0, U)``; both are what the item index's scorer already
     prepares.  Seen-item knockout is the caller's (it owns the ratings).
+    The tables' width must be whole tiles: build them with
+    :func:`support_rows` at :func:`support_width` for the same ``bt``.
     """
-    b, k_len = nb_idx.shape
-    n_items = dev.shape[1]
-    bt_ = min(bt, n_items)
-    pad = (-n_items) % bt_
-    if pad:
-        dev = jnp.pad(dev, ((0, 0), (0, pad)))
-        msk = jnp.pad(msk, ((0, 0), (0, pad)))
-    grid = (b, (n_items + pad) // bt_, k_len)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, k_len), lambda bb, j, kk, idx_ref: (bb, 0)),
-            pl.BlockSpec((1, 1), lambda bb, j, kk, idx_ref: (bb, 0)),
-            pl.BlockSpec((1, bt_),
-                         lambda bb, j, kk, idx_ref: (idx_ref[bb, kk], j)),
-            pl.BlockSpec((1, bt_),
-                         lambda bb, j, kk, idx_ref: (idx_ref[bb, kk], j)),
-        ],
-        out_specs=pl.BlockSpec((1, bt_), lambda bb, j, kk, idx_ref: (bb, j)),
-        scratch_shapes=[pltpu.VMEM((1, bt_), jnp.float32),
-                        pltpu.VMEM((1, bt_), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_support_kernel, k_len=k_len),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_items + pad), jnp.float32),
-        interpret=interpret,
-    )(nb_idx.astype(jnp.int32), nb_w.astype(jnp.float32),
-      q_means.astype(jnp.float32)[:, None], dev, msk)
-    return out[:, :n_items]
+    width = dev.shape[2]
+    bt_ = min(bt, width)
+    assert width % bt_ == 0, (
+        f"table width {width} is not whole {bt_}-wide tiles; build the "
+        "tables with support_rows(..., support_width(n_items, bt))")
+    nb_idx = nb_idx.astype(jnp.int32)
+    nb_w = nb_w.astype(jnp.float32)
+    q_means = q_means.astype(jnp.float32)
+    outs = [_support_call(dev, msk, nb_idx[lo:lo + BB], nb_w[lo:lo + BB],
+                          q_means[lo:lo + BB], bt=bt_, interpret=interpret)
+            for lo in range(0, nb_idx.shape[0], BB)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)

@@ -6,8 +6,8 @@ state.  Single pod: (16, 16) = 256 chips, axes (data, model).  Multi-pod:
 parallelism (the slow inter-pod DCI links only ever carry gradient
 all-reduces, never layer-wise TP traffic).
 
-All meshes go through ``repro.compat.make_mesh`` so the ``axis_types``
-kwarg drift between jax 0.4.x and ≥0.5 is handled in one place.
+All meshes go through ``repro.compat.make_mesh``, which builds them with
+Auto axes (``jax.make_mesh`` defaults to Explicit).
 """
 
 from __future__ import annotations

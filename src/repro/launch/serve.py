@@ -32,6 +32,7 @@ import numpy as np
 from repro import obs
 from repro.core import CFConfig, UserCF
 from repro.data import load_ml1m_synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import BatchingServer
 
 
@@ -89,6 +90,7 @@ def main():
     ap.add_argument("--max-restarts", type=int, default=3,
                     help="retry budget per faulted batch")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.distributed.fault_tolerance import (FaultInjector,
                                                    RecoveryPolicy)

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 
 REPLICATE_THRESHOLD = 8192      # tables smaller than this are replicated
 
@@ -211,7 +210,7 @@ def sharded_lookup(layout: TableLayout, tables, indices: jnp.ndarray,
                     tbl_loc, owner, local_row, n, capacity, batch_axes)
                 return got.reshape(ids_loc.shape + (d,))
 
-            vals = compat.shard_map(
+            vals = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(batch_axes, None), P(batch_axes, None)),
                 out_specs=P(batch_axes, None, None),

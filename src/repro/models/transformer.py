@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 from repro.models import common as cm
 from repro.models.common import ShardingCtx, NO_SHARDING
@@ -464,7 +463,7 @@ def _moe_ffn(cfg: TransformerConfig, p, x, sc: ShardingCtx,
             w_up = jax.lax.all_gather(w_up, "data", axis=2, tiled=True)
             w_down = jax.lax.all_gather(w_down, "data", axis=1, tiled=True)
             m_rank = jax.lax.axis_index("model")
-            n_model = compat.axis_size("model")
+            n_model = jax.lax.axis_size("model")
         else:
             m_rank, n_model = 0, 1
         e_loc = w_gate.shape[0]
@@ -518,7 +517,7 @@ def _moe_ffn(cfg: TransformerConfig, p, x, sc: ShardingCtx,
         mesh = sc.mesh
         if mesh is None:
             raise ValueError("sharded MoE needs ShardingCtx.mesh")
-        y = compat.shard_map(
+        y = jax.shard_map(
             local_moe, mesh=mesh,
             in_specs=(P(sc.batch, None, None), P(None, None),
                       P("model", None, "data"), P("model", None, "data"),

@@ -12,7 +12,7 @@ Usage: wrap grads between value_and_grad and optimizer.update::
     comp_state = init_compression(params)
     grads, comp_state = compress_decompress(grads, comp_state)
 
-Under pjit the quantise → psum(int32) → dequantise pattern lets the SPMD
+Under jit the quantise → psum(int32) → dequantise pattern lets the SPMD
 partitioner carry 1-byte payloads over the ``pod`` axis; in this framework's
 step functions the compression is applied around the gradient psum
 boundary (the grads produced by backward are already partially reduced over

@@ -30,14 +30,14 @@ def test_engines_bit_identical_across_devices():
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.data import load_ml1m_synthetic
-        from repro.core.engine import (cpu_mesh, ring_sharded_predict,
+        from repro.core.engine import (local_mesh, ring_sharded_predict,
                                        ring_sharded_topk, sharded_topk,
                                        sharded_predict)
         from repro.core.neighbors import topk_neighbors
         from repro.core.predict import predict_from_neighbors
         train, _, _ = load_ml1m_synthetic(n_users=256, n_items=200, seed=0)
         r = jnp.asarray(train)
-        mesh = cpu_mesh(8)
+        mesh = local_mesh(8)
         for meas in ("jaccard", "cosine", "pcc"):
             s0, i0 = topk_neighbors(r, 12, measure=meas, block_size=64)
             s1, i1 = sharded_topk(r, 12, mesh, measure=meas, block_size=64)
@@ -156,11 +156,11 @@ def test_shard_scaling_timing():
     """
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core.engine import cpu_mesh, sharded_topk
+        from repro.core.engine import local_mesh, sharded_topk
         from repro.data import load_ml1m_synthetic
         train, _, _ = load_ml1m_synthetic(n_users=512, n_items=256, seed=1)
         r = jnp.asarray(train)
-        mesh = cpu_mesh(8)
+        mesh = local_mesh(8)
         s, i = sharded_topk(r, 8, mesh, measure="cosine", block_size=64)
         # per-device shard of the output is 512/8 = 64 query users
         shards = s.addressable_shards

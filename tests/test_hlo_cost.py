@@ -72,7 +72,7 @@ def test_collectives_counted_inside_loops():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.launch.hlo_cost import analyze
-        from repro.compat import make_mesh, shard_map
+        from repro.compat import make_mesh
         mesh = make_mesh((8,), ("d",))
         def f(x):
             def body(h, _):
@@ -80,8 +80,8 @@ def test_collectives_counted_inside_loops():
                 return h * 0.125, None
             h, _ = jax.lax.scan(body, x, None, length=10)
             return h
-        g = shard_map(f, mesh=mesh, in_specs=P(None, None),
-                      out_specs=P(None, None), check_vma=False)
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(None, None),
+                          out_specs=P(None, None), check_vma=False)
         co = jax.jit(g).lower(
             jax.ShapeDtypeStruct((32, 64), jnp.float32)).compile()
         r = analyze(co.as_text())

@@ -41,7 +41,7 @@ def test_seeded_int8_upcast_fires_exactly_once():
 
 
 def test_widening_traced_through_jit_boundary():
-    """The real hot paths widen inside nested pjit calls; provenance must
+    """The real hot paths widen inside nested jit calls; provenance must
     cross the sub-jaxpr boundary with the chain intact."""
     @jax.jit
     def inner(x):

@@ -60,12 +60,12 @@ def test_sharded_deviation_subprocess():
     code = """
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import slope_one as so
-        from repro.core.engine import cpu_mesh
+        from repro.core.engine import local_mesh
         rng = np.random.default_rng(0)
         r = (rng.integers(1, 6, (60, 32))
              * (rng.random((60, 32)) < 0.5)).astype(np.float32)
         d0, c0 = so.deviation_matrix(jnp.asarray(r))
-        mesh = cpu_mesh(8)
+        mesh = local_mesh(8)
         d1, c1 = so.sharded_deviation(jnp.asarray(r), mesh)
         assert np.allclose(d0, d1, atol=1e-5)
         assert np.allclose(c0, c1)

@@ -13,7 +13,8 @@ from repro.index import (ClusteredIndex, IndexConfig, ItemClusteredIndex,
                          ItemIndexConfig)
 from repro.index.item_index import _affinity_weights, _fold_profiles
 from repro.kernels import ref
-from repro.kernels.support import fused_support_scores
+from repro.kernels.support import (fused_support_scores, support_rows,
+                                   support_width)
 
 
 def _ratings(rng, u, d, density=0.3):
@@ -24,22 +25,28 @@ def _ratings(rng, u, d, density=0.3):
 # -- kernel vs oracle ---------------------------------------------------------
 
 @pytest.mark.parametrize("shape", [(5, 7, 40, 130), (9, 3, 25, 64),
-                                   (2, 12, 50, 33)])
+                                   (2, 12, 50, 33), (260, 2, 20, 33)])
 def test_support_kernel_matches_ref(shape, rng):
+    """Tables built by ``support_rows`` at ``support_width`` (pad columns
+    whenever ``i`` is not whole tiles) score as the oracle on the
+    unpadded deviation/mask."""
     b, k, u, i = shape
-    dev = (rng.normal(size=(u, i)).astype(np.float32)
-           * (rng.random((u, i)) < 0.3))
-    msk = (dev != 0).astype(np.float32)
+    r = np.asarray(_ratings(rng, u, i))
+    means = rng.uniform(2, 4, u).astype(np.float32)
+    dev = np.where(r > 0, r - means[:, None], 0.0).astype(np.float32)
+    msk = (r > 0).astype(np.float32)
     idx = rng.integers(0, u, (b, k)).astype(np.int32)
     w = (rng.random((b, k)) * (rng.random((b, k)) < 0.8)).astype(np.float32)
     qm = rng.uniform(2, 4, b).astype(np.float32)
     want = ref.support_scores_ref(jnp.asarray(dev), jnp.asarray(msk),
                                   jnp.asarray(idx), jnp.asarray(w),
                                   jnp.asarray(qm))
-    got = fused_support_scores(jnp.asarray(dev), jnp.asarray(msk),
-                               jnp.asarray(idx), jnp.asarray(w),
-                               jnp.asarray(qm), bt=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    dev_t, msk_t = support_rows(jnp.asarray(r), jnp.asarray(means),
+                                support_width(i, 32))
+    got = fused_support_scores(dev_t, msk_t, jnp.asarray(idx),
+                               jnp.asarray(w), jnp.asarray(qm), bt=32,
+                               interpret=True)
+    np.testing.assert_allclose(np.asarray(got)[:, :i], np.asarray(want),
                                atol=1e-5)
 
 
@@ -51,7 +58,8 @@ def test_support_kernel_all_masked_neighbors(rng):
     w = np.zeros((3, 4), np.float32)
     qm = np.array([1.5, 3.0, 4.5], np.float32)
     got = np.asarray(fused_support_scores(
-        jnp.asarray(dev), jnp.asarray(msk), jnp.asarray(idx),
+        jnp.asarray(dev[:, None]), jnp.asarray(msk[:, None]),
+        jnp.asarray(idx),
         jnp.asarray(w), jnp.asarray(qm), bt=16, interpret=True))
     np.testing.assert_allclose(got, np.broadcast_to(qm[:, None], got.shape),
                                atol=1e-6)
