@@ -1,0 +1,25 @@
+"""Published peaks per chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture page):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2 at 819 GB/s per chip.
+A device that is not in the table is an error, never a default.
+"""
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud TPU v5e documentation",
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak row of ``device_kind``; raises for an unknown device."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
