@@ -456,14 +456,12 @@ class ItemClusteredIndex(_SpillClusterCore):
         self._obs_recommend(sp)
         return out
 
-    def _obs_recommend(self, sp) -> None:
-        """Publish one recommend call to the registry (root span closed)."""
-        st = self.last_recommend
-        reg = obs.registry()
-        reg.counter("item_index.recommend.count").inc()
-        reg.counter("item_index.recommend.queries").inc(st.n_queries)
-        reg.counter("item_index.recommend.reranked_rows").inc(st.n_reranked)
-        reg.histogram("item_index.recommend.seconds").observe(sp.duration)
+    @staticmethod
+    def _obs_recommend(sp) -> None:
+        """Publish one recommend call's time to the registry (root span
+        closed); its counts are in ``last_recommend``."""
+        obs.registry().histogram("item_index.recommend.seconds").observe(
+            sp.duration)
 
     def _recommend_proxy(self, ratings, means, nb_scores, nb_idx,
                          uids: np.ndarray, *, n: int, n_probe: int,
@@ -607,12 +605,15 @@ class ItemClusteredIndex(_SpillClusterCore):
     def _select_shortlist(self, num: np.ndarray, m_short: int) -> np.ndarray:
         """Canonical top-``m_short`` selection over scored rows (seen items
         already at -inf) with the tie-boundary repair of
-        ``_score_select_rows``'s docstring."""
-        with obs.span("recommend.select", rows=int(num.shape[0])):
-            return self._select_shortlist_body(num, m_short)
+        ``_score_select_rows``'s docstring; the span's ``repaired`` counts
+        the rows that took the repair."""
+        with obs.span("recommend.select", rows=int(num.shape[0])) as sp:
+            shorts, repaired = self._select_shortlist_body(num, m_short)
+            sp.set_attr("repaired", repaired)
+            return shorts
 
     def _select_shortlist_body(self, num: np.ndarray,
-                               m_short: int) -> np.ndarray:
+                               m_short: int) -> Tuple[np.ndarray, int]:
         n_items = self.n_items
         # reprolint: disable=canonical-selection -- shortlist only (exact rerank follows); cut-value ties get the boundary repair below, same policy as _topm_rows
         sel = np.argpartition(num, n_items - m_short,
@@ -624,14 +625,15 @@ class ItemClusteredIndex(_SpillClusterCore):
         vb = np.where(np.isfinite(vb), vb, np.inf)
         row_cnt = np.count_nonzero(num == vb[:, None], axis=1)
         sel_cnt = np.count_nonzero(selv == vb[:, None], axis=1)
-        for row in np.nonzero(row_cnt > sel_cnt)[0]:
+        repair = np.nonzero(row_cnt > sel_cnt)[0]
+        for row in repair:
             v = vb[row]
             above = np.nonzero(num[row] > v)[0]
             tied = np.nonzero(num[row] == v)[0][:m_short - len(above)]
             merged = np.concatenate([above, tied]).astype(np.int32)
             shorts[row, :len(merged)] = merged
             shorts[row, len(merged):] = n_items
-        return np.sort(shorts, axis=1)
+        return np.sort(shorts, axis=1), len(repair)
 
     def _recommend_support(self, ratings, means, nb_scores, nb_idx,
                            uids: np.ndarray, *, n: int,
@@ -668,24 +670,27 @@ class ItemClusteredIndex(_SpillClusterCore):
         bq = min(self.cfg.rerank_block, _bucket(len(uids)))
 
         def score_chunk(ids):
-            """Shortlists for one chunk, halved over two host threads."""
+            """Shortlists for one chunk, halved over two host threads.
+            The kernel scores on this thread, in a ``recommend.score``
+            span that its host copy fences; the host scorer scores on the
+            pool (``_score_select_rows``)."""
             w = np.where((sc_np[ids] > 0) & (idx_np[ids] >= 0),
                          sc_np[ids], 0.0).astype(np.float32)
             safe = np.where(idx_np[ids] >= 0, idx_np[ids], 0)
             seen = rnp[ids] > 0
+            half = (len(ids) + 1) // 2 if len(ids) >= 64 else len(ids)
             if scorer == "kernel":
                 from repro.kernels.support import fused_support_scores
-                dev, msk = self._support_dense(ratings, means)
-                num = np.asarray(fused_support_scores(
-                    dev, msk, jnp.asarray(safe), jnp.asarray(w),
-                    means[jnp.asarray(ids)],
-                    interpret=self.cfg.interpret))[:, :n_items].copy()
-                num[seen] = -np.inf
-                half = (len(ids) + 1) // 2 if len(ids) >= 64 else len(ids)
+                with obs.span("recommend.score", rows=len(ids)):
+                    dev, msk = self._support_dense(ratings, means)
+                    num = np.asarray(fused_support_scores(
+                        dev, msk, jnp.asarray(safe), jnp.asarray(w),
+                        means[jnp.asarray(ids)],
+                        interpret=self.cfg.interpret))[:, :n_items].copy()
+                    num[seen] = -np.inf
                 return [pool.submit(self._select_shortlist,
                                     num[h0:h0 + half], m_short)
                         for h0 in range(0, len(ids), half)]
-            half = (len(ids) + 1) // 2 if len(ids) >= 64 else len(ids)
             parts = [pool.submit(
                 self._score_select_rows, stacked, w[h0:h0 + half],
                 safe[h0:h0 + half], means_np[ids[h0:h0 + half]],
@@ -702,8 +707,10 @@ class ItemClusteredIndex(_SpillClusterCore):
                                        + self.cfg.score_block])
             for ci, lo in enumerate(chunk_starts):
                 ids = uids[lo:lo + self.cfg.score_block]
-                shorts = np.concatenate([p.result() for p in pending],
-                                        axis=0)
+                with obs.span("recommend.select_wait", chunk=ci,
+                              rows=len(ids)):
+                    shorts = np.concatenate([p.result() for p in pending],
+                                            axis=0)
                 if ci + 1 < len(chunk_starts):
                     nxt = chunk_starts[ci + 1]
                     pending = score_chunk(

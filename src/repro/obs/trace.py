@@ -23,14 +23,22 @@ not completion.  ``device_sync=True`` (on :func:`traced`) or
 closes, so the recorded duration covers the device work — measured
 honestly instead of timing dispatch.
 
-No dependencies beyond the standard library; ``jax`` is imported lazily
-and only when a fence is actually requested.
+While a JAX profiler session is recording, each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so the program's spans
+appear in the profiler's ``.xplane.pb`` on its own clock, on the host
+thread that ran them.  With no session recording that costs one
+``is_enabled()`` check per span.
+
+No dependencies beyond the standard library; ``jax`` is imported lazily,
+only when a fence is actually requested, and the profiler is looked up
+only once something else has imported ``jax``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -48,6 +56,9 @@ _tls = threading.local()
 # perf_counter epoch → unix time, so exported timestamps are wall-clock
 # anchored while durations keep perf_counter's monotonic resolution
 _EPOCH_UNIX = time.time() - time.perf_counter()
+
+# jax.profiler.TraceAnnotation, once looked up (see _annotate)
+_annotation = None
 
 
 @dataclasses.dataclass
@@ -80,7 +91,7 @@ class Span:
     """
 
     __slots__ = ("name", "attrs", "device_sync", "span_id", "parent_id",
-                 "t_start", "duration", "_tracked")
+                 "t_start", "duration", "_tracked", "_ann", "_keep")
 
     def __init__(self, name: str, *, device_sync: bool = False, **attrs):
         self.name = name
@@ -91,6 +102,8 @@ class Span:
         self.t_start = 0.0
         self.duration = 0.0
         self._tracked: list = []
+        self._ann = None
+        self._keep = True
 
     # -- attribute / fence plumbing ---------------------------------------
     def set_attr(self, key: str, value) -> None:
@@ -106,12 +119,18 @@ class Span:
             self._tracked.append(value)
         return value
 
+    def discard(self) -> None:
+        """Record nothing when this span closes (it still times): for a
+        wait that ended without the work it was waiting for."""
+        self._keep = False
+
     # -- context manager ---------------------------------------------------
     def __enter__(self) -> "Span":
         st = _stack()
         self.parent_id = st[-1].span_id if st else 0
         self.span_id = next(_ids)
         st.append(self)
+        self._ann = _annotate(self.name)
         self.t_start = time.perf_counter()
         return self
 
@@ -123,8 +142,12 @@ class Span:
         if st and st[-1] is self:
             st.pop()
         elif self in st:            # mis-nested exit: drop up to self
-            del st[st.index(self):]
-        if _enabled:
+            i = st.index(self)
+            for inner in reversed(st[i + 1:]):   # annotations close LIFO
+                inner._close_annotation()
+            del st[i:]
+        self._close_annotation()
+        if _enabled and self._keep:
             th = threading.current_thread()
             rec = SpanRecord(name=self.name, span_id=self.span_id,
                              parent_id=self.parent_id,
@@ -138,6 +161,29 @@ class Span:
                 else:
                     _dropped += 1
         return None
+
+    def _close_annotation(self) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name`` while a
+    profiler session records, else None.  Before anything has imported
+    ``jax`` no session can be recording, so nothing is looked up."""
+    global _annotation
+    cls = _annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        cls = _annotation = TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
 
 
 def span(name: str, *, device_sync: bool = False, **attrs) -> Span:

@@ -55,7 +55,10 @@ mutating.  Percentiles are histogram bucket *upper bounds* (exact bounds,
 ~26 % worst-case relative error at 10 buckets/decade) — never below the
 true quantile.  Each served batch also records a ``serve.batch`` span with
 a ``serve.predict`` child, so batches appear on the batcher thread's track
-in the exported chrome trace.
+in the exported chrome trace, preceded by one ``serve.gather`` span: the
+batcher's wait from being free until the batch formed, empty polls
+included.  The three carry the batch's ``batch_seq``, which each
+:class:`Recommendation` of the batch carries too.
 """
 
 from __future__ import annotations
@@ -108,6 +111,7 @@ class Recommendation:
     items: np.ndarray
     scores: np.ndarray
     latency_ms: float
+    batch_seq: int = 0      # the serving batch's spans carry the same id
 
 
 @dataclasses.dataclass
@@ -389,17 +393,12 @@ class BatchingServer:
     def _loop(self):
         try:
             while not self._stop.is_set():
-                batch = self._gather()
-                if batch:
-                    self._run_batch(batch)
+                self._serve_next()
             with self._state_lock:
                 drain = self._drain
             if drain:
-                while True:
-                    batch = self._gather(drain=True)
-                    if not batch:
-                        break
-                    self._run_batch(batch)
+                while self._serve_next(drain=True):
+                    pass
         finally:
             # belt and braces: if the batcher exits for ANY reason with
             # requests still queued, mark the server stopped (so submit
@@ -410,6 +409,52 @@ class BatchingServer:
                 self._stopped = True
             self._flush_queue(ServerStopped(
                 "batcher exited before serving this request"))
+
+    def _serve_next(self, drain: bool = False) -> bool:
+        """Gather one batch of live requests and run it; False when none
+        formed before the server stopped (draining: before the queue ran
+        dry).  One ``serve.gather`` span covers the wait, however many
+        polls time out on an empty queue, and is recorded only for a
+        batch that runs."""
+        with obs.span("serve.gather") as sp:
+            live = self._gather_live(drain)
+            if not live:
+                sp.discard()
+                return False
+            self._batch_seq += 1
+            sp.set_attr("batch_seq", self._batch_seq)
+            sp.set_attr("batch_size", len(live))
+        self._run_batch(live, self._batch_seq)
+        return True
+
+    def _gather_live(self, drain: bool) -> list:
+        """Poll until a batch with a request whose deadline has not passed
+        forms; [] once stopped (draining: once the queue is empty)."""
+        while True:
+            batch = self._gather(drain)
+            if batch:
+                live = self._triage(batch)
+                if live:
+                    return live
+            elif drain or self._stop.is_set():
+                return []
+
+    def _triage(self, batch: list) -> list:
+        """Resolve requests whose deadline passed in the queue with
+        :class:`DeadlineExceeded`, before compute is spent on them; the
+        rest are live."""
+        now = time.perf_counter()
+        live = []
+        for req in batch:
+            dl = req[2]
+            if dl is not None and now >= dl:
+                self._c_deadline.inc()
+                req[4].set_exception(DeadlineExceeded(
+                    f"deadline passed {(now - dl) * 1e3:.1f} ms ago while "
+                    f"queued"))
+            else:
+                live.append(req)
+        return live
 
     def _gather(self, drain: bool = False) -> list:
         batch: list = []
@@ -434,26 +479,10 @@ class BatchingServer:
                 break
         return batch
 
-    def _run_batch(self, batch: list) -> None:
-        """Supervised batch execution: deadline triage, bounded retry on
-        transient failures, resolve-with-error on everything else.  The
-        batcher thread survives every path."""
-        now = time.perf_counter()
-        live = []
-        for req in batch:
-            dl = req[2]
-            if dl is not None and now >= dl:
-                # expired in queue: resolve before compute is wasted
-                self._c_deadline.inc()
-                req[4].set_exception(DeadlineExceeded(
-                    f"deadline passed {(now - dl) * 1e3:.1f} ms ago while "
-                    f"queued"))
-            else:
-                live.append(req)
-        if not live:
-            return
-        self._batch_seq += 1
-        seq = self._batch_seq
+    def _run_batch(self, live: list, seq: int) -> None:
+        """Supervised batch execution: bounded retry on transient
+        failures, resolve-with-error on everything else.  The batcher
+        thread survives every path."""
         attempt = 0
         while True:
             try:
@@ -505,7 +534,7 @@ class BatchingServer:
                 for j, r in enumerate(sub):
                     users[j] = r[0]
                 with obs.span("serve.predict", batch_size=len(sub),
-                              request_class=cls,
+                              batch_seq=seq, request_class=cls,
                               degraded=bool(budget)):
                     scores, items = self._run_padded(jnp.asarray(users),
                                                      budget)
@@ -521,7 +550,7 @@ class BatchingServer:
                     self._h_latency.observe(lat / 1e3)
                     fut.set_result(Recommendation(
                         user=u, items=items[j], scores=scores[j],
-                        latency_ms=lat))
+                        latency_ms=lat, batch_seq=seq))
             compute_s = time.perf_counter() - t_launch
         self._after_batch(seq, compute_s)
 

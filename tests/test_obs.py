@@ -157,6 +157,78 @@ def test_capacity_bound_drops_newest():
     assert obs.dropped_spans() == 0
 
 
+def test_discarded_span_times_but_records_nothing():
+    with obs.span("given.up") as sp:
+        time.sleep(0.005)
+        sp.discard()
+    assert sp.duration >= 0.005
+    assert obs.get_spans() == []
+
+
+# -- the profiler bridge ------------------------------------------------------
+def _profiled(log_dir, body):
+    """Run ``body`` inside a JAX profiler session; the session's host
+    events as ``{name: [(start_ns, end_ns)]}``."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return events
+
+
+def test_span_is_a_host_event_on_the_profilers_clock(tmp_path):
+    def body():
+        with obs.span("t.clock"):
+            time.sleep(0.02)
+
+    events = _profiled(tmp_path, body)
+    (rec,) = obs.get_spans()
+    ((start, end),) = events["t.clock"]
+    assert abs((end - start) / 1e9 - rec.duration) < 2e-3
+
+
+def test_misnested_exit_closes_profiler_annotations_inner_first(tmp_path):
+    def body():
+        outer, inner = obs.span("t.outer"), obs.span("t.inner")
+        outer.__enter__()
+        inner.__enter__()
+        time.sleep(0.005)
+        outer.__exit__(None, None, None)    # closes inner's annotation too
+        inner.__exit__(None, None, None)
+
+    events = _profiled(tmp_path, body)
+    ((o_start, o_end),) = events["t.outer"]
+    ((i_start, i_end),) = events["t.inner"]
+    assert o_start <= i_start < i_end <= o_end
+    assert obs.current_span() is None
+
+
+def test_span_without_a_profiler_session_times_and_records():
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()
+    with obs.span("t.plain", k=1) as sp:
+        time.sleep(0.005)
+    (rec,) = obs.get_spans()
+    assert (rec.name, rec.attrs) == ("t.plain", {"k": 1})
+    assert rec.duration == sp.duration >= 0.005
+
+
 # -- histograms -------------------------------------------------------------
 def test_histogram_single_observation_p50_equals_p99():
     """The small-n estimator fix: one sample must give p50 == p99 == the

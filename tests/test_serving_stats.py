@@ -97,3 +97,42 @@ def test_stats_with_approx_engine(rng):
     assert fut.result(timeout=30).items.shape == (3,)
     server.stop()
     assert server.stats()["n_requests"] == 9
+
+
+def test_one_gather_span_per_batch_after_idling(rng):
+    """An idle batcher polls its empty queue every ``max_wait_ms`` inside
+    one open ``serve.gather`` span, recorded only once a batch forms: one
+    gather per batch, linked to the batch and its answers by
+    ``batch_seq``."""
+    import time
+
+    from repro import obs
+
+    server = BatchingServer(_engine(rng), max_batch=4, max_wait_ms=2.0,
+                            topn=3)
+    obs.clear()
+    server.start()
+    time.sleep(0.05)                      # 25 empty polls
+    results = [f.result(timeout=30) for f in
+               [server.submit(int(u)) for u in rng.integers(0, 64, 6)]]
+    server.stop()
+    spans = [s for s in obs.get_spans()
+             if s.thread_id == server._thread.ident]
+    gathers = [s for s in spans if s.name == "serve.gather"]
+    batches = [s for s in spans if s.name == "serve.batch"]
+    assert batches and len(gathers) == len(batches)
+    assert [g.attrs["batch_seq"] for g in gathers] == \
+        [b.attrs["batch_seq"] for b in batches]
+    assert [g.attrs["batch_size"] for g in gathers] == \
+        [b.attrs["batch_size"] for b in batches]
+    assert gathers[0].duration >= 0.04    # the idle wait, in one span
+    assert all(g.parent_id == 0 for g in gathers)
+    # a request's batch_seq finds its batch's spans
+    by_seq = {b.attrs["batch_seq"]: b for b in batches}
+    predicts = {s.attrs["batch_seq"]: s for s in spans
+                if s.name == "serve.predict"}
+    for r in results:
+        assert r.batch_seq in by_seq and r.batch_seq in predicts
+        assert predicts[r.batch_seq].parent_id == by_seq[r.batch_seq].span_id
+    assert sum(by_seq[q].attrs["batch_size"]
+               for q in {r.batch_seq for r in results}) == len(results)

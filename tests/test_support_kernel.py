@@ -84,6 +84,40 @@ def test_kernel_shortlist_mode_matches_support(rng):
     np.testing.assert_array_equal(outs["support"][1], outs["kernel"][1])
 
 
+def test_kernel_path_stage_spans_and_repair_counts(rng):
+    """On the kernel path the score stage, the wait for host selection and
+    the rerank are spans on the calling thread under
+    ``item_index.recommend``; each ``recommend.select`` counts the rows it
+    selected and those that took the tie-boundary repair."""
+    from repro import obs
+    r = _ratings(rng, 180, 140)
+    eng = CFEngine(r, measure="cosine", k=8, recommend_mode="approx",
+                   item_index_cfg=ItemIndexConfig(
+                       n_clusters=8, seed=0, shortlist=32, score_block=64,
+                       shortlist_mode="kernel", interpret=True)).fit()
+    obs.clear()
+    eng.recommend(n=5)
+    spans = obs.get_spans()
+    (root,) = [s for s in spans if s.name == "item_index.recommend"]
+    for name in ("recommend.score", "recommend.select_wait",
+                 "recommend.rerank"):
+        stage = [s for s in spans if s.name == name]
+        assert stage and all(s.parent_id == root.span_id for s in stage)
+    assert sorted(s.attrs["chunk"] for s in spans
+                  if s.name == "recommend.select_wait") == [0, 1, 2]
+    selects = [s for s in spans if s.name == "recommend.select"]
+    assert sum(s.attrs["rows"] for s in selects) == 180
+    assert all(0 <= s.attrs["repaired"] <= s.attrs["rows"] for s in selects)
+
+    # every score tied: each row takes the repair, and keeps the lowest ids
+    obs.clear()
+    ii = eng.item_index
+    shorts = ii._select_shortlist(np.full((5, 140), 3.0, np.float32), 32)
+    np.testing.assert_array_equal(shorts, np.tile(np.arange(32), (5, 1)))
+    (sel,) = obs.get_spans()
+    assert sel.attrs["repaired"] == sel.attrs["rows"] == 5
+
+
 def test_shortlist_mode_validation():
     with pytest.raises(ValueError):
         ItemClusteredIndex(ItemIndexConfig(shortlist_mode="psychic"))
