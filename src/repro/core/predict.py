@@ -7,7 +7,7 @@ uses:
               ───────────────────────────────────────────────────
                         Σ_{v ∈ N(u), v rated i} |s_uv|
 
-falling back to r̄_u when no selected neighbor rated item i.  Four forms are
+falling back to r̄_u when no selected neighbor rated item i.  Three forms are
 provided:
 
 * ``predict_from_neighbors`` — one-shot gather form; materialises the
@@ -16,11 +16,9 @@ provided:
   ``item_block`` so peak memory is O(m·k·T), never O(m·k·I); bit-identical
   to the one-shot form (the k-reduction per output element is unchanged,
   tiling only splits the independent item axis).  Optionally routes each
-  tile through the fused Pallas kernel (``repro.kernels.predict``);
-* ``predict_items`` — scores only an explicit per-user candidate item list
-  (the exact rerank primitive of the two-stage recommend path), chunked
-  over the candidate axis with the same tile arithmetic, so a full
-  ascending candidate list reproduces the blocked form bit for bit;
+  tile through the fused Pallas kernel (``repro.kernels.predict``).  It is
+  also the exact rerank of the two-stage recommend path, which masks its
+  rows to each user's shortlist;
 * ``predict_dense`` — dense matmul oracle for tests.
 
 ``gather_src`` on the streaming forms accepts a cheaper gather operand for
@@ -174,36 +172,6 @@ def predict_from_neighbors_blocked(ratings: jnp.ndarray, scores: jnp.ndarray,
         else:
             tiles.append(_tile_predict(w, nbr, nb_means, query_means))
     return jnp.concatenate(tiles, axis=1)
-
-
-def predict_items(ratings: jnp.ndarray, scores: jnp.ndarray,
-                  idx: jnp.ndarray, item_ids: jnp.ndarray, *,
-                  means: jnp.ndarray | None = None,
-                  query_means: jnp.ndarray | None = None,
-                  item_block: int = 512,
-                  gather_src: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Predict only the ``(m, M)`` candidate items ``item_ids`` per user —
-    the exact rerank primitive of the two-stage recommend path.
-
-    ``item_ids`` out of ``[0, I)`` (candidate-list padding) are gathered at
-    a clipped position; the caller masks those slots.  Chunked over the
-    candidate axis with the same tile arithmetic as the blocked form, so a
-    full ascending candidate list is bit-identical to it.
-    """
-    safe_idx, w, nb_means, query_means = _neighbor_inputs(
-        ratings, scores, idx, means, query_means)
-    src = ratings if gather_src is None else gather_src
-    n_items = ratings.shape[1]
-    chunks = []
-    for lo in range(0, item_ids.shape[1], item_block):
-        ids = jax.lax.slice_in_dim(item_ids, lo,
-                                   min(lo + item_block, item_ids.shape[1]),
-                                   axis=1)
-        safe_items = jnp.clip(ids, 0, n_items - 1)
-        nbr = src[safe_idx[:, :, None],
-                  safe_items[:, None, :]].astype(jnp.float32)  # (m, k, T)
-        chunks.append(_tile_predict(w, nbr, nb_means, query_means))
-    return jnp.concatenate(chunks, axis=1)
 
 
 def predict_dense(ratings: jnp.ndarray, weight_matrix: jnp.ndarray, *,

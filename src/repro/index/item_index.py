@@ -38,12 +38,14 @@ two-stage idea on the item axis:
      pass is unavailable and as the candidate-pruning stage the cluster
      structure was built for.
 
-4. **Rerank** — only the shortlist is scored with the *true*
-   neighbor-weighted prediction (``repro.core.predict.predict_items``,
-   O(m·k·shortlist) instead of O(m·k·I)), masked to unseen items, and
-   canonically sorted.  Returned scores are exact predictions — identical
-   arithmetic to the dense blocked path — so only the candidate set is
-   approximate.
+4. **Rerank** — the batch's rows are predicted over every item with the
+   *true* neighbor-weighted prediction of the dense blocked path
+   (``repro.core.predict.predict_from_neighbors_blocked``: whole neighbor
+   rows gathered per item tile, O(m·k·I) streamed rather than
+   O(m·k·shortlist) gathered one element at a time), masked to the
+   shortlist's unseen items, and canonically top-n selected.  Returned
+   scores are exact predictions — the dense blocked path's own
+   arithmetic — so only the candidate set is approximate.
 
 With ``n_probe == n_clusters`` and ``shortlist = 0`` (uncapped) the
 shortlist stage is bypassed, the candidate set is every item, and the
@@ -115,7 +117,8 @@ class ItemIndexConfig:
     query_block: int = 256
     score_block: int = 8192               # support-scorer users per chunk
     rerank_block: int = 1024              # support-path rerank batch (the
-                                          # (b, k, shortlist) gather unit)
+                                          # (b, k, item_block) row-gather
+                                          # unit)
     use_kernel: Optional[bool] = None     # None → auto: fused kernel on TPU
     interpret: bool = False
     refit_reassign_frac: float = 0.5      # shared auto-refit drift guard
@@ -263,35 +266,39 @@ def _support_scores_jnp(stacked, nb_scores, nb_idx, q_means):
     return jnp.clip(pred, 1.0, 5.0)
 
 
+def _shortlist_mask(short: np.ndarray, n_items: int) -> np.ndarray:
+    """(b, L) int32 shortlist rows → (b, I) boolean candidate mask.
+
+    Built on the host: the ids land in a (b, I + 1) array, so the
+    ``n_items`` padding sentinel falls in the dropped last column."""
+    mask = np.zeros((short.shape[0], n_items + 1), bool)
+    mask[np.arange(short.shape[0])[:, None], short] = True
+    return mask[:, :n_items]
+
+
 @functools.partial(jax.jit, static_argnames=("n", "item_block"))
 def _rerank_items(ratings, gather_src, nb_scores, nb_idx, means, q_means,
-                  q_ids, cand_items, *, n, item_block):
-    """Exact top-n over per-query candidate item lists.
+                  q_ids, cand_mask, *, n, item_block):
+    """Exact top-n over per-query candidate item masks.
 
-    Predictions come from the same tiled arithmetic as the exact blocked
-    recommend path (``predict_items``); selection is the canonical
-    (-score, item id) sort — which together make the full-candidate case
-    bit-identical to the dense path.  Seen/padding slots get -inf and
-    surface as item id -1, the recommendation contract.
+    The batch's rows are predicted over every item by the exact blocked
+    recommend path's own form (``predict_from_neighbors_blocked``: whole
+    neighbor rows gathered per item tile, never one element per
+    (neighbor, candidate)), then masked to the candidates.  Selection is
+    the canonical (-score, item id) top-n, so the full-candidate case is
+    bit-identical to the dense path.  Seen and non-candidate items get
+    -inf and surface as item id -1, the recommendation contract.
     """
     n_users, n_items = ratings.shape
-    pred = pred_mod.predict_items(ratings, nb_scores, nb_idx, cand_items,
-                                  means=means, query_means=q_means,
-                                  item_block=item_block,
-                                  gather_src=gather_src)
-    safe_items = jnp.clip(cand_items, 0, n_items - 1)
-    rows = ratings[jnp.clip(q_ids, 0, n_users - 1)]
-    seen = jnp.take_along_axis(rows, safe_items, axis=1) > 0
-    invalid = (cand_items < 0) | (cand_items >= n_items) | seen
-    s = jnp.where(invalid, -jnp.inf, pred)
-    ids = cand_items
-    if s.shape[1] < n:
-        s = jnp.pad(s, ((0, 0), (0, n - s.shape[1])),
-                    constant_values=-jnp.inf)
-        ids = jnp.pad(ids, ((0, 0), (0, n - ids.shape[1])),
-                      constant_values=n_items)
-    neg_sorted, idx_sorted = jax.lax.sort((-s, ids), num_keys=2)
-    top_s, top_i = -neg_sorted[:, :n], idx_sorted[:, :n]
+    pred = pred_mod.predict_from_neighbors_blocked(
+        ratings, nb_scores, nb_idx, means=means, query_means=q_means,
+        item_block=item_block, gather_src=gather_src)
+    seen = ratings[jnp.clip(q_ids, 0, n_users - 1)] > 0
+    s = jnp.where(cand_mask & ~seen, pred, -jnp.inf)
+    if n_items < n:
+        s = jnp.pad(s, ((0, 0), (0, n - n_items)), constant_values=-jnp.inf)
+    # reprolint: disable=canonical-selection -- column j is item j and XLA top_k ties break toward the lower index: the canonical (-score, item id) order
+    top_s, top_i = jax.lax.top_k(s, n)
     return top_s, jnp.where(top_s == -jnp.inf, -1, top_i)
 
 
@@ -549,8 +556,8 @@ class ItemClusteredIndex(_SpillClusterCore):
                           rows=blk_rows):
                 s, i = _rerank_items(
                     ratings, gather_src, nbs, nbi, means, q_means, ids_j,
-                    jnp.asarray(short_pad), n=n,
-                    item_block=self.cfg.item_block)
+                    jnp.asarray(_shortlist_mask(short_pad, self.n_items)),
+                    n=n, item_block=self.cfg.item_block)
                 out_s[lo:lo + nv] = np.asarray(s)[:nv]
                 out_i[lo:lo + nv] = np.asarray(i)[:nv]
 
@@ -732,8 +739,8 @@ class ItemClusteredIndex(_SpillClusterCore):
                         s_j, i_j = _rerank_items(
                             ratings, gather_src, nb_scores[safe_j],
                             nb_idx[safe_j], means, means[safe_j], ids_j,
-                            jnp.asarray(sh_pad), n=n,
-                            item_block=self.cfg.item_block)
+                            jnp.asarray(_shortlist_mask(sh_pad, n_items)),
+                            n=n, item_block=self.cfg.item_block)
                         out_s[lo + b0:lo + b0 + nv] = np.asarray(s_j)[:nv]
                         out_i[lo + b0:lo + b0 + nv] = np.asarray(i_j)[:nv]
 

@@ -48,18 +48,6 @@ def test_blocked_predict_int8_gather_src_is_exact(rng):
     np.testing.assert_array_equal(dense, blocked)
 
 
-def test_predict_items_matches_blocked_on_full_list(rng):
-    """An ascending full candidate list through the per-item predictor is
-    the blocked form, bit for bit — the degenerate-mode linchpin."""
-    r = _ratings(rng, 80, 70)
-    scores, idx = nb.topk_neighbors(r, 6, measure="cosine", block_size=16)
-    items = jnp.broadcast_to(jnp.arange(70)[None, :], (80, 70))
-    full = np.asarray(pr.predict_items(r, scores, idx, items, item_block=32))
-    blocked = np.asarray(pr.predict_from_neighbors_blocked(
-        r, scores, idx, item_block=32))
-    np.testing.assert_array_equal(full, blocked)
-
-
 def test_fused_tile_predict_matches_oracle(rng):
     """Interpret-mode kernel vs the jnp oracle (and the core tile)."""
     r = _ratings(rng, 37, 100)
