@@ -273,9 +273,10 @@ def _np_ratings(u=8, d=6, seed=0):
 
 def _common():
     import jax.numpy as jnp
+    from repro.core.predict import make_gather_source
     r8 = _np_ratings()
     ratings = jnp.asarray(r8, jnp.float32)
-    r_gather = jnp.asarray(r8)                       # int8 gather source
+    r_gather = make_gather_source(ratings)           # int8 gather code
     norms = jnp.sqrt(jnp.sum(ratings * ratings, -1))
     counts = jnp.sum(ratings > 0, -1).astype(jnp.float32)
     return r_gather, ratings, norms, counts
@@ -329,6 +330,23 @@ def _build_fused_rerank_block():
                                  "q_ids", "shorts")
 
 
+def _build_fused_rerank_whole():
+    import jax.numpy as jnp
+    from repro.index import clustered as cl
+    fn = cl._fused_rerank_whole
+
+    def make_args():
+        r_gather, _, norms, counts = _common()
+        q_ids = jnp.asarray([0, 3], jnp.int32)
+        shorts = jnp.asarray([[1, 2, 8], [4, 5, 8]], jnp.int32)
+        return (r_gather, norms, counts, q_ids, shorts)
+
+    call = functools.partial(fn, k=2, measure="pcc_sig", beta=50.0,
+                             use_pallas=False, interpret=False)
+    return fn, call, make_args, ("r_gather", "norms", "counts", "q_ids",
+                                 "shorts")
+
+
 def _build_rerank_sparse():
     import jax.numpy as jnp
     from repro.index import clustered as cl
@@ -356,7 +374,7 @@ def _build_rerank_scores_xla():
     def make_args():
         r_gather, ratings, norms, counts = _common()
         q_vals = ratings[:2]
-        cand_rows = r_gather[:4]                     # int8, as the fused
+        cand_rows = r_gather.code[:4]                # int8, as the fused
         return (q_vals, cand_rows, norms[:4], counts[:4])
 
     call = functools.partial(fn, measure="pcc_sig", beta=50.0)
@@ -394,7 +412,7 @@ def _build_fused_support_scores():
         q_means = jnp.asarray([3.0, 2.5], jnp.float32)
         return (dev, msk, nb_idx, nb_w, q_means)
 
-    call = functools.partial(fn, bt=4, interpret=True)
+    call = functools.partial(fn, scale=(1.0, 5.0), bt=4, interpret=True)
     return fn, call, make_args, ("dev", "msk", "nb_idx", "nb_w", "q_means")
 
 
@@ -408,6 +426,8 @@ HOT_PATHS: Tuple[HotPath, ...] = (
             "src/repro/index/clustered.py", _build_fused_scan_restricted),
     HotPath("index.clustered._fused_rerank_block",
             "src/repro/index/clustered.py", _build_fused_rerank_block),
+    HotPath("index.clustered._fused_rerank_whole",
+            "src/repro/index/clustered.py", _build_fused_rerank_whole),
     HotPath("index.clustered._rerank_sparse",
             "src/repro/index/clustered.py", _build_rerank_sparse),
     HotPath("kernels.rerank.rerank_scores_xla",

@@ -119,13 +119,15 @@ def sharded_predict(ratings: jnp.ndarray, scores: jnp.ndarray,
                     ) -> jnp.ndarray:
     """Mean-centered neighbor prediction with query users sharded over ``axis``."""
     means = user_means(ratings)
+    scale = pred_mod.rating_scale(ratings)
 
     def per_shard(scores_blk, idx_blk, all_ratings, all_means):
         i = jax.lax.axis_index(axis)
         m = scores_blk.shape[0]
         qm = jax.lax.dynamic_slice_in_dim(all_means, i * m, m)
         return pred_mod.predict_from_neighbors(
-            all_ratings, scores_blk, idx_blk, means=all_means, query_means=qm)
+            all_ratings, scores_blk, idx_blk, means=all_means, query_means=qm,
+            scale=scale)
 
     f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None), P(axis, None),
@@ -162,6 +164,11 @@ def ring_sharded_predict(ratings: jnp.ndarray, scores: jnp.ndarray,
         g_cnt = jax.lax.psum(loc_cnt, axis)
         g_tot = jax.lax.psum(loc_tot, axis)
         global_mean = g_tot / jnp.maximum(g_cnt, 1)
+        # the rating scale: smallest and largest rated value of any shard
+        rated = q_ratings > 0
+        lo = jax.lax.pmin(jnp.min(jnp.where(rated, q_ratings, jnp.inf)), axis)
+        hi = jax.lax.pmax(jnp.max(jnp.where(rated, q_ratings, -jnp.inf)),
+                          axis)
 
         def means_of(block):
             mask = block > 0
@@ -193,7 +200,7 @@ def ring_sharded_predict(ratings: jnp.ndarray, scores: jnp.ndarray,
         (num, den, _), _ = jax.lax.scan(body, init, jnp.arange(axis_size))
         pred = my_means[:, None] + num / jnp.maximum(den, 1e-8)
         pred = jnp.where(den > 1e-8, pred, my_means[:, None])
-        return jnp.clip(pred, 1.0, 5.0)
+        return jnp.where(lo <= hi, jnp.clip(pred, lo, hi), pred)
 
     f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(P(axis, None), P(axis, None), P(axis, None)),

@@ -74,6 +74,14 @@ RECOMMEND_MODES = ("exact", "approx")
 USER_BLOCK = 1024
 ITEM_BLOCK = 512
 
+# share of the users each query of the auto user index exactly reranks:
+# at ML-10M's 69,878 users a 0.15 shortlist holds recall@40 at 0.58-0.76
+# on the chip, under the 0.70 that deployment states; 0.4 holds it near
+# 0.9 (PERF.md).  Once a query block's shortlists can cover every user
+# the fit scores the whole matrix anyway, so only the top-M selection
+# grows with it.
+AUTO_RERANK_FRAC = 0.4
+
 
 def _bucket(n: int, cap: int) -> int:
     """Next power of two ≥ n (≥ 8), capped — bounds distinct compile shapes."""
@@ -210,7 +218,8 @@ class CFEngine:
         the approx cache is bit-identical to the exact one.
     index_cfg : optional :class:`repro.index.IndexConfig`; default auto
         (feature geometry follows ``measure``: mean-centered rows for pcc,
-        raw rows for cosine/jaccard).
+        raw rows for cosine/jaccard; shortlists of ``AUTO_RERANK_FRAC`` of
+        the users).
     interpret : force Pallas interpret mode; default auto (on unless TPU).
     """
 
@@ -285,7 +294,7 @@ class CFEngine:
             if index_cfg is None:
                 index_cfg = IndexConfig(
                     features="centered" if measure in ("pcc", "pcc_sig")
-                    else "raw")
+                    else "raw", rerank_frac=AUTO_RERANK_FRAC)
             self.index = ClusteredIndex(index_cfg, mesh=self.mesh,
                                         mesh_axis=self.axis)
 
@@ -305,7 +314,7 @@ class CFEngine:
         self._cnt = None                             # (U,) rated-item counts
         self._tot = None                             # (U,) rating sums
         self._snapshot: Optional[tuple] = None       # atomically-published
-        self._gather_cache: Optional[tuple] = None   # int8 recommend operand
+        self._gather_cache: Optional[tuple] = None   # gather code + scale
         # ratings version counter: every update_ratings bumps it, and the
         # derived per-ratings caches (the gather operand here, the CSR /
         # pair-table / support caches inside the indexes) are delta-patched
@@ -725,9 +734,11 @@ class CFEngine:
         return self
 
     def _gather_source(self, ratings):
-        """int8 gather operand for the recommend/predict gathers when the
-        matrix round-trips exactly (cached per ratings array — a rating
-        update replaces the array, which invalidates by identity).
+        """Gather operand of the recommend/predict gathers
+        (``predict.GatherSource``: an int8 code when the matrix round-trips
+        exactly) and the engine's rating scale, derived once per ratings
+        array and only widened by updates (cached per ratings array — a
+        rating update replaces the array, which invalidates by identity).
 
         Read the cache reference ONCE: the serving batcher calls this
         while ``update_ratings`` may swap ``_gather_cache`` on the writer

@@ -65,8 +65,8 @@ from repro.index.kmeans import (KMeansStats, center_rows, kmeans,
                                 normalize_rows)
 from repro.kernels import select as sel_mod
 from repro.kernels.cluster import centroid_distances
-from repro.kernels.rerank import (fused_rerank_scores, rerank_scores_host,
-                                  rerank_scores_xla)
+from repro.kernels.rerank import (BK, BN, fused_rerank_scores,
+                                  rerank_scores_host, rerank_scores_xla)
 
 try:                # optional host fast path for the proxy scan: torch's
                     # CPU mm/topk are multithreaded and topk selects k
@@ -536,10 +536,11 @@ def _rerank_sparse(r_gather, norms, counts, q_ids, q_items, q_vals,
     a candidate lives on the query's *rated* items, so instead of gathering
     full (M, D) candidate rows we gather the (M, nnz) sub-block
     ``ratings[cand, items_q]`` — O(M·nnz) traffic instead of O(M·D).
-    ``r_gather`` is the rating matrix as the gather source: int8 when every
-    rating is a small integer (MovieLens 1..5 — the gather is element-count
-    bound and int8 moves ~4× faster on CPU; the cast back to f32 is exact),
-    f32 otherwise.
+    ``r_gather`` is the rating matrix as the gather source
+    (``predict.GatherSource``): an int8 code when every rating is a small
+    multiple of a power-of-two step (MovieLens stars and half stars — the
+    gather is element-count bound and int8 moves ~4× faster on CPU; the
+    decode back to f32 is exact), f32 otherwise.
 
     ``q_items``/``q_vals``: (b, nnz) the query's rated item ids and values,
     zero-padded (a zero value knocks the slot out of every term, since each
@@ -550,8 +551,8 @@ def _rerank_sparse(r_gather, norms, counts, q_ids, q_items, q_vals,
     """
     n_users = r_gather.shape[0]
     safe_c = jnp.clip(cand_ids, 0, n_users - 1)
-    rc = r_gather[safe_c[:, :, None], q_items[:, None, :]
-                  ].astype(jnp.float32)                      # (b, M, nnz)
+    rc = r_gather.rows((safe_c[:, :, None],
+                        q_items[:, None, :]))                # (b, M, nnz)
     vq = q_vals                                              # (b, nnz)
     vq_pos = (vq > 0).astype(jnp.float32)
     mc = (rc > 0).astype(jnp.float32)
@@ -616,7 +617,7 @@ def _pair_scores_sparse(r_gather, norms, counts, tbl_items, tbl_vals,
     it = tbl_items[w_local]                                  # (P, nnz)
     vq = tbl_vals[w_local]
     safe_v = jnp.clip(v_ids, 0, n_users - 1)
-    rc = r_gather[safe_v[:, None], it].astype(jnp.float32)   # (P, nnz)
+    rc = r_gather.rows((safe_v[:, None], it))                # (P, nnz)
     vq_pos = (vq > 0).astype(jnp.float32)
     mc = (rc > 0).astype(jnp.float32)
     eps = 1e-8
@@ -726,6 +727,33 @@ def _fused_scan_restricted(proxies, cand_pad, q_ids, *, m):
     return v, shorts.astype(jnp.int32)
 
 
+def _gram_scores(q_rows, r_gather, cand, norms, counts, *, measure, beta,
+                 use_pallas, interpret):
+    """(b, J) query rows against the candidate code rows ``cand`` of
+    ``r_gather``: the fused co-rated Gram kernel, or its XLA twin."""
+    score = fused_rerank_scores if use_pallas else rerank_scores_xla
+    kw = dict(interpret=interpret) if use_pallas else {}
+    return score(q_rows, cand, norms, counts, measure=measure, beta=beta,
+                 cand_step=r_gather.factor, **kw)
+
+
+def _restrict_topk(sc, q_ids, shorts, n, k):
+    """Scores of each query's own shortlist ``sc`` (b, M) → canonical
+    ``(-score, id)`` top-k; sentinel and self slots are masked and NEG_INF
+    slots surface as id -1 like every exact path."""
+    invalid = (shorts >= n) | (shorts == q_ids[:, None])
+    sc = jnp.where(invalid, nb.NEG_INF, sc)
+    ci = jnp.where(invalid, n, shorts)
+    if sc.shape[1] < k:
+        sc = jnp.pad(sc, ((0, 0), (0, k - sc.shape[1])),
+                     constant_values=nb.NEG_INF)
+        ci = jnp.pad(ci, ((0, 0), (0, k - ci.shape[1])),
+                     constant_values=n)
+    neg_sorted, idx_sorted = jax.lax.sort((-sc, ci), num_keys=2)
+    top_s, top_i = -neg_sorted[:, :k], idx_sorted[:, :k]
+    return top_s, jnp.where(top_s <= nb.NEG_INF, -1, top_i)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "ku", "k", "measure", "beta", "use_pallas", "interpret"))
 def _fused_rerank_block(r_gather, ratings, norms, counts, q_ids, shorts, *,
@@ -741,37 +769,60 @@ def _fused_rerank_block(r_gather, ratings, norms, counts, q_ids, shorts, *,
     Scoring the union — a superset of each query's shortlist — changes
     nothing: the result is defined by the ``searchsorted`` restriction
     back to each query's own shortlist, and every Gram statistic is
-    exact (bit-identical to the sparse gather walk for integer rating
-    matrices).  The epilogue is the canonical ``(-score, id)`` sort;
-    NEG_INF slots surface as id -1 like every exact path.
+    exact (bit-identical to the sparse gather walk for integer and
+    half-star rating matrices).  Where the union can reach every row,
+    :func:`_fused_rerank_whole` scores the matrix without the union.
     """
     n = r_gather.shape[0]
     u = jnp.unique(shorts, size=ku, fill_value=n)
     safe_u = jnp.minimum(u, n - 1)
     q_rows = ratings[jnp.minimum(q_ids, n - 1)]
-    if use_pallas:
-        s = fused_rerank_scores(q_rows, r_gather[safe_u], norms[safe_u],
-                                counts[safe_u], measure=measure,
-                                beta=beta, interpret=interpret)
-    else:
-        s = rerank_scores_xla(q_rows, r_gather[safe_u], norms[safe_u],
-                              counts[safe_u], measure=measure, beta=beta)
+    s = _gram_scores(q_rows, r_gather, r_gather.code[safe_u], norms[safe_u],
+                     counts[safe_u], measure=measure, beta=beta,
+                     use_pallas=use_pallas, interpret=interpret)
     # restriction: every real shortlist id is present in the union, so
     # searchsorted lands exactly on its column; sentinel slots are masked
     # (never gathered as row 0 — the clamp below is for the pad columns)
     col = jnp.clip(jnp.searchsorted(u, shorts), 0, ku - 1)
-    sc = jnp.take_along_axis(s, col, axis=1)
-    invalid = (shorts >= n) | (shorts == q_ids[:, None])
-    sc = jnp.where(invalid, nb.NEG_INF, sc)
-    ci = jnp.where(invalid, n, shorts)
-    if sc.shape[1] < k:
-        sc = jnp.pad(sc, ((0, 0), (0, k - sc.shape[1])),
-                     constant_values=nb.NEG_INF)
-        ci = jnp.pad(ci, ((0, 0), (0, k - ci.shape[1])),
-                     constant_values=n)
-    neg_sorted, idx_sorted = jax.lax.sort((-sc, ci), num_keys=2)
-    top_s, top_i = -neg_sorted[:, :k], idx_sorted[:, :k]
-    return top_s, jnp.where(top_s <= nb.NEG_INF, -1, top_i)
+    return _restrict_topk(jnp.take_along_axis(s, col, axis=1), q_ids,
+                          shorts, n, k)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "k", "measure", "beta", "use_pallas", "interpret"))
+def _fused_rerank_whole(r_gather, norms, counts, q_ids, shorts, *, k,
+                        measure, beta, use_pallas, interpret):
+    """:func:`_fused_rerank_block` where the block's union can hold every
+    row (``bq·M ≥ U``): the block is scored against all ``U`` rows of the
+    gather code — no ``unique``, no row gather, no padding rows — and
+    column ``j`` of the scores is user ``j``, so each shortlist id is its
+    own column.  Bit-identical to the union path: the kernel computes each
+    (query, candidate) statistic the same way whatever else is in the
+    block.  The query rows are decoded from the code, so the f32 matrix is
+    no operand.  ``r_gather``'s code may be padded past ``U`` rows and
+    ``I`` columns (:func:`_whole_operand`); ``norms`` and ``counts`` give
+    ``U``.
+    """
+    n = norms.shape[0]
+    q_rows = r_gather.rows(jnp.minimum(q_ids, n - 1))
+    s = _gram_scores(q_rows, r_gather, r_gather.code, norms, counts,
+                     measure=measure, beta=beta, use_pallas=use_pallas,
+                     interpret=interpret)
+    sc = jnp.take_along_axis(s, jnp.minimum(shorts, n - 1), axis=1)
+    return _restrict_topk(sc, q_ids, shorts, n, k)
+
+
+@jax.jit
+def _whole_operand(r_gather):
+    """The gather code padded to the Gram kernel's whole tiles (rows of
+    ``min(BN, U)``, columns of ``min(BK, I)``, as the kernel pads), so the
+    kernel uses its candidate operand as it is: padded once per query
+    call, not copied in every block."""
+    code = r_gather.code
+    n, j = code.shape
+    pad = ((0, -n % min(BN, n)), (0, -j % min(BK, j)))
+    return pred_mod.GatherSource(jnp.pad(code, pad), r_gather.step,
+                                 r_gather.scale)
 
 
 class _SpillClusterCore:
@@ -925,9 +976,10 @@ class _SpillClusterCore:
         return p_np
 
     def _gather_source(self, ratings):
-        """Rerank gather operand (``predict.make_gather_source``: int8
-        when exact), cached per ratings array — a rating update replaces
-        the array, which invalidates by identity."""
+        """Rerank gather operand and rating scale
+        (``predict.make_gather_source``: an int8 code when exact), cached
+        per ratings array — a rating update replaces the array, which
+        invalidates by identity."""
         if self._gather_cache is not None and \
                 self._gather_cache[0] is ratings:
             return self._gather_cache[1]
@@ -2078,6 +2130,12 @@ class ClusteredIndex(_SpillClusterCore):
         m = min(max_rerank, n)
         r_gather = self._gather_source(ratings)
         norms, counts = _user_norms_counts(ratings)
+        # a block's candidate union can reach every row: score the whole
+        # matrix (the union ``bq·m`` ids can hold is at least ``n``)
+        whole = bq * m >= n
+        r_whole = (_whole_operand(r_gather) if whole and use_pallas
+                   else r_gather)
+        union_rows = obs.registry().counter("index.rerank_union_rows")
         n_probed = 0
         n_reranked = 0
         t_rerank = 0.0
@@ -2138,17 +2196,24 @@ class ClusteredIndex(_SpillClusterCore):
             # the count sync below also fences the scan, so its cost
             # lands in the shortlist stage (rerank timing starts after)
             n_reranked += int(jnp.sum(shorts[:nv] < n))
-            ku = _bucket(min(bq * shorts.shape[1], n) + 1)
-            # union gather + Gram rerank run inside one jitted call; the
-            # host copy of the outputs is the fence that keeps the span
-            # honest about device time
-            with obs.span("query.rerank", kind="fused", block=lo // bq,
-                          ku=ku) as rsp:
-                s, i = _fused_rerank_block(r_gather, ratings, norms, counts,
-                                           ids_j, shorts, ku=ku, k=k,
-                                           measure=measure, beta=beta,
-                                           use_pallas=use_pallas,
-                                           interpret=interpret)
+            # union gather (or the whole matrix) + Gram rerank run inside
+            # one jitted call; the host copy of the outputs is the fence
+            # that keeps the span honest about device time
+            if whole:
+                rsp = obs.span("query.rerank", kind="whole",
+                               block=lo // bq, rows=n)
+                call = functools.partial(_fused_rerank_whole, r_whole)
+            else:
+                ku = _bucket(min(bq * shorts.shape[1], n) + 1)
+                rsp = obs.span("query.rerank", kind="fused",
+                               block=lo // bq, ku=ku)
+                call = functools.partial(_fused_rerank_block, r_gather,
+                                         ratings, ku=ku)
+                union_rows.inc(ku)
+            with rsp:
+                s, i = call(norms, counts, ids_j, shorts, k=k,
+                            measure=measure, beta=beta,
+                            use_pallas=use_pallas, interpret=interpret)
                 out_s[lo:lo + bq] = np.asarray(s)[:nv]
                 out_i[lo:lo + bq] = np.asarray(i)[:nv]
             t_rerank += rsp.duration
@@ -2387,10 +2452,10 @@ class ClusteredIndex(_SpillClusterCore):
                 cu_j = jnp.asarray(np.pad(cu, (0, kb - len(cu)),
                                           constant_values=cu[0]))
                 s = np.asarray(fused_rerank_scores(
-                    ratings[jnp.asarray(q_pad)], r_gather[cu_j],
+                    ratings[jnp.asarray(q_pad)], r_gather.code[cu_j],
                     norms[cu_j], counts[cu_j], measure=measure,
-                    beta=beta, interpret=self.cfg.interpret)
-                    )[:len(gs), :len(cu)]
+                    beta=beta, cand_step=r_gather.factor,
+                    interpret=self.cfg.interpret))[:len(gs), :len(cu)]
             else:
                 s = rerank_scores_host(
                     rnp[q], np.take(rnp, cu, axis=0),

@@ -250,8 +250,8 @@ def _support_csr(rnp: np.ndarray, means_np: np.ndarray):
     return _scipy_sparse.hstack([dev, mask], format="csr")
 
 
-@jax.jit
-def _support_scores_jnp(stacked, nb_scores, nb_idx, q_means):
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _support_scores_jnp(stacked, nb_scores, nb_idx, q_means, *, scale):
     """jnp fallback for the support scorer (no scipy): gather the (b, k,
     2I) stacked rows and reduce — exact same num/den epilogue, element-
     bound instead of row-major."""
@@ -263,7 +263,17 @@ def _support_scores_jnp(stacked, nb_scores, nb_idx, q_means):
     num, den = nd[:, :n_items], nd[:, n_items:]
     pred = q_means[:, None] + num / jnp.maximum(den, 1e-8)
     pred = jnp.where(den > 1e-8, pred, q_means[:, None])
-    return jnp.clip(pred, 1.0, 5.0)
+    return pred_mod.clip_to_scale(pred, scale)
+
+
+def _rerank_attrs(gather_src, rows: int, k: int, n_items: int,
+                  item_block: int) -> dict:
+    """Attributes of a ``recommend.rerank`` span: its query rows, and
+    the neighbor-row bytes the call reads from the gather source — each
+    row's ``k`` neighbors over the whole item tiles at the code's width."""
+    width = -(-n_items // item_block) * item_block
+    return {"rows": rows, "gather_bytes": rows * k * width
+            * gather_src.code.dtype.itemsize}
 
 
 def _shortlist_mask(short: np.ndarray, n_items: int) -> np.ndarray:
@@ -293,7 +303,15 @@ def _rerank_items(ratings, gather_src, nb_scores, nb_idx, means, q_means,
     pred = pred_mod.predict_from_neighbors_blocked(
         ratings, nb_scores, nb_idx, means=means, query_means=q_means,
         item_block=item_block, gather_src=gather_src)
-    seen = ratings[jnp.clip(q_ids, 0, n_users - 1)] > 0
+    # the query rows' rated items, read from the item tiles of the gather
+    # code that the prediction slices: no row gather of a (U, I) operand,
+    # which a column-major device layout (the TPU's choice at ML-10M's
+    # shape) would copy whole
+    q = jnp.clip(q_ids, 0, n_users - 1)
+    seen = jnp.concatenate(
+        [jax.lax.slice_in_dim(gather_src.code, lo,
+                              min(lo + item_block, n_items), axis=1)[q] > 0
+         for lo in range(0, n_items, item_block)], axis=1)
     s = jnp.where(cand_mask & ~seen, pred, -jnp.inf)
     if n_items < n:
         s = jnp.pad(s, ((0, 0), (0, n - n_items)), constant_values=-jnp.inf)
@@ -553,7 +571,10 @@ class ItemClusteredIndex(_SpillClusterCore):
             n_reranked += blk_rows
 
             with obs.span("recommend.rerank", block=lo // bq,
-                          rows=blk_rows):
+                          candidates=blk_rows,
+                          **_rerank_attrs(gather_src, nv, nbi.shape[1],
+                                          self.n_items,
+                                          self.cfg.item_block)):
                 s, i = _rerank_items(
                     ratings, gather_src, nbs, nbi, means, q_means, ids_j,
                     jnp.asarray(_shortlist_mask(short_pad, self.n_items)),
@@ -567,9 +588,10 @@ class ItemClusteredIndex(_SpillClusterCore):
         return jnp.asarray(out_s), jnp.asarray(out_i)
 
     def _score_select_rows(self, stacked, w, safe_idx, q_means, seen_rows,
-                           m_short: int) -> np.ndarray:
-        """Score one row chunk (exact f32 num/den, clip epilogue, seen →
-        -inf) and select its canonical top-``m_short`` items.
+                           m_short: int, scale: tuple) -> np.ndarray:
+        """Score one row chunk (exact f32 num/den, clip to ``scale``
+        epilogue, seen → -inf) and select its canonical top-``m_short``
+        items.
 
         Selection exactness without a full-width composite-key pass: a
         plain f32 argpartition is canonical except when the *cut value*
@@ -583,10 +605,12 @@ class ItemClusteredIndex(_SpillClusterCore):
         """
         with obs.span("recommend.score", rows=int(w.shape[0])):
             return self._score_select_rows_body(stacked, w, safe_idx,
-                                                q_means, seen_rows, m_short)
+                                                q_means, seen_rows, m_short,
+                                                scale)
 
     def _score_select_rows_body(self, stacked, w, safe_idx, q_means,
-                                seen_rows, m_short: int) -> np.ndarray:
+                                seen_rows, m_short: int,
+                                scale: tuple) -> np.ndarray:
         n_items = self.n_items
         if _scipy_sparse is not None:
             rows = np.repeat(np.arange(w.shape[0]), w.shape[1])
@@ -600,12 +624,14 @@ class ItemClusteredIndex(_SpillClusterCore):
             np.maximum(den, 1e-8, out=den)
             np.divide(num, den, out=num)
             num += qm
-            np.clip(num, 1.0, 5.0, out=num)
+            if scale[0] <= scale[1]:
+                np.clip(num, *scale, out=num)
             np.copyto(num, np.broadcast_to(qm, num.shape), where=fallback)
         else:
             num = np.asarray(_support_scores_jnp(
                 jnp.asarray(stacked), jnp.asarray(w),
-                jnp.asarray(safe_idx), jnp.asarray(q_means))).copy()
+                jnp.asarray(safe_idx), jnp.asarray(q_means),
+                scale=scale)).copy()
         num[seen_rows] = -np.inf
         return self._select_shortlist(num, m_short)
 
@@ -667,6 +693,7 @@ class ItemClusteredIndex(_SpillClusterCore):
         m_short = min(max(n, self.cfg.shortlist if shortlist is None
                           else shortlist), n_items)
         gather_src = self._gather_source(ratings)
+        scale = gather_src.scale
         rnp = np.asarray(ratings)
         means_np = np.asarray(means)
         sc_np = np.asarray(nb_scores)
@@ -692,7 +719,7 @@ class ItemClusteredIndex(_SpillClusterCore):
                     dev, msk = self._support_dense(ratings, means)
                     num = np.asarray(fused_support_scores(
                         dev, msk, jnp.asarray(safe), jnp.asarray(w),
-                        means[jnp.asarray(ids)],
+                        means[jnp.asarray(ids)], scale=scale,
                         interpret=self.cfg.interpret))[:, :n_items].copy()
                     num[seen] = -np.inf
                 return [pool.submit(self._select_shortlist,
@@ -701,7 +728,7 @@ class ItemClusteredIndex(_SpillClusterCore):
             parts = [pool.submit(
                 self._score_select_rows, stacked, w[h0:h0 + half],
                 safe[h0:h0 + half], means_np[ids[h0:h0 + half]],
-                seen[h0:h0 + half], m_short)
+                seen[h0:h0 + half], m_short, scale)
                 for h0 in range(0, len(ids), half)]
             return parts
 
@@ -735,7 +762,11 @@ class ItemClusteredIndex(_SpillClusterCore):
                     sh_pad = np.full((bq, m_short), n_items, np.int32)
                     sh_pad[:nv] = shorts[b0:b0 + nv]
                     with obs.span("recommend.rerank", chunk=ci,
-                                  rows=int((sh_pad[:nv] < n_items).sum())):
+                                  candidates=int((sh_pad[:nv]
+                                                  < n_items).sum()),
+                                  **_rerank_attrs(gather_src, nv,
+                                                  nb_idx.shape[1], n_items,
+                                                  self.cfg.item_block)):
                         s_j, i_j = _rerank_items(
                             ratings, gather_src, nb_scores[safe_j],
                             nb_idx[safe_j], means, means[safe_j], ids_j,

@@ -5,7 +5,7 @@ One tile of the mean-centered weighted-deviation predictor
 
     num[m, t] = Σ_k w[m,k] · (nbr[m,k,t] − nb_mean[m,k]) · 1[nbr > 0]
     den[m, t] = Σ_k w[m,k] · 1[nbr[m,k,t] > 0]
-    pred      = clip(q_mean[m] + num/den, 1, 5)   (q_mean when den == 0)
+    pred      = clip(q_mean[m] + num/den, lo, hi)  (q_mean when den == 0)
 
 XLA materialises the mask and deviation tensors as separate (m, k, T)
 HBM intermediates; the fused kernel keeps one VMEM-resident pass over the
@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.predict import clip_to_scale
 from repro.kernels.similarity import _pad_to
 
 # default tile sizes: bm·k·bt f32 must sit comfortably in VMEM
@@ -37,7 +38,7 @@ BM, BT = 128, 512
 _DEN_EPS = 1e-8
 
 
-def _predict_kernel(nbr_ref, w_ref, nbm_ref, qm_ref, out_ref):
+def _predict_kernel(nbr_ref, w_ref, nbm_ref, qm_ref, out_ref, *, scale):
     nbr = nbr_ref[...].astype(jnp.float32)        # (bm, k, bt)
     w = w_ref[...].astype(jnp.float32)            # (bm, k)
     nbm = nbm_ref[...].astype(jnp.float32)        # (bm, k)
@@ -48,19 +49,21 @@ def _predict_kernel(nbr_ref, w_ref, nbm_ref, qm_ref, out_ref):
     den = jnp.sum(w[:, :, None] * mask, axis=1)
     pred = qm + num / jnp.maximum(den, _DEN_EPS)
     pred = jnp.where(den > _DEN_EPS, pred, qm)
-    out_ref[...] = jnp.clip(pred, 1.0, 5.0)
+    out_ref[...] = clip_to_scale(pred, scale)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bt", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bm", "bt",
+                                             "interpret"))
 def fused_tile_predict(nbr: jnp.ndarray, w: jnp.ndarray,
                        nb_means: jnp.ndarray, q_means: jnp.ndarray, *,
-                       bm: int = BM, bt: int = BT,
+                       scale: tuple, bm: int = BM, bt: int = BT,
                        interpret: bool = False) -> jnp.ndarray:
     """(m, k, T) gathered neighbor tile → (m, T) predictions.
 
     ``w`` must already be the masked weights (invalid/negative-score
     neighbors at 0 — a zero weight cancels in both reductions, which is
-    also why the k padding below is harmless).
+    also why the k padding below is harmless); ``scale``: the ``(lo, hi)``
+    clip (``repro.core.predict.rating_scale``).
     """
     m, k, t = nbr.shape
     bm_, bt_ = min(bm, m), min(bt, t)
@@ -73,7 +76,7 @@ def fused_tile_predict(nbr: jnp.ndarray, w: jnp.ndarray,
     grid = (mp // bm_, tp // bt_)
 
     out = pl.pallas_call(
-        _predict_kernel,
+        functools.partial(_predict_kernel, scale=scale),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, kp, bt_), lambda i, j: (i, 0, j)),

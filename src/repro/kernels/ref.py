@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import similarity as core_sim
+from repro.core.predict import clip_to_scale
 
 
 # -- fused pairwise similarity ------------------------------------------------
@@ -33,11 +34,13 @@ def similarity_ref(ra: jnp.ndarray, rb: jnp.ndarray, measure: str = "all"):
 def rerank_scores_ref(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
                       cand_norms: jnp.ndarray, cand_counts: jnp.ndarray,
                       measure: str = "cosine",
-                      beta: float | None = None) -> jnp.ndarray:
+                      beta: float | None = None,
+                      cand_step: float = 1.0) -> jnp.ndarray:
     """(G, J) query rows × (Kc, J) candidate-union rows → (G, Kc) exact
     similarity under ``measure``, with full-row candidate norms/counts
     passed in (the union block may be item-compressed, so they cannot be
-    derived from it).  Oracle for
+    derived from it); ``cand_rows`` may be a gather code, decoded by the
+    power-of-two ``cand_step``.  Oracle for
     ``repro.kernels.rerank.fused_rerank_scores`` and its host BLAS twin;
     the same sparse num/den formulas as the index's ``_rerank_sparse``.
     """
@@ -45,6 +48,8 @@ def rerank_scores_ref(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
     beta = core_sim.resolve_beta(beta)
     vq = q_vals.astype(jnp.float32)
     rc = cand_rows.astype(jnp.float32)
+    if cand_step != 1.0:
+        rc = rc * cand_step
     mq = (vq > 0).astype(jnp.float32)
     mc = (rc > 0).astype(jnp.float32)
     dot_kw = dict(precision=jax.lax.Precision.HIGHEST)
@@ -78,7 +83,8 @@ def rerank_scores_ref(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
 
 def support_scores_ref(dev: jnp.ndarray, msk: jnp.ndarray,
                        nb_idx: jnp.ndarray, nb_w: jnp.ndarray,
-                       q_means: jnp.ndarray) -> jnp.ndarray:
+                       q_means: jnp.ndarray, *, scale: tuple
+                       ) -> jnp.ndarray:
     """(U, I) deviation/mask tables, (b, k) masked neighbor weights/ids →
     (b, I) exact clipped predictions.  Oracle for
     ``repro.kernels.support.fused_support_scores``; the same num/den
@@ -91,7 +97,7 @@ def support_scores_ref(dev: jnp.ndarray, msk: jnp.ndarray,
     den = jnp.einsum("bk,bki->bi", nb_w, rows_m)
     pred = q_means[:, None] + num / jnp.maximum(den, 1e-8)
     pred = jnp.where(den > 1e-8, pred, q_means[:, None])
-    return jnp.clip(pred, 1.0, 5.0)
+    return clip_to_scale(pred, scale)
 
 
 # -- blockwise top-M select ---------------------------------------------------
@@ -143,7 +149,7 @@ def centroid_distances_ref(x: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
 # -- fused tile predict -------------------------------------------------------
 
 def tile_predict_ref(nbr: jnp.ndarray, w: jnp.ndarray, nb_means: jnp.ndarray,
-                     q_means: jnp.ndarray) -> jnp.ndarray:
+                     q_means: jnp.ndarray, *, scale: tuple) -> jnp.ndarray:
     """(m, k, T) gathered neighbor ratings, (m, k) weights/means, (m,) query
     means → (m, T) clipped predictions.  Oracle for
     ``repro.kernels.predict.fused_tile_predict`` (and the same arithmetic as
@@ -155,7 +161,7 @@ def tile_predict_ref(nbr: jnp.ndarray, w: jnp.ndarray, nb_means: jnp.ndarray,
     den = jnp.einsum("mk,mkt->mt", w, mask)
     pred = q_means[:, None] + num / jnp.maximum(den, 1e-8)
     pred = jnp.where(den > 1e-8, pred, q_means[:, None])
-    return jnp.clip(pred, 1.0, 5.0)
+    return clip_to_scale(pred, scale)
 
 
 # -- attention ----------------------------------------------------------------

@@ -25,8 +25,11 @@ full-vector candidate norms and jaccard's rated counts cannot be derived
 from a column-compressed union block, so they stream in precomputed
 (one cheap global pass, shapes ``(1, Kc)``).
 
-For integer-valued rating matrices (MovieLens 1..5) every Gram sum is an
-exactly-representable f32 integer regardless of accumulation order, so
+Candidate rows may be the int8 gather code of ``repro.core.predict``
+(``r / cand_step``); tiles are decoded in-register by the power-of-two
+step, exactly.  For integer-valued rating matrices (MovieLens 1..5), and
+for half-star ones, every Gram sum is an exactly-representable f32
+number regardless of accumulation order, so
 the kernel, the jnp oracle (``repro.kernels.ref.rerank_scores_ref``), the
 host BLAS twin below, and ``_rerank_sparse`` all agree **bit for bit** —
 the equivalence the oracle tests pin.
@@ -64,8 +67,15 @@ def _dot_t(a, b):
         preferred_element_type=jnp.float32)
 
 
+def _decode(rows, step):
+    """Candidate code → f32 ratings (exact: ``step`` is a power of two;
+    1.0 for f32 rows and integer codes)."""
+    rows = rows.astype(jnp.float32)
+    return rows if step == 1.0 else rows * step
+
+
 def _rerank_kernel(q_ref, c_ref, cn_ref, cc_ref, out_ref, *accs,
-                   n_k: int, measure: str, beta: float):
+                   n_k: int, measure: str, beta: float, cand_step: float):
     (acc_n, acc_dot, acc_sa, acc_sb, acc_qa, acc_qb,
      acc_qn, acc_qc) = accs
     k = pl.program_id(2)
@@ -76,7 +86,7 @@ def _rerank_kernel(q_ref, c_ref, cn_ref, cc_ref, out_ref, *accs,
             r[...] = jnp.zeros_like(r)
 
     vq = q_ref[...].astype(jnp.float32)            # (bm, bk) query values
-    rc = c_ref[...].astype(jnp.float32)            # (bn, bk) candidate rows
+    rc = _decode(c_ref[...], cand_step)            # (bn, bk) candidate rows
     mq = (vq > 0).astype(jnp.float32)
     mc = (rc > 0).astype(jnp.float32)
 
@@ -129,20 +139,23 @@ def _pad_to(x, mult, axis):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "measure", "beta", "bm", "bn", "bk", "interpret"))
+    "measure", "beta", "cand_step", "bm", "bn", "bk", "interpret"))
 def fused_rerank_scores(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
                         cand_norms: jnp.ndarray, cand_counts: jnp.ndarray,
                         *, measure: str = "cosine", beta: float = 50.0,
+                        cand_step: float = 1.0,
                         bm: int = BM, bn: int = BN, bk: int = BK,
                         interpret: bool = False) -> jnp.ndarray:
     """Exact similarity of a query group against a candidate union.
 
     ``q_vals``: (G, J) query rating rows (0 = unrated); ``cand_rows``:
-    (Kc, J) candidate rows over the same item axis (int8 or f32 — the
-    kernel casts tiles in-register, so the int8 gather source streams 4×
-    less HBM); ``cand_norms``/``cand_counts``: (Kc,) full-row L2 norms and
-    rated counts.  Returns (G, Kc) scores under ``measure`` — the same
-    formulas as ``_rerank_sparse``; self/padding masking is the caller's.
+    (Kc, J) candidate rows over the same item axis (int8 code or f32 — the
+    kernel decodes tiles in-register by ``cand_step``, so the int8 gather
+    source streams 4× less HBM); ``cand_norms``/``cand_counts``: (Kc,)
+    full-row L2 norms and rated counts.  Operands already whole tiles
+    (rows of ``bn``, columns of ``bk``) are used as they are, uncopied.
+    Returns (G, Kc) scores under ``measure`` — the same formulas as
+    ``_rerank_sparse``; self/padding masking is the caller's.
     """
     if measure not in MEASURES:
         raise ValueError(f"unknown measure {measure!r}; want one of "
@@ -161,7 +174,7 @@ def fused_rerank_scores(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_rerank_kernel, n_k=grid[2], measure=measure,
                           # reprolint: disable=host-transfer -- beta is a static Python scalar baked into the kernel closure, never traced
-                          beta=float(beta)),
+                          beta=float(beta), cand_step=cand_step),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j_, k: (i, k)),
@@ -180,11 +193,13 @@ def fused_rerank_scores(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
     return out[:g, :kc]
 
 
-@functools.partial(jax.jit, static_argnames=("measure", "beta"))
+@functools.partial(jax.jit, static_argnames=("measure", "beta",
+                                             "cand_step"))
 def rerank_scores_xla(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
                       cand_norms: jnp.ndarray, cand_counts: jnp.ndarray,
                       *, measure: str = "cosine",
-                      beta: float = 50.0) -> jnp.ndarray:
+                      beta: float = 50.0,
+                      cand_step: float = 1.0) -> jnp.ndarray:
     """XLA twin of :func:`fused_rerank_scores`: the same union-Gram
     statistics as one jitted jnp pass — the fused query pipeline's rerank
     stage wherever the Pallas kernel does not run.  Delegates to the jnp
@@ -197,7 +212,8 @@ def rerank_scores_xla(q_vals: jnp.ndarray, cand_rows: jnp.ndarray,
                          f"{MEASURES}")
     from repro.kernels import ref
     return ref.rerank_scores_ref(q_vals, cand_rows, cand_norms,
-                                 cand_counts, measure=measure, beta=beta)
+                                 cand_counts, measure=measure, beta=beta,
+                                 cand_step=cand_step)
 
 
 def rerank_scores_host(q_vals: np.ndarray, cand_rows: np.ndarray,

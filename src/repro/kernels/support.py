@@ -5,7 +5,7 @@ predictor num/den form for every item:
 
     num[u, i] = Σ_k w[u,k] · dev[nb[u,k], i]
     den[u, i] = Σ_k w[u,k] · msk[nb[u,k], i]
-    pred      = clip(r̄_u + num/den, 1, 5)     (r̄_u when den == 0)
+    pred      = clip(r̄_u + num/den, lo, hi)   (r̄_u when den == 0)
 
 — a segmented SpMM between the k-sparse neighbor-weight matrix and the
 stacked deviation/mask table.  On CPU that pass runs row-major over a
@@ -45,9 +45,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.predict import clip_to_scale
+
 _DEN_EPS = 1e-8
 
 BT = 512            # item-tile width: 2 tables · (1, bt) f32 per step
+ROWS = 2048         # rating rows laid out per step of the table build
 # query rows per pallas_call: two (BB, k) SMEM operands padded to 128
 # lanes stay at 256 KiB of the 1 MiB SMEM
 BB = 256
@@ -59,20 +62,39 @@ def support_width(n_items: int, bt: int = BT) -> int:
     return -(-n_items // bt_) * bt_
 
 
+@functools.partial(jax.jit, static_argnames=("width",))
 def support_rows(rows: jnp.ndarray, row_means: jnp.ndarray,
                  width: int) -> jnp.ndarray:
     """(n, I) rating rows → ``(dev, msk)`` kernel table rows, each
     ``(n, 1, width)`` f32: mean-centred ratings and the rated mask, zero
-    in the pad columns (den 0 there → mean fallback, sliced off)."""
-    mask = rows > 0
-    dev = jnp.where(mask, rows - row_means[:, None], 0.0).astype(jnp.float32)
-    msk = mask.astype(jnp.float32)
+    in the pad columns (den 0 there → mean fallback, sliced off).
+
+    Written ``ROWS`` rows at a time into the two tables: the device may
+    lay a ``(n, I)`` matrix out column-major (the TPU does at ML-10M's
+    shape), and a whole-matrix pass would then hold a relaid-out copy of
+    each table, ~3 GB apiece, beside the tables themselves.  The last
+    block overlaps the one before it; it writes the same values."""
+    n = rows.shape[0]
+    blk = min(n, ROWS)
     pad = ((0, 0), (0, width - rows.shape[1]))
-    return jnp.pad(dev, pad)[:, None, :], jnp.pad(msk, pad)[:, None, :]
+
+    def body(b, tables):
+        lo = jnp.minimum(b * blk, n - blk)
+        r = jax.lax.dynamic_slice_in_dim(rows, lo, blk)
+        m = jax.lax.dynamic_slice_in_dim(row_means, lo, blk)
+        mask = r > 0
+        dev = jnp.where(mask, r - m[:, None], 0.0).astype(jnp.float32)
+        new = (jnp.pad(dev, pad), jnp.pad(mask.astype(jnp.float32), pad))
+        return tuple(jax.lax.dynamic_update_slice(t, x[:, None, :],
+                                                  (lo, 0, 0))
+                     for t, x in zip(tables, new))
+
+    empty = jnp.zeros((n, 1, width), jnp.float32)
+    return jax.lax.fori_loop(0, -(-n // blk), body, (empty, empty))
 
 
 def _support_kernel(idx_ref, w_ref, qm_ref, dev_ref, msk_ref, out_ref,
-                    acc_num, acc_den, *, k_len: int):
+                    acc_num, acc_den, *, k_len: int, scale: tuple):
     b, kk = pl.program_id(0), pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -91,10 +113,10 @@ def _support_kernel(idx_ref, w_ref, qm_ref, dev_ref, msk_ref, out_ref,
         num, den = acc_num[...], acc_den[...]
         pred = qm + num / jnp.maximum(den, _DEN_EPS)
         pred = jnp.where(den > _DEN_EPS, pred, qm)
-        out_ref[...] = jnp.clip(pred, 1.0, 5.0)
+        out_ref[...] = clip_to_scale(pred, scale)
 
 
-def _support_call(dev, msk, nb_idx, nb_w, q_means, *, bt, interpret):
+def _support_call(dev, msk, nb_idx, nb_w, q_means, *, scale, bt, interpret):
     b, k_len = nb_idx.shape
     width = dev.shape[2]
     row = lambda bb, j, kk, idx_ref, w_ref, qm_ref: (idx_ref[bb, kk], 0, j)
@@ -109,7 +131,7 @@ def _support_call(dev, msk, nb_idx, nb_w, q_means, *, bt, interpret):
                         pltpu.VMEM((1, bt), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_support_kernel, k_len=k_len),
+        functools.partial(_support_kernel, k_len=k_len, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, width), jnp.float32),
         interpret=interpret,
@@ -117,17 +139,20 @@ def _support_call(dev, msk, nb_idx, nb_w, q_means, *, bt, interpret):
     return out[:, 0, :]
 
 
-@functools.partial(jax.jit, static_argnames=("bt", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bt", "interpret"))
 def fused_support_scores(dev: jnp.ndarray, msk: jnp.ndarray,
                          nb_idx: jnp.ndarray, nb_w: jnp.ndarray,
-                         q_means: jnp.ndarray, *, bt: int = BT,
+                         q_means: jnp.ndarray, *, scale: tuple,
+                         bt: int = BT,
                          interpret: bool = False) -> jnp.ndarray:
     """(U, 1, W) deviation/mask tables × (b, k) neighbors → (b, W) scores.
 
     ``nb_w`` must be the masked weights (invalid/negative-score neighbors
     at 0 — a zero weight cancels both accumulators) and ``nb_idx`` must be
     clipped into ``[0, U)``; both are what the item index's scorer already
-    prepares.  Seen-item knockout is the caller's (it owns the ratings).
+    prepares.  ``scale``: the ``(lo, hi)`` clip of the predictions
+    (``repro.core.predict.rating_scale``).  Seen-item knockout is the
+    caller's (it owns the ratings).
     The tables' width must be whole tiles: build them with
     :func:`support_rows` at :func:`support_width` for the same ``bt``.
     """
@@ -140,6 +165,7 @@ def fused_support_scores(dev: jnp.ndarray, msk: jnp.ndarray,
     nb_w = nb_w.astype(jnp.float32)
     q_means = q_means.astype(jnp.float32)
     outs = [_support_call(dev, msk, nb_idx[lo:lo + BB], nb_w[lo:lo + BB],
-                          q_means[lo:lo + BB], bt=bt_, interpret=interpret)
+                          q_means[lo:lo + BB], scale=scale, bt=bt_,
+                          interpret=interpret)
             for lo in range(0, nb_idx.shape[0], BB)]
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)

@@ -76,7 +76,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.predict import predict_from_neighbors_blocked, topn_unseen
+from repro.core.predict import (predict_from_neighbors_blocked,
+                                rating_scale, topn_unseen)
 from repro.distributed.fault_tolerance import (RecoveryPolicy,
                                                StragglerWatchdog,
                                                TransientServeError)
@@ -184,11 +185,11 @@ class DegradationLadder:
         return level, ""
 
 
-@functools.partial(jax.jit, static_argnames=("topn",))
-def _predict_users(users, ratings, scores, idx, means, *, topn):
+@functools.partial(jax.jit, static_argnames=("topn", "scale"))
+def _predict_users(users, ratings, scores, idx, means, *, topn, scale):
     pred = predict_from_neighbors_blocked(
         ratings, scores[users], idx[users], means=means,
-        query_means=means[users], item_block=_ITEM_BLOCK)
+        query_means=means[users], item_block=_ITEM_BLOCK, scale=scale)
     seen = ratings[users] > 0
     return topn_unseen(pred, seen, topn)
 
@@ -209,6 +210,8 @@ class BatchingServer:
             if getattr(cf_model, "scores", None) is None:
                 raise ValueError("fit the engine first")
             self._snapshot = cf_model.snapshot
+            # the engine's rating scale, kept with its gather operand
+            self._scale = lambda r: cf_model._gather_source(r).scale
             if getattr(cf_model, "recommend_mode", "exact") == "approx":
                 # two-stage serving: candidate items from the item index,
                 # exact rerank — updates land between batches (the batcher
@@ -222,6 +225,8 @@ class BatchingServer:
             st = cf_model.state
             snap = (ratings, st.scores, st.idx, st.means)
             self._snapshot = lambda: snap
+            scale = rating_scale(ratings)
+            self._scale = lambda r: scale
         self.max_batch = max_batch
         self.max_wait = max_wait_ms / 1e3
         self.topn = topn
@@ -297,7 +302,7 @@ class BatchingServer:
                                                  n=self.topn)
         ratings, scores, idx, means = self._snapshot()
         return _predict_users(users, ratings, scores, idx, means,
-                              topn=self.topn)
+                              topn=self.topn, scale=self._scale(ratings))
 
     # -- public API --------------------------------------------------------
     @property

@@ -29,6 +29,12 @@ def _delta(rng, n_users, n_items, n):
             rng.integers(0, 6, n).astype(np.float32))
 
 
+def _assert_src_equal(got, want):
+    assert (got.step, got.scale) == (want.step, want.scale)
+    np.testing.assert_array_equal(np.asarray(got.code),
+                                  np.asarray(want.code))
+
+
 def _assert_csr_equal(got, want):
     for g, w, name in zip(got, want, ("indptr", "indices", "data")):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
@@ -60,9 +66,8 @@ def test_engine_caches_patched_across_updates(rng):
                                           features="raw"))
         _assert_csr_equal(ix._csr_cache[1],
                           cold._ratings_csr(eng.ratings))
-        np.testing.assert_array_equal(
-            np.asarray(ix._gather_cache[1]),
-            np.asarray(pred_mod.make_gather_source(eng.ratings)))
+        _assert_src_equal(ix._gather_cache[1],
+                          pred_mod.make_gather_source(eng.ratings))
         b_got, l_got, t_got = ix._csr_cache[2]
         b_want, l_want, t_want = cold._item_tables(eng.ratings)
         np.testing.assert_array_equal(b_got, b_want)
@@ -83,21 +88,28 @@ def test_engine_gather_cache_patched(rng):
     assert eng._gather_cache is not None
     eng.update_ratings(*_delta(rng, 64, 48, 3))
     assert eng._gather_cache[0] is eng.ratings
-    np.testing.assert_array_equal(
-        np.asarray(eng._gather_cache[1]),
-        np.asarray(pred_mod.make_gather_source(eng.ratings)))
+    _assert_src_equal(eng._gather_cache[1],
+                      pred_mod.make_gather_source(eng.ratings))
 
 
 def test_gather_patch_int8_fallout(rng):
-    """A delta that breaks int8 exactness must rebuild, not mis-patch."""
+    """A delta that breaks the code's exactness must rebuild, not
+    mis-patch: a half star moves an integer code to step 0.5, a value on
+    no power-of-two grid to the f32 matrix."""
     r = _ratings(rng, 32, 16)
     src = pred_mod.make_gather_source(r)
-    assert src.dtype == jnp.int8
-    r2 = r.at[3, 2].set(2.5)                # non-integer rating
+    assert src.code.dtype == jnp.int8 and src.step == 1.0
+    r2 = r.at[3, 2].set(2.5)                # a half star
     patched = pred_mod.patch_gather_source(
         src, r2, jnp.asarray([3], jnp.int32))
-    assert patched.dtype == jnp.float32
-    np.testing.assert_array_equal(np.asarray(patched), np.asarray(r2))
+    assert patched.code.dtype == jnp.int8 and patched.step == 0.5
+    np.testing.assert_array_equal(np.asarray(patched.code),
+                                  np.asarray(r2 * 2).astype(np.int8))
+    r3 = r2.at[5, 1].set(2.3)               # on no power-of-two grid
+    patched = pred_mod.patch_gather_source(
+        patched, r3, jnp.asarray([5], jnp.int32))
+    assert patched.code.dtype == jnp.float32 and patched.step == 0.0
+    np.testing.assert_array_equal(np.asarray(patched.code), np.asarray(r3))
 
 
 def test_item_index_support_caches_patched(rng):

@@ -66,13 +66,20 @@ def test_fused_shortlists_pin_to_gather_oracle(rng, monkeypatch):
     r, means, _, ix_f = _pair(rng, u=260)
     k = 8
     captured = []
-    orig = cl._fused_rerank_block
 
-    def grab(r_gather, ratings, norms, counts, q_ids, shorts, **kw):
-        captured.append((np.asarray(q_ids), np.asarray(shorts)))
-        return orig(r_gather, ratings, norms, counts, q_ids, shorts, **kw)
+    def grabbing(name):
+        orig = getattr(cl, name)
 
-    monkeypatch.setattr(cl, "_fused_rerank_block", grab)
+        def grab(*args, **kw):
+            q_ids, shorts = args[-2:]
+            captured.append((np.asarray(q_ids), np.asarray(shorts)))
+            return orig(*args, **kw)
+        monkeypatch.setattr(cl, name, grab)
+
+    # the union path, and the whole-matrix path a block takes where its
+    # union can reach every row
+    grabbing("_fused_rerank_block")
+    grabbing("_fused_rerank_whole")
     s_f, i_f = ix_f.query(r, means, k=k, measure="pcc")
     assert captured, "fused rerank never ran"
     qs, shorts = [], []

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import neighbors as nb
+from repro.core import predict as pr
 from repro.core import similarity as sim
 from repro.index.item_index import _rerank_items, _shortlist_mask
 
@@ -31,9 +32,11 @@ def _fma(a, b, acc):
 def _point_gather_rerank(src, ratings, nb_scores, nb_idx, means, q_means,
                          q_ids, short, n):
     """Reference: score each row's candidate list by gathering
-    ``src[neighbor, candidate]`` one element at a time, then sort by
+    ``src[neighbor, candidate]`` one element at a time (decoded by the
+    code's step), clip to the gather source's scale, then sort by
     (-score, item id); -inf slots come back as id -1."""
-    src, ratings = np.asarray(src), np.asarray(ratings)
+    scale, factor = src.scale, np.float32(src.factor)
+    src, ratings = np.asarray(src.code), np.asarray(ratings)
     means, q_means = np.asarray(means), np.asarray(q_means)
     n_users, n_items = ratings.shape
     out_s = np.full((len(q_ids), n), -np.inf, np.float32)
@@ -46,14 +49,14 @@ def _point_gather_rerank(src, ratings, nb_scores, nb_idx, means, q_means,
         num = np.zeros(len(cand), np.float32)
         den = np.zeros(len(cand), np.float32)
         for j in range(len(idx)):          # the k-reduction, in order
-            nbr = src[safe_nb[j], safe_c].astype(np.float32)
+            nbr = src[safe_nb[j], safe_c].astype(np.float32) * factor
             mask = (nbr > 0).astype(np.float32)
             dev = (nbr - means[safe_nb[j]]) * mask
             num = _fma(w[j], dev, num)
             den = _fma(w[j], mask, den)
         qm = q_means[row]
         pred = qm + num / np.maximum(den, np.float32(1e-8))
-        pred = np.clip(np.where(den > 1e-8, pred, qm), 1.0, 5.0)
+        pred = np.clip(np.where(den > 1e-8, pred, qm), *scale)
         seen = ratings[min(max(q_ids[row], 0), n_users - 1), safe_c] > 0
         valid = (cand >= 0) & (cand < n_items) & ~seen
         s = np.where(valid, pred, -np.inf).astype(np.float32)
@@ -127,7 +130,9 @@ def test_rerank_matches_point_gather_reference(case, src_dtype, rng):
     # a dead neighbor slot and a negative weight, as a cache may hold
     nbi = nbi.at[0, -1].set(-1)
     nbs = nbs.at[1, -1].set(-0.5)
-    src = ratings.astype(jnp.dtype(src_dtype))
+    src = (pr.make_gather_source(ratings) if src_dtype == "int8" else
+           pr.GatherSource(ratings, 0.0, pr.rating_scale(ratings)))
+    assert src.code.dtype == jnp.dtype(src_dtype)
     got_s, got_i = _rerank_items(
         ratings, src, nbs, nbi, means, q_means, jnp.asarray(q_ids),
         jnp.asarray(_shortlist_mask(short, I)), n=n, item_block=ITEM_BLOCK)
@@ -166,7 +171,8 @@ def test_rerank_has_no_element_gather_on_the_rating_matrix(src_dtype):
     closed = jax.make_jaxpr(
         lambda r, s, nbs, nbi, mu, qm, q, c: _rerank_items(
             r, s, nbs, nbi, mu, qm, q, c, n=N, item_block=ITEM_BLOCK))(
-        ratings, ratings.astype(jnp.dtype(src_dtype)),
+        ratings, pr.GatherSource(ratings.astype(jnp.dtype(src_dtype)),
+                                 float(src_dtype == "int8"), (1.0, 5.0)),
         jnp.zeros((M, K), jnp.float32), jnp.zeros((M, K), jnp.int32),
         jnp.zeros((U,), jnp.float32), jnp.zeros((M,), jnp.float32),
         jnp.zeros((M,), jnp.int32), jnp.zeros((M, I), bool))

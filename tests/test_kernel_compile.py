@@ -4,7 +4,9 @@ The interpret-mode suites pin kernel *semantics*; they cannot show that a
 kernel lowers through Mosaic.  The compile tests here build the main-path
 kernels, as the served path dispatches them, at the ML-1M deployment's
 widths (U=6040 users, I=3952 items, proxy dim 256, k=40, shortlist
-M=0.15·U) for one chip of a described ``v5e:2x2`` topology — the TPU
+M=0.15·U), and the fit's rerank and the item scorer at the ML-10M
+deployment's (U=69,878, I=10,677, half-star int8 code), for one chip of a
+described ``v5e:2x2`` topology — the TPU
 compiler is installed even where no chip is attached.  Each asserts the
 kernel is in the compiled program (``tpu_custom_call``) and bounds the
 program's device memory from ``memory_analysis()``.
@@ -16,6 +18,7 @@ over odd, misaligned block shapes, which catches grid/padding bugs that
 the default-aligned suites never exercise.
 """
 
+import functools
 import os
 
 import jax
@@ -23,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.facade import AUTO_RERANK_FRAC
+from repro.core.predict import GatherSource
 from repro.index import clustered as cl
 from repro.kernels import ref
 from repro.kernels import support as sup
@@ -36,6 +41,8 @@ M = int(0.15 * U)                  # IndexConfig.rerank_frac at ML-1M
 BQ = 2048                          # fused pool-scan query block
 KU = 8192                          # candidate-union bucket of one block
 MEASURES = ("cosine", "jaccard", "pcc", "pcc_sig")
+# ML-10M (bench/configs/ml10m-served.json): half stars 0.5-5.0, int8 code step 0.5
+U10, I10 = 69878, 10677
 
 
 @pytest.fixture(scope="module")
@@ -93,7 +100,8 @@ def test_fused_rerank_block_compiles_for_v5e(spec):
     fn = lambda *a: cl._fused_rerank_block(
         *a, ku=KU, k=K, measure="pcc", beta=50.0, use_pallas=True,
         interpret=False)
-    c = _compile(fn, spec((U, I), jnp.int8), spec((U, I)), spec((U,)),
+    src = GatherSource(spec((U, I), jnp.int8), 1.0, (1.0, 5.0))
+    c = _compile(fn, src, spec((U, I)), spec((U,)),
                  spec((U,)), spec((BQ,), jnp.int32), spec((BQ, M), jnp.int32))
     assert "tpu_custom_call" in c.as_text()
     # operands (~120 MB) + the (BQ, KU) scores and gathered union rows
@@ -116,10 +124,60 @@ def test_support_kernel_compiles_for_v5e(b, spec):
     two ~95 MB tables, and more than ``BB`` query rows loop over SMEM-sized
     row blocks."""
     w = sup.support_width(I)
-    c = _compile(sup.fused_support_scores, spec((U, 1, w)), spec((U, 1, w)),
+    c = _compile(functools.partial(sup.fused_support_scores,
+                                   scale=(1.0, 5.0)),
+                 spec((U, 1, w)), spec((U, 1, w)),
                  spec((b, K), jnp.int32), spec((b, K)), spec((b,)))
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_support_kernel_compiles_for_v5e_at_ml10m(spec):
+    """The item scorer of a served ML-10M batch (16 rows) over the
+    stored (U, 1, 10,752) f32 tables (3.0 GB each): the 16 MiB temp bound
+    of the ML-1M shape holds, since the kernel streams (1, 512) tiles and
+    its output is 16 × 10,752 × 4 B = 0.7 MB — nothing scales with U·I."""
+    w = sup.support_width(I10)
+    assert w == 21 * 512
+    c = _compile(functools.partial(sup.fused_support_scores,
+                                   scale=(0.5, 5.0)),
+                 spec((U10, 1, w)), spec((U10, 1, w)),
+                 spec((16, K), jnp.int32), spec((16, K)), spec((16,)))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("frac, bound_mb", [(0.15, 2500),
+                                             (AUTO_RERANK_FRAC, 3000)],
+                         ids=["index_default", "engine_auto"])
+def test_fused_rerank_whole_fits_ml10m(frac, bound_mb, spec):
+    """The fit's rerank block at ML-10M on the whole-matrix path: a
+    2,048-query block scored against all 69,878 rows of the int8 code,
+    padded once to whole kernel tiles (69,888 × 10,752).  The union path
+    compiled to 16.06 GB here (131,072 gathered f32 rows, their padded
+    copy, the f32 matrix and the (2,048, 131,072) scores).  This one, at
+    shortlists of M = ``frac`` · U (10,481 at 0.15, 27,951 at 0.4):
+
+    - arguments: the padded code 69,888 × 10,752 × 1 B = 751 MB, the
+      (2,048, M) int32 shortlists (86 MB at 0.15, 229 MB at 0.4), norms
+      and counts 0.6 MB;
+    - temps: the query rows 2,048 × 10,752 × 4 B = 88 MB, the scores
+      2,048 × 69,888 × 4 B = 573 MB, the shortlist's scores and the sort's
+      keys and values, about 4 × the shortlists (344 MB at 0.15, 916 MB
+      at 0.4);
+
+    about 1.84 GB at 0.15 (1.59 GB compiled), so the bound is 2.5 GB;
+    about 2.56 GB at 0.4 (2.47 GB compiled), so the bound is 3 GB."""
+    n_pad, j_pad = 273 * 256, 21 * 512
+    fn = lambda src, *a: cl._fused_rerank_whole(
+        src, *a, k=K, measure="pcc", beta=50.0, use_pallas=True,
+        interpret=False)
+    src = GatherSource(spec((n_pad, j_pad), jnp.int8), 0.5, (0.5, 5.0))
+    c = _compile(fn, src, spec((U10,)), spec((U10,)),
+                 spec((BQ,), jnp.int32),
+                 spec((BQ, int(frac * U10)), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+    assert _device_bytes(c) < bound_mb << 20
 
 
 def test_select_paths_compile_for_v5e(spec):

@@ -420,6 +420,47 @@ def test_query_metrics_land_in_registry(rng):
     assert st.seconds_total <= h["p50"] <= st.seconds_total * 10 ** 0.1
 
 
+@pytest.mark.parametrize("users, kind", [(None, "whole"), ([5], "fused")])
+def test_fit_rerank_kind_and_union_rows(users, kind, rng):
+    """The fused query scores every row where a block's union can reach
+    them all (every user: a block of 256 queries × 10 shortlisted ≥ 192
+    users): its rerank spans say ``kind="whole"`` and ``rows`` = U, and
+    no row is gathered into a union.  One query (a block of 8 × 10 < 192)
+    takes the union path and counts the union's bucket of rows."""
+    from repro.index import ClusteredIndex, IndexConfig
+    r, means, _ = _small_index(rng, "fused")
+    ix = ClusteredIndex(IndexConfig(n_clusters=8, n_probe=8, seed=0,
+                                    features="raw", rerank_frac=0.05,
+                                    project_dim=16,
+                                    query_mode="fused")).fit(r, means)
+    assert ix._max_rerank(5) == 10
+    obs.reset_metrics()
+    obs.clear()
+    ix.query(r, means, users, k=5, measure="cosine")
+    rer = [s for s in obs.get_spans() if s.name == "query.rerank"]
+    assert rer and {s.attrs["kind"] for s in rer} == {kind}
+    union = obs.registry().snapshot()["counters"]["index.rerank_union_rows"]
+    if kind == "whole":
+        assert all(s.attrs["rows"] == ix.n_users for s in rer)
+        assert union == 0
+    else:
+        assert union == sum(s.attrs["ku"] for s in rer) > 0
+
+
+@pytest.mark.parametrize("step, values", [
+    (1.0, (1.0, 2.0, 5.0)), (0.5, (0.5, 3.5, 5.0)), (0.0, (0.3, 0.6, 0.9))])
+def test_gather_step_gauge(step, values, rng):
+    """``engine.gather_step``: the int8 code's step — 1 for integer
+    stars, 0.5 for half stars — and 0 where no power-of-two step holds
+    the ratings (a 0.3 grid) and the f32 matrix is the operand."""
+    from repro.core.predict import make_gather_source
+    r = rng.choice(np.asarray((0.0,) + values, np.float32), (24, 16))
+    src = make_gather_source(jnp.asarray(r))
+    assert src.step == step
+    assert src.code.dtype == (jnp.float32 if step == 0.0 else jnp.int8)
+    assert obs.registry().snapshot()["gauges"]["engine.gather_step"] == step
+
+
 # -- windowed deltas (serving health windows) -------------------------------
 
 def _hist_snaps(obs_values_1, obs_values_2, buckets=(1.0, 10.0, 100.0)):

@@ -43,8 +43,12 @@ def test_blocked_predict_int8_gather_src_is_exact(rng):
     r = _ratings(rng, 64, 96)
     scores, idx = nb.topk_neighbors(r, 5, measure="cosine", block_size=16)
     dense = np.asarray(pr.predict_from_neighbors(r, scores, idx))
+    src = pr.make_gather_source(r)
+    assert src.code.dtype == jnp.int8 and src.step == 1.0
+    np.testing.assert_array_equal(np.asarray(src.code),
+                                  np.asarray(r.astype(jnp.int8)))
     blocked = np.asarray(pr.predict_from_neighbors_blocked(
-        r, scores, idx, item_block=32, gather_src=r.astype(jnp.int8)))
+        r, scores, idx, item_block=32, gather_src=src))
     np.testing.assert_array_equal(dense, blocked)
 
 
@@ -56,9 +60,10 @@ def test_fused_tile_predict_matches_oracle(rng):
     safe = jnp.where(idx >= 0, idx, 0)
     w = jnp.where((scores > 0) & (idx >= 0), scores, 0.0)
     nbr = r[safe]
-    got = fused_tile_predict(nbr, w, means[safe], means, bm=16, bt=64,
-                             interpret=True)
-    ref = tile_predict_ref(nbr, w, means[safe], means)
+    scale = pr.rating_scale(r)
+    got = fused_tile_predict(nbr, w, means[safe], means, scale=scale,
+                             bm=16, bt=64, interpret=True)
+    ref = tile_predict_ref(nbr, w, means[safe], means, scale=scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
     dense = np.asarray(pr.predict_from_neighbors(r, scores, idx))
     np.testing.assert_allclose(np.asarray(got), dense, atol=2e-5)

@@ -2,6 +2,7 @@
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from repro.core import CFEngine
 from repro.serving.engine import BatchingServer
@@ -136,3 +137,34 @@ def test_one_gather_span_per_batch_after_idling(rng):
         assert predicts[r.batch_seq].parent_id == by_seq[r.batch_seq].span_id
     assert sum(by_seq[q].attrs["batch_size"]
                for q in {r.batch_seq for r in results}) == len(results)
+
+
+@pytest.mark.parametrize("values, itemsize", [
+    ((1.0, 3.0, 5.0), 1), ((0.5, 2.5, 4.5), 1), ((0.3, 1.2, 2.7), 4)],
+    ids=["stars", "half_stars", "f32"])
+def test_served_rerank_span_counts_rows_and_gather_bytes(values, itemsize,
+                                                         rng):
+    """Each served batch's ``recommend.rerank`` span carries its query
+    rows and the neighbor-row bytes read from the gather source: rows × k
+    × the whole item tiles' width × the code's itemsize — one byte on the
+    int8 code of integer or half stars, four on the f32 matrix."""
+    from repro import obs
+    from repro.index import ItemIndexConfig
+    r = rng.choice(np.asarray((0.0,) + values, np.float32), (64, 32))
+    eng = CFEngine(jnp.asarray(r), measure="cosine", k=5, block_size=16,
+                   recommend_mode="approx",
+                   item_index_cfg=ItemIndexConfig(n_clusters=4, seed=0,
+                                                  shortlist=8)).fit()
+    server = BatchingServer(eng, max_batch=4, max_wait_ms=5.0, topn=3)
+    obs.clear()
+    server.start()
+    for f in [server.submit(int(u)) for u in rng.integers(0, 64, 10)]:
+        f.result(timeout=30)
+    server.stop()
+    rer = [s for s in obs.get_spans() if s.name == "recommend.rerank"]
+    assert sum(s.attrs["rows"] for s in rer) >= 10
+    width = eng.item_index.cfg.item_block * -(-32 // eng.item_index.cfg
+                                              .item_block)
+    for s in rer:
+        assert s.attrs["gather_bytes"] == s.attrs["rows"] * 5 * width * \
+            itemsize

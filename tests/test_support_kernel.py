@@ -9,6 +9,7 @@ import pytest
 from repro.core import CFEngine
 from repro.core import neighbors as nb
 from repro.core import similarity as sim
+from repro.core.predict import rating_scale
 from repro.index import (ClusteredIndex, IndexConfig, ItemClusteredIndex,
                          ItemIndexConfig)
 from repro.index.item_index import _affinity_weights, _fold_profiles
@@ -38,14 +39,15 @@ def test_support_kernel_matches_ref(shape, rng):
     idx = rng.integers(0, u, (b, k)).astype(np.int32)
     w = (rng.random((b, k)) * (rng.random((b, k)) < 0.8)).astype(np.float32)
     qm = rng.uniform(2, 4, b).astype(np.float32)
+    scale = rating_scale(r)
     want = ref.support_scores_ref(jnp.asarray(dev), jnp.asarray(msk),
                                   jnp.asarray(idx), jnp.asarray(w),
-                                  jnp.asarray(qm))
+                                  jnp.asarray(qm), scale=scale)
     dev_t, msk_t = support_rows(jnp.asarray(r), jnp.asarray(means),
                                 support_width(i, 32))
     got = fused_support_scores(dev_t, msk_t, jnp.asarray(idx),
-                               jnp.asarray(w), jnp.asarray(qm), bt=32,
-                               interpret=True)
+                               jnp.asarray(w), jnp.asarray(qm), scale=scale,
+                               bt=32, interpret=True)
     np.testing.assert_allclose(np.asarray(got)[:, :i], np.asarray(want),
                                atol=1e-5)
 
@@ -60,7 +62,8 @@ def test_support_kernel_all_masked_neighbors(rng):
     got = np.asarray(fused_support_scores(
         jnp.asarray(dev[:, None]), jnp.asarray(msk[:, None]),
         jnp.asarray(idx),
-        jnp.asarray(w), jnp.asarray(qm), bt=16, interpret=True))
+        jnp.asarray(w), jnp.asarray(qm), scale=(1.0, 5.0), bt=16,
+        interpret=True))
     np.testing.assert_allclose(got, np.broadcast_to(qm[:, None], got.shape),
                                atol=1e-6)
 
