@@ -416,6 +416,27 @@ def _build_fused_support_scores():
     return fn, call, make_args, ("dev", "msk", "nb_idx", "nb_w", "q_means")
 
 
+def _build_select_rerank_items():
+    import jax.numpy as jnp
+    from repro.index import item_index as ii
+    fn = ii._select_rerank_items
+
+    def make_args():
+        r_gather, ratings, _, _ = _common()
+        rng = np.random.default_rng(5)
+        nb_scores = jnp.asarray(rng.random((8, 2)), jnp.float32)
+        nb_idx = jnp.asarray(rng.integers(0, 8, (8, 2)), jnp.int32)
+        means = jnp.asarray(rng.uniform(1, 5, 8), jnp.float32)
+        q_ids = jnp.asarray([0, 3], jnp.int32)
+        scores = jnp.asarray(rng.uniform(1, 5, (2, 8)), jnp.float32)
+        return (ratings, r_gather, nb_scores, nb_idx, means, q_ids, scores,
+                jnp.int32(3))
+
+    call = functools.partial(fn, n=2, shortlist=4, item_block=4)
+    return fn, call, make_args, ("ratings", "gather_code", "nb_scores",
+                                 "nb_idx", "means", "q_ids", "scores", "m")
+
+
 #: The fused query pipeline + its twins: the surfaces ROADMAP item 1 will
 #: quantize, in execution order.  Statics are bound to the XLA twins
 #: (use_pallas=False / interpret=True) so the audit traces on any host.
@@ -436,6 +457,8 @@ HOT_PATHS: Tuple[HotPath, ...] = (
             "src/repro/kernels/select.py", _build_scan_topm_xla),
     HotPath("kernels.support.fused_support_scores",
             "src/repro/kernels/support.py", _build_fused_support_scores),
+    HotPath("index.item_index._select_rerank_items",
+            "src/repro/index/item_index.py", _build_select_rerank_items),
 )
 
 

@@ -13,9 +13,13 @@ two-stage idea on the item axis:
    audience; each item spill-assigns to its nearest clusters exactly as
    users do (all bookkeeping inherited from ``_SpillClusterCore``).
 3. **Shortlist** — a cheap full-width scorer ranks candidate items per
-   query user; the best ``shortlist`` unseen items go forward.  Two
-   scorers are provided (``shortlist_mode``):
+   query user; the canonical (-score, item id) best ``shortlist`` unseen
+   items go forward, selected on the device by ``lax.top_k`` in the same
+   program as the rerank.  The scorers (``shortlist_mode``):
 
+   * ``"kernel"`` (TPU default) — the support pass below as a fused
+     Pallas segmented SpMM (``repro.kernels.support``); its scores stay
+     on the device.
    * ``"support"`` (CPU default) — the *item-major sparse pass*: the
      predictor ``r̄_u + Σ w·dev / Σ w·mask`` is one sparse×dense product
      ``W @ [DEV | MASK]`` between the k-sparse neighbor-weight matrix and
@@ -27,7 +31,7 @@ two-stage idea on the item axis:
      epilogue, so shortlist containment of the exact top-n is ≈1 even at
      tiny shortlists.  Uses ``scipy.sparse`` when importable (gated; the
      container ships it) and a jnp gather fallback otherwise.
-   * ``"proxy"`` (TPU default) — MXU-friendly two-stage candidate
+   * ``"proxy"`` — MXU-friendly two-stage candidate
      generation: each user carries a *taste profile* in item-proxy space
      (``Σ max(r−r̄,0)·proxy_i`` over their rated items; neighbors'
      profiles aggregated with the prediction weights), the profile probes
@@ -115,10 +119,9 @@ class ItemIndexConfig:
     item_block: int = 512                 # rerank/predict tile width
     kmeans_block: int = 2048
     query_block: int = 256
-    score_block: int = 8192               # support-scorer users per chunk
-    rerank_block: int = 1024              # support-path rerank batch (the
-                                          # (b, k, item_block) row-gather
-                                          # unit)
+    rerank_block: int = 1024              # support-path block rows: one
+                                          # score and one select-rerank
+                                          # program each
     use_kernel: Optional[bool] = None     # None → auto: fused kernel on TPU
     interpret: bool = False
     refit_reassign_frac: float = 0.5      # shared auto-refit drift guard
@@ -137,13 +140,15 @@ class RecommendStats:
     n_queries: int
     n_items: int           # candidate population the fractions refer to
     n_probed: int          # probed-member items summed over queries
-    n_reranked: int        # items exactly predicted (true rerank)
+    n_reranked: int        # items exactly predicted (true rerank); the
+                           # support path leaves a device scalar, read
+                           # when a fraction is asked for
     scorer: str = ""       # shortlist scorer that ran: "support" (host
                            # CSR pass), "kernel" (Pallas support kernel)
                            # or "proxy" (proxy GEMM + top-M selection)
 
-    def _frac(self, total: int) -> float:
-        return total / max(self.n_queries * max(self.n_items, 1), 1)
+    def _frac(self, total) -> float:
+        return int(total) / max(self.n_queries * max(self.n_items, 1), 1)
 
     @property
     def probed_fraction(self) -> float:
@@ -286,6 +291,33 @@ def _shortlist_mask(short: np.ndarray, n_items: int) -> np.ndarray:
     return mask[:, :n_items]
 
 
+def _seen_rows(gather_src, q, n_items: int, item_block: int):
+    """(b, I) rated mask of the query rows ``q`` (clipped ids), read from
+    the item tiles of the gather code that the blocked prediction slices:
+    no row gather of a (U, I) operand, which a column-major device layout
+    (the TPU's choice at ML-10M's shape) would copy whole."""
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(gather_src.code, lo,
+                              min(lo + item_block, n_items), axis=1)[q] > 0
+         for lo in range(0, n_items, item_block)], axis=1)
+
+
+def _rerank_masked(ratings, gather_src, nb_scores, nb_idx, means, q_means,
+                   cand_mask, seen, *, n, item_block):
+    """The rerank body: predict the rows over every item, keep the unseen
+    candidates, canonical top-n (see :func:`_rerank_items`)."""
+    n_items = ratings.shape[1]
+    pred = pred_mod.predict_from_neighbors_blocked(
+        ratings, nb_scores, nb_idx, means=means, query_means=q_means,
+        item_block=item_block, gather_src=gather_src)
+    s = jnp.where(cand_mask & ~seen, pred, -jnp.inf)
+    if n_items < n:
+        s = jnp.pad(s, ((0, 0), (0, n - n_items)), constant_values=-jnp.inf)
+    # reprolint: disable=canonical-selection -- column j is item j and XLA top_k ties break toward the lower index: the canonical (-score, item id) order
+    top_s, top_i = jax.lax.top_k(s, n)
+    return top_s, jnp.where(top_s == -jnp.inf, -1, top_i)
+
+
 @functools.partial(jax.jit, static_argnames=("n", "item_block"))
 def _rerank_items(ratings, gather_src, nb_scores, nb_idx, means, q_means,
                   q_ids, cand_mask, *, n, item_block):
@@ -300,24 +332,64 @@ def _rerank_items(ratings, gather_src, nb_scores, nb_idx, means, q_means,
     -inf and surface as item id -1, the recommendation contract.
     """
     n_users, n_items = ratings.shape
-    pred = pred_mod.predict_from_neighbors_blocked(
-        ratings, nb_scores, nb_idx, means=means, query_means=q_means,
-        item_block=item_block, gather_src=gather_src)
-    # the query rows' rated items, read from the item tiles of the gather
-    # code that the prediction slices: no row gather of a (U, I) operand,
-    # which a column-major device layout (the TPU's choice at ML-10M's
-    # shape) would copy whole
+    seen = _seen_rows(gather_src, jnp.clip(q_ids, 0, n_users - 1), n_items,
+                      item_block)
+    return _rerank_masked(ratings, gather_src, nb_scores, nb_idx, means,
+                          q_means, cand_mask, seen, n=n,
+                          item_block=item_block)
+
+
+@jax.jit
+def _support_operands(nb_scores, nb_idx, means, q_ids):
+    """The support kernel's operands for a block of padded query ids:
+    the neighbor ids clipped into ``[0, U)``, the prediction weights
+    (invalid and non-positive neighbors at 0) and the query means."""
+    n_users = means.shape[0]
     q = jnp.clip(q_ids, 0, n_users - 1)
-    seen = jnp.concatenate(
-        [jax.lax.slice_in_dim(gather_src.code, lo,
-                              min(lo + item_block, n_items), axis=1)[q] > 0
-         for lo in range(0, n_items, item_block)], axis=1)
-    s = jnp.where(cand_mask & ~seen, pred, -jnp.inf)
-    if n_items < n:
-        s = jnp.pad(s, ((0, 0), (0, n - n_items)), constant_values=-jnp.inf)
+    sc, idx = nb_scores[q], nb_idx[q]
+    w = jnp.where((sc > 0.0) & (idx >= 0), sc, 0.0)
+    return jnp.clip(idx, 0, n_users - 1), w, means[q]
+
+
+def _canonical_top_mask(s, m, *, width: int):
+    """(b, I) scores, -inf where excluded → (b, I) mask of each row's
+    canonical (-score, item id) top-``m`` finite items, ``m <= width``
+    traced.  The top-``m`` are the items at or before the ``m``-th pick
+    of ``top_k(s, width)`` in that order, read off as a threshold on
+    (score, id); a row with fewer than ``m`` finite items takes them
+    all."""
     # reprolint: disable=canonical-selection -- column j is item j and XLA top_k ties break toward the lower index: the canonical (-score, item id) order
-    top_s, top_i = jax.lax.top_k(s, n)
-    return top_s, jnp.where(top_s == -jnp.inf, -1, top_i)
+    top_v, top_c = jax.lax.top_k(s, width)
+    v = jax.lax.dynamic_index_in_dim(top_v, m - 1, axis=1)
+    c = jax.lax.dynamic_index_in_dim(top_c, m - 1, axis=1)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return (s > -jnp.inf) & ((s > v) | ((s == v) & (col <= c)))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n", "shortlist", "item_block"))
+def _select_rerank_items(ratings, gather_src, nb_scores, nb_idx, means,
+                         q_ids, scores, m, *, n, shortlist, item_block):
+    """Shortlist selection and exact rerank of one block, in one program.
+
+    ``scores``: the block's (b, >= I) support scores (columns past ``I``
+    are the kernel's pad tiles and are dropped).  Seen items are knocked
+    out and the canonical (-score, item id) top-``m`` unseen items form
+    the candidate mask (:func:`_canonical_top_mask`).  ``shortlist`` is
+    the static selection width; ``m <= shortlist`` is traced, so every
+    smaller budget of the serving ladder runs this one program.  Padding
+    rows carry the id ``U``.  Returns the rerank's ``(scores, ids)`` and
+    the real rows' candidate count."""
+    n_users, n_items = ratings.shape
+    q = jnp.clip(q_ids, 0, n_users - 1)
+    seen = _seen_rows(gather_src, q, n_items, item_block)
+    cand = _canonical_top_mask(
+        jnp.where(seen, -jnp.inf, scores[:, :n_items]), m, width=shortlist)
+    n_cand = jnp.sum(cand & (q_ids < n_users)[:, None])
+    top_s, top_i = _rerank_masked(ratings, gather_src, nb_scores[q],
+                                  nb_idx[q], means, means[q], cand, seen,
+                                  n=n, item_block=item_block)
+    return top_s, top_i, n_cand
 
 
 class ItemClusteredIndex(_SpillClusterCore):
@@ -337,6 +409,7 @@ class ItemClusteredIndex(_SpillClusterCore):
         self._has_pos: Optional[jnp.ndarray] = None   # (U,) bool
         self._support_cache: Optional[tuple] = None   # per-ratings [dev|mask]
         self._support_dense_cache: Optional[tuple] = None  # kernel operands
+        self._budgets: dict = {}                      # m → device scalar
         self._touched_since_profile = 0               # profile-refold drift
         self.last_recommend: Optional[RecommendStats] = None
 
@@ -587,199 +660,108 @@ class ItemClusteredIndex(_SpillClusterCore):
             n_probed=n_probed, n_reranked=n_reranked, scorer="proxy")
         return jnp.asarray(out_s), jnp.asarray(out_i)
 
-    def _score_select_rows(self, stacked, w, safe_idx, q_means, seen_rows,
-                           m_short: int, scale: tuple) -> np.ndarray:
-        """Score one row chunk (exact f32 num/den, clip to ``scale``
-        epilogue, seen → -inf) and select its canonical top-``m_short``
-        items.
-
-        Selection exactness without a full-width composite-key pass: a
-        plain f32 argpartition is canonical except when the *cut value*
-        is tied beyond the cap — which happens only at genuine score ties
-        (the 5.0 clip group, and the ``q_mean`` fallback group of
-        unsupported items).  Those rows are repaired individually: items
-        strictly above the cut all stay, and the tie group contributes
-        its lowest item ids — exactly the canonical order the exact
-        path's tie-break produces.  Runs on one thread; the caller fans
-        chunks over two (numpy ufuncs and the selection release the GIL).
-        """
-        with obs.span("recommend.score", rows=int(w.shape[0])):
-            return self._score_select_rows_body(stacked, w, safe_idx,
-                                                q_means, seen_rows, m_short,
-                                                scale)
-
-    def _score_select_rows_body(self, stacked, w, safe_idx, q_means,
-                                seen_rows, m_short: int,
-                                scale: tuple) -> np.ndarray:
+    def _host_support_scores(self, stacked, safe_idx, w, q_means,
+                             scale: tuple):
+        """The support scores of one block on the host: the exact f32
+        num/den form with the clip-to-``scale`` epilogue, as (b, I)."""
         n_items = self.n_items
-        if _scipy_sparse is not None:
-            rows = np.repeat(np.arange(w.shape[0]), w.shape[1])
-            W = _scipy_sparse.csr_matrix(
-                (w.reshape(-1), (rows, safe_idx.reshape(-1))),
-                shape=(w.shape[0], self.n_users))
-            nd = (W @ stacked).toarray()              # (b, 2I)
-            num, den = nd[:, :n_items], nd[:, n_items:]
-            qm = q_means[:, None]
-            fallback = den <= 1e-8
-            np.maximum(den, 1e-8, out=den)
-            np.divide(num, den, out=num)
-            num += qm
-            if scale[0] <= scale[1]:
-                np.clip(num, *scale, out=num)
-            np.copyto(num, np.broadcast_to(qm, num.shape), where=fallback)
-        else:
-            num = np.asarray(_support_scores_jnp(
-                jnp.asarray(stacked), jnp.asarray(w),
-                jnp.asarray(safe_idx), jnp.asarray(q_means),
-                scale=scale)).copy()
-        num[seen_rows] = -np.inf
-        return self._select_shortlist(num, m_short)
+        if _scipy_sparse is None:
+            return _support_scores_jnp(jnp.asarray(stacked), w, safe_idx,
+                                       q_means, scale=scale)
+        w, safe_idx = np.asarray(w), np.asarray(safe_idx)
+        rows = np.repeat(np.arange(w.shape[0]), w.shape[1])
+        W = _scipy_sparse.csr_matrix(
+            (w.reshape(-1), (rows, safe_idx.reshape(-1))),
+            shape=(w.shape[0], self.n_users))
+        nd = (W @ stacked).toarray()              # (b, 2I)
+        num, den = nd[:, :n_items], nd[:, n_items:]
+        qm = np.asarray(q_means)[:, None]
+        fallback = den <= 1e-8
+        np.maximum(den, 1e-8, out=den)
+        np.divide(num, den, out=num)
+        num += qm
+        if scale[0] <= scale[1]:
+            np.clip(num, *scale, out=num)
+        np.copyto(num, np.broadcast_to(qm, num.shape), where=fallback)
+        return num
 
-    def _select_shortlist(self, num: np.ndarray, m_short: int) -> np.ndarray:
-        """Canonical top-``m_short`` selection over scored rows (seen items
-        already at -inf) with the tie-boundary repair of
-        ``_score_select_rows``'s docstring; the span's ``repaired`` counts
-        the rows that took the repair."""
-        with obs.span("recommend.select", rows=int(num.shape[0])) as sp:
-            shorts, repaired = self._select_shortlist_body(num, m_short)
-            sp.set_attr("repaired", repaired)
-            return shorts
-
-    def _select_shortlist_body(self, num: np.ndarray,
-                               m_short: int) -> Tuple[np.ndarray, int]:
-        n_items = self.n_items
-        # reprolint: disable=canonical-selection -- shortlist only (exact rerank follows); cut-value ties get the boundary repair below, same policy as _topm_rows
-        sel = np.argpartition(num, n_items - m_short,
-                              axis=1)[:, n_items - m_short:]
-        selv = np.take_along_axis(num, sel, 1)
-        shorts = np.where(selv == -np.inf, n_items, sel).astype(np.int32)
-        # canonical boundary repair (see _score_select_rows docstring)
-        vb = np.min(np.where(selv == -np.inf, np.inf, selv), axis=1)
-        vb = np.where(np.isfinite(vb), vb, np.inf)
-        row_cnt = np.count_nonzero(num == vb[:, None], axis=1)
-        sel_cnt = np.count_nonzero(selv == vb[:, None], axis=1)
-        repair = np.nonzero(row_cnt > sel_cnt)[0]
-        for row in repair:
-            v = vb[row]
-            above = np.nonzero(num[row] > v)[0]
-            tied = np.nonzero(num[row] == v)[0][:m_short - len(above)]
-            merged = np.concatenate([above, tied]).astype(np.int32)
-            shorts[row, :len(merged)] = merged
-            shorts[row, len(merged):] = n_items
-        return np.sort(shorts, axis=1), len(repair)
+    def _budget(self, m: int):
+        """The traced selection budget ``m`` as a device scalar, made once
+        per value so a served batch uploads only its ids."""
+        if m not in self._budgets:
+            self._budgets[m] = jnp.asarray(m, jnp.int32)
+        return self._budgets[m]
 
     def _recommend_support(self, ratings, means, nb_scores, nb_idx,
-                           uids: np.ndarray, *, n: int,
-                           scorer: str = "support",
-                           shortlist: Optional[int] = None):
+                           uids: np.ndarray, *, n: int, scorer: str,
+                           shortlist: int):
         """Support-scorer path: every item scored with the exact num/den
         predictor form, the canonical top ``shortlist`` unseen items per
         user go to the exact rerank.
 
-        ``scorer="support"`` is the item-major sparse pass — one
-        ``W @ [DEV|MASK]`` product between the k-sparse neighbor-weight
-        matrix and the stacked deviation/mask CSR, walked row-major.
-        ``scorer="kernel"`` computes the same num/den form with the fused
-        Pallas segmented SpMM (``repro.kernels.support``) — the TPU twin,
-        gathering each neighbor row tile once through VMEM.  Either way
-        the scorer *is* the predictor, so shortlist containment of the
-        exact top-n is limited only by float summation order; the rerank
-        then restores scores bit-consistent with the dense blocked path.
+        ``scorer="kernel"`` computes the num/den form with the fused
+        Pallas segmented SpMM (``repro.kernels.support``), gathering each
+        neighbor row tile once through VMEM; ``scorer="support"`` is its
+        host twin, one ``W @ [DEV|MASK]`` product between the k-sparse
+        neighbor-weight matrix and the stacked deviation/mask CSR.  Either
+        way the scorer *is* the predictor, so shortlist containment of the
+        exact top-n is limited only by float summation order.  Selection
+        and rerank run in one device program per block of
+        ``rerank_block`` rows (:func:`_select_rerank_items`): on the
+        kernel path the scores never leave the device, and a block uploads
+        only its ids.  Returns device arrays; the one fence is the last
+        block's, in its ``recommend.rerank`` span.
         """
-        from concurrent.futures import ThreadPoolExecutor
+        from repro.kernels.support import fused_support_scores
         stacked = (self._support_table(ratings, means)
                    if scorer == "support" else None)
         n_items = self.n_items
-        m_short = min(max(n, self.cfg.shortlist if shortlist is None
-                          else shortlist), n_items)
+        width = min(max(n, self.cfg.shortlist, shortlist), n_items)
+        m = self._budget(min(max(n, shortlist), n_items))
         gather_src = self._gather_source(ratings)
         scale = gather_src.scale
-        rnp = np.asarray(ratings)
-        means_np = np.asarray(means)
-        sc_np = np.asarray(nb_scores)
-        idx_np = np.asarray(nb_idx)
-        out_s = np.empty((len(uids), n), np.float32)
-        out_i = np.empty((len(uids), n), np.int32)
-        n_reranked = 0
         bq = min(self.cfg.rerank_block, _bucket(len(uids)))
-
-        def score_chunk(ids):
-            """Shortlists for one chunk, halved over two host threads.
-            The kernel scores on this thread, in a ``recommend.score``
-            span that its host copy fences; the host scorer scores on the
-            pool (``_score_select_rows``)."""
-            w = np.where((sc_np[ids] > 0) & (idx_np[ids] >= 0),
-                         sc_np[ids], 0.0).astype(np.float32)
-            safe = np.where(idx_np[ids] >= 0, idx_np[ids], 0)
-            seen = rnp[ids] > 0
-            half = (len(ids) + 1) // 2 if len(ids) >= 64 else len(ids)
-            if scorer == "kernel":
-                from repro.kernels.support import fused_support_scores
-                with obs.span("recommend.score", rows=len(ids)):
+        outs = []
+        n_reranked = None
+        for b0 in range(0, len(uids), bq):
+            sub = uids[b0:b0 + bq]
+            nv = len(sub)
+            ids_pad = np.full((bq,), self.n_users, np.int32)
+            ids_pad[:nv] = sub
+            ids_j = jnp.asarray(ids_pad)
+            with obs.span("recommend.score", rows=nv):
+                safe, w, q_means = _support_operands(nb_scores, nb_idx,
+                                                     means, ids_j)
+                if scorer == "kernel":
                     dev, msk = self._support_dense(ratings, means)
-                    num = np.asarray(fused_support_scores(
-                        dev, msk, jnp.asarray(safe), jnp.asarray(w),
-                        means[jnp.asarray(ids)], scale=scale,
-                        interpret=self.cfg.interpret))[:, :n_items].copy()
-                    num[seen] = -np.inf
-                return [pool.submit(self._select_shortlist,
-                                    num[h0:h0 + half], m_short)
-                        for h0 in range(0, len(ids), half)]
-            parts = [pool.submit(
-                self._score_select_rows, stacked, w[h0:h0 + half],
-                safe[h0:h0 + half], means_np[ids[h0:h0 + half]],
-                seen[h0:h0 + half], m_short, scale)
-                for h0 in range(0, len(ids), half)]
-            return parts
-
-        chunk_starts = list(range(0, len(uids), self.cfg.score_block))
-        with ThreadPoolExecutor(max_workers=2) as pool:
-            # pipeline: the host scorer of chunk i+1 overlaps the jax
-            # rerank of chunk i (XLA releases the GIL while executing)
-            pending = score_chunk(uids[chunk_starts[0]:
-                                       chunk_starts[0]
-                                       + self.cfg.score_block])
-            for ci, lo in enumerate(chunk_starts):
-                ids = uids[lo:lo + self.cfg.score_block]
-                with obs.span("recommend.select_wait", chunk=ci,
-                              rows=len(ids)):
-                    shorts = np.concatenate([p.result() for p in pending],
-                                            axis=0)
-                if ci + 1 < len(chunk_starts):
-                    nxt = chunk_starts[ci + 1]
-                    pending = score_chunk(
-                        uids[nxt:nxt + self.cfg.score_block])
-                n_reranked += int((shorts < n_items).sum())
-
-                # exact rerank in fixed-size jit batches
-                for b0 in range(0, len(ids), bq):
-                    sub = ids[b0:b0 + bq]
-                    nv = len(sub)
-                    ids_pad = np.full((bq,), self.n_users, np.int32)
-                    ids_pad[:nv] = sub
-                    ids_j = jnp.asarray(ids_pad)
-                    safe_j = jnp.clip(ids_j, 0, self.n_users - 1)
-                    sh_pad = np.full((bq, m_short), n_items, np.int32)
-                    sh_pad[:nv] = shorts[b0:b0 + nv]
-                    with obs.span("recommend.rerank", chunk=ci,
-                                  candidates=int((sh_pad[:nv]
-                                                  < n_items).sum()),
-                                  **_rerank_attrs(gather_src, nv,
-                                                  nb_idx.shape[1], n_items,
-                                                  self.cfg.item_block)):
-                        s_j, i_j = _rerank_items(
-                            ratings, gather_src, nb_scores[safe_j],
-                            nb_idx[safe_j], means, means[safe_j], ids_j,
-                            jnp.asarray(_shortlist_mask(sh_pad, n_items)),
-                            n=n, item_block=self.cfg.item_block)
-                        out_s[lo + b0:lo + b0 + nv] = np.asarray(s_j)[:nv]
-                        out_i[lo + b0:lo + b0 + nv] = np.asarray(i_j)[:nv]
+                    scores = fused_support_scores(
+                        dev, msk, safe, w, q_means, scale=scale,
+                        interpret=self.cfg.interpret)
+                else:
+                    scores = self._host_support_scores(stacked, safe, w,
+                                                       q_means, scale)
+            with obs.span("recommend.rerank", block=b0 // bq,
+                          **_rerank_attrs(gather_src, nv, nb_idx.shape[1],
+                                          n_items, self.cfg.item_block)):
+                s, i, cnt = _select_rerank_items(
+                    ratings, gather_src, nb_scores, nb_idx, means, ids_j,
+                    scores, m, n=n, shortlist=width,
+                    item_block=self.cfg.item_block)
+                if b0 + bq >= len(uids):
+                    jax.block_until_ready((s, i))
+            outs.append((s, i) if nv == bq else (s[:nv], i[:nv]))
+            n_reranked = cnt if n_reranked is None else n_reranked + cnt
+        obs.registry().counter("item_index.device_select_rows").inc(
+            len(uids))
 
         self.last_recommend = RecommendStats(
             n_queries=len(uids), n_items=n_items,
             n_probed=len(uids) * n_items, n_reranked=n_reranked,
             scorer=scorer)
-        return jnp.asarray(out_s), jnp.asarray(out_i)
+        if len(outs) == 1:
+            return outs[0]
+        return (jnp.concatenate([o[0] for o in outs]),
+                jnp.concatenate([o[1] for o in outs]))
 
     # -- delta-aware cache maintenance -------------------------------------
     def _patch_extra_row_caches(self, ratings, means, touched, old) -> int:

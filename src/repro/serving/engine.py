@@ -72,7 +72,6 @@ from concurrent.futures import Future
 from typing import List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
@@ -289,17 +288,12 @@ class BatchingServer:
         self._g_health = self.registry.gauge("serve.health")
         self._g_health.set(HEALTHY)
         # warm the executable with the padded batch shape
-        self._run_padded(jnp.zeros((self.max_batch,), jnp.int32))
+        self._run_padded(np.zeros((self.max_batch,), np.int32))
 
     def _run_padded(self, users, budget: Optional[dict] = None):
         if self._approx_engine is not None:
-            if budget:
-                return self._approx_engine.recommend(
-                    np.asarray(users), n=self.topn,
-                    n_probe=budget["n_probe"],
-                    shortlist=budget["shortlist"])
-            return self._approx_engine.recommend(np.asarray(users),
-                                                 n=self.topn)
+            return self._approx_engine.recommend(users, n=self.topn,
+                                                 **(budget or {}))
         ratings, scores, idx, means = self._snapshot()
         return _predict_users(users, ratings, scores, idx, means,
                               topn=self.topn, scale=self._scale(ratings))
@@ -541,8 +535,7 @@ class BatchingServer:
                 with obs.span("serve.predict", batch_size=len(sub),
                               batch_seq=seq, request_class=cls,
                               degraded=bool(budget)):
-                    scores, items = self._run_padded(jnp.asarray(users),
-                                                     budget)
+                    scores, items = self._run_padded(users, budget)
                     scores = np.asarray(scores)   # host copy = device fence
                     items = np.asarray(items)
                 now = time.perf_counter()

@@ -5,7 +5,8 @@ kernel lowers through Mosaic.  The compile tests here build the main-path
 kernels, as the served path dispatches them, at the ML-1M deployment's
 widths (U=6040 users, I=3952 items, proxy dim 256, k=40, shortlist
 M=0.15·U), and the fit's rerank and the item scorer at the ML-10M
-deployment's (U=69,878, I=10,677, half-star int8 code), for one chip of a
+deployment's (U=69,878, I=10,677, half-star int8 code), and the
+served path's select-and-rerank program there, for one chip of a
 described ``v5e:2x2`` topology — the TPU
 compiler is installed even where no chip is attached.  Each asserts the
 kernel is in the compiled program (``tpu_custom_call``) and bounds the
@@ -29,6 +30,7 @@ import pytest
 from repro.core.facade import AUTO_RERANK_FRAC
 from repro.core.predict import GatherSource
 from repro.index import clustered as cl
+from repro.index import item_index
 from repro.kernels import ref
 from repro.kernels import support as sup
 from repro.kernels.cluster import fused_centroid_distances
@@ -145,6 +147,22 @@ def test_support_kernel_compiles_for_v5e_at_ml10m(spec):
                  spec((16, K), jnp.int32), spec((16, K)), spec((16,)))
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_select_rerank_fits_ml10m(spec):
+    """The support path's select-and-rerank program of a served ML-10M
+    batch: 16 rows of kernel scores (16 × 10,752 f32), top-512 and the
+    exact rerank over the int8 code.  Its temps are the rerank's own,
+    the (U, 512) item-tile slices of the code (586 MB compiled for the
+    rerank alone, 587 MB with the selection), so the bound is 640 MB."""
+    src = GatherSource(spec((U10, I10), jnp.int8), 0.5, (0.5, 5.0))
+    fn = lambda r, s, *a: item_index._select_rerank_items(
+        r, s, *a, n=10, shortlist=512, item_block=512)
+    c = _compile(fn, spec((U10, I10)), src, spec((U10, K)),
+                 spec((U10, K), jnp.int32), spec((U10,)),
+                 spec((16,), jnp.int32), spec((16, 21 * 512)),
+                 spec((), jnp.int32))
+    assert c.memory_analysis().temp_size_in_bytes < 640 << 20
 
 
 @pytest.mark.parametrize("frac, bound_mb", [(0.15, 2500),

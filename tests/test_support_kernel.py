@@ -12,7 +12,8 @@ from repro.core import similarity as sim
 from repro.core.predict import rating_scale
 from repro.index import (ClusteredIndex, IndexConfig, ItemClusteredIndex,
                          ItemIndexConfig)
-from repro.index.item_index import _affinity_weights, _fold_profiles
+from repro.index.item_index import (_affinity_weights,
+                                    _canonical_top_mask, _fold_profiles)
 from repro.kernels import ref
 from repro.kernels.support import (fused_support_scores, support_rows,
                                    support_width)
@@ -88,37 +89,40 @@ def test_kernel_shortlist_mode_matches_support(rng):
 
 
 def test_kernel_path_stage_spans_and_repair_counts(rng):
-    """On the kernel path the score stage, the wait for host selection and
-    the rerank are spans on the calling thread under
-    ``item_index.recommend``; each ``recommend.select`` counts the rows it
-    selected and those that took the tie-boundary repair."""
+    """On the kernel path each block of rows is a ``recommend.score`` and
+    a ``recommend.rerank`` span on the calling thread under
+    ``item_index.recommend``; selection runs on the device, so no host
+    selection span appears, and ``item_index.device_select_rows`` counts
+    every row recommended."""
     from repro import obs
     r = _ratings(rng, 180, 140)
     eng = CFEngine(r, measure="cosine", k=8, recommend_mode="approx",
                    item_index_cfg=ItemIndexConfig(
-                       n_clusters=8, seed=0, shortlist=32, score_block=64,
+                       n_clusters=8, seed=0, shortlist=32, rerank_block=64,
                        shortlist_mode="kernel", interpret=True)).fit()
+    counter = obs.registry().counter("item_index.device_select_rows")
+    before = counter.value
     obs.clear()
     eng.recommend(n=5)
     spans = obs.get_spans()
     (root,) = [s for s in spans if s.name == "item_index.recommend"]
-    for name in ("recommend.score", "recommend.select_wait",
-                 "recommend.rerank"):
+    for name in ("recommend.score", "recommend.rerank"):
         stage = [s for s in spans if s.name == name]
-        assert stage and all(s.parent_id == root.span_id for s in stage)
-    assert sorted(s.attrs["chunk"] for s in spans
-                  if s.name == "recommend.select_wait") == [0, 1, 2]
-    selects = [s for s in spans if s.name == "recommend.select"]
-    assert sum(s.attrs["rows"] for s in selects) == 180
-    assert all(0 <= s.attrs["repaired"] <= s.attrs["rows"] for s in selects)
+        assert len(stage) == 3
+        assert all(s.parent_id == root.span_id for s in stage)
+        assert sum(s.attrs["rows"] for s in stage) == 180
+    assert sorted(s.attrs["block"] for s in spans
+                  if s.name == "recommend.rerank") == [0, 1, 2]
+    assert not [s for s in spans
+                if s.name in ("recommend.select", "recommend.select_wait")]
+    assert counter.value - before == 180
 
-    # every score tied: each row takes the repair, and keeps the lowest ids
-    obs.clear()
-    ii = eng.item_index
-    shorts = ii._select_shortlist(np.full((5, 140), 3.0, np.float32), 32)
-    np.testing.assert_array_equal(shorts, np.tile(np.arange(32), (5, 1)))
-    (sel,) = obs.get_spans()
-    assert sel.attrs["repaired"] == sel.attrs["rows"] == 5
+    # every score tied: each row keeps the lowest ids
+    mask = np.asarray(_canonical_top_mask(
+        jnp.full((5, 140), 3.0, jnp.float32), jnp.int32(32), width=32))
+    assert mask.sum(axis=1).tolist() == [32] * 5
+    np.testing.assert_array_equal(np.nonzero(mask)[1].reshape(5, 32),
+                                  np.tile(np.arange(32), (5, 1)))
 
 
 def test_shortlist_mode_validation():
