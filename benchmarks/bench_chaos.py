@@ -17,7 +17,7 @@ exactly once, so recovery is reproducible, not probabilistic):
   must produce **bit-identical** recommendations to a fault-free run and
   a consistent index ledger.
 * **degraded recall** — the DEGRADED rung of the ladder (staged query
-  mode + halved ``n_probe``/``shortlist`` budgets, exactly what
+  mode + a halved ``shortlist`` budget, exactly what
   ``DegradationLadder.budget`` hands the batcher) against the
   full-budget path at U=8192: recall@20 must hold the 0.90 floor.
 
@@ -50,17 +50,15 @@ from repro.serving.engine import (DEGRADED, BatchingServer,
 RECALL_USERS = 8192          # acceptance size for the DEGRADED recall floor
 
 
-def _engine(u, d, *, seed=0, n_clusters=32, n_probe=8, shortlist=256):
+def _engine(u, d):
     from repro.index import ItemIndexConfig
     train, _, _ = load_ml1m_synthetic(n_users=u, n_items=d)
     return CFEngine(jnp.asarray(train), measure="cosine", k=40,
                     block_size=256, neighbor_mode="approx",
                     recommend_mode="approx",
-                    index_cfg=IndexConfig(n_clusters=n_clusters,
-                                          n_probe=n_probe, seed=seed,
+                    index_cfg=IndexConfig(n_clusters=32, n_probe=8, seed=0,
                                           features="raw"),
-                    item_index_cfg=ItemIndexConfig(
-                        shortlist=shortlist)).fit()
+                    item_index_cfg=ItemIndexConfig(shortlist=256)).fit()
 
 
 def _drain(futures, timeout=60.0):
@@ -220,8 +218,7 @@ def drill_degraded_recall(u, d, *, topn=20):
     users = np.arange(u, dtype=np.int32)
     _, ref_i = map(np.asarray, eng.recommend(users, n=topn))
     lad = DegradationLadder()
-    budget = lad.budget(DEGRADED, eng.item_index.n_probe,
-                        eng.item_index.cfg.shortlist, topn)
+    budget = lad.budget(DEGRADED, eng.item_index.cfg.shortlist, topn)
     eng.index.query_mode_override = "staged"
     _, got_i = map(np.asarray, eng.recommend(users, n=topn, **budget))
     eng.index.query_mode_override = None

@@ -1,20 +1,18 @@
 """Two-stage recommend path vs dense blocked prediction, at scale.
 
 For each user count the benchmark fits one engine (approx neighbor cache +
-item index on the same synthetic ML-1M surrogate, full item axis), then
+item stage on the same synthetic ML-1M surrogate, full item axis), then
 produces top-n recommendations for *every* user twice:
 
 * **dense** — the exact path: blocked neighbor-weighted prediction over
-  all I items per user (item-tiled, the O(U·k·I) compute wall this PR's
-  index exists to break), canonical top-n;
-* **approx** — the two-stage path: probe item clusters near the user's
-  neighbor-taste profile → proxy shortlist → exact rerank of
-  ``shortlist`` items per user.
+  all I items per user (item-tiled), canonical top-n;
+* **approx** — the item stage: support scores over every item → device
+  top-``shortlist`` → exact rerank of the shortlist.
 
 Reported: end-to-end recommend throughput for both paths, their ratio
-(``recommend_speedup`` — the acceptance metric), recommendation recall@n
-of approx against dense, and the item-index fit cost.  All timings are
-single-shot from a cold process (compile time included on both sides).
+(``recommend_speedup``), and recommendation recall@n of approx against
+dense.  All timings are single-shot from a cold process (compile time
+included on both sides).
 
 Writes ``BENCH_recommend.json`` so the perf trajectory is
 machine-readable across PRs.
@@ -35,7 +33,7 @@ import numpy as np
 
 DEFAULT_SIZES = (2048, 8192, 32768)
 
-# per-size item-index overrides: the item catalog stays ML-1M-sized, so one
+# per-size user-index overrides: the item catalog stays ML-1M-sized, so one
 # shortlist budget works across user counts; the neighbor-side knobs follow
 # bench_index's tuning (thinner rerank, wider proxies past 10⁴ users)
 NEIGHBOR_RERANK = {32768: 0.03}
@@ -60,7 +58,7 @@ def _recall(ref_i: np.ndarray, got_i: np.ndarray) -> float:
 
 def run(sizes=DEFAULT_SIZES, n: int = 10, k: int = 40,
         measure: str = "cosine", n_items=None, seed: int = 0,
-        shortlist: int = 64, item_kwargs=None) -> list:
+        shortlist: int = 64) -> list:
     from repro import obs
     from repro.core import CFEngine
     from repro.data import load_ml1m_synthetic
@@ -73,8 +71,6 @@ def run(sizes=DEFAULT_SIZES, n: int = 10, k: int = 40,
                                           seed=seed)
         ratings = jnp.asarray(train)
 
-        ikw = dict(seed=seed, shortlist=shortlist)
-        ikw.update(item_kwargs or {})
         engine = CFEngine(
             ratings, measure=measure, k=k,
             neighbor_mode="approx",
@@ -84,15 +80,11 @@ def run(sizes=DEFAULT_SIZES, n: int = 10, k: int = 40,
                 rerank_frac=NEIGHBOR_RERANK.get(n_users, 0.15),
                 project_dim=NEIGHBOR_PROJECT.get(n_users, 256)),
             recommend_mode="approx",
-            item_index_cfg=ItemIndexConfig(**ikw))
+            item_index_cfg=ItemIndexConfig(shortlist=shortlist))
 
         t0 = time.perf_counter()
         engine.fit()
         fit_s = time.perf_counter() - t0
-        # isolate the item-index share of the fit (a second cold fit)
-        t0 = time.perf_counter()
-        engine.item_index.fit(engine.ratings, engine.means)
-        item_fit_s = time.perf_counter() - t0
 
         t0 = time.perf_counter()
         _, dense_i = engine.recommend(n=n, mode="exact")
@@ -114,10 +106,8 @@ def run(sizes=DEFAULT_SIZES, n: int = 10, k: int = 40,
             "n_items": int(ratings.shape[1]),
             "k": k,
             "topn": n,
-            "n_item_clusters": engine.item_index.n_clusters,
-            "shortlist": ikw["shortlist"],
+            "shortlist": shortlist,
             "fit_s": round(fit_s, 3),
-            "item_index_fit_s": round(item_fit_s, 3),
             "dense_recommend_s": round(dense_s, 3),
             "approx_recommend_s": round(approx_s, 3),
             "dense_users_per_s": round(n_users / dense_s, 1),
@@ -132,7 +122,7 @@ def run(sizes=DEFAULT_SIZES, n: int = 10, k: int = 40,
         })
         print(f"U={n_users}: dense={dense_s:.1f}s approx={approx_s:.1f}s "
               f"speedup={speedup:.2f}x recall@{n}={recall:.4f} "
-              f"rerank={frac:.3f} (item fit {item_fit_s:.1f}s)")
+              f"rerank={frac:.3f}")
     return rows
 
 

@@ -304,9 +304,7 @@ class CFEngine:
             from repro.index import ItemClusteredIndex, ItemIndexConfig
             if item_index_cfg is None:
                 item_index_cfg = ItemIndexConfig()
-            self.item_index = ItemClusteredIndex(item_index_cfg,
-                                                 mesh=self.mesh,
-                                                 mesh_axis=self.axis)
+            self.item_index = ItemClusteredIndex(item_index_cfg)
 
         self.scores: Optional[jnp.ndarray] = None    # (U, k)
         self.idx: Optional[jnp.ndarray] = None       # (U, k)
@@ -316,10 +314,10 @@ class CFEngine:
         self._snapshot: Optional[tuple] = None       # atomically-published
         self._gather_cache: Optional[tuple] = None   # gather code + scale
         # ratings version counter: every update_ratings bumps it, and the
-        # derived per-ratings caches (the gather operand here, the CSR /
-        # pair-table / support caches inside the indexes) are delta-patched
-        # along the version chain instead of rebuilt wholesale — a
-        # 1-rating delta no longer pays an O(U·I) cache rebuild
+        # derived per-ratings caches (the gather code here, the user
+        # index's CSR / pair tables, the item stage's support tables) are
+        # delta-patched along the version chain instead of rebuilt
+        # wholesale — a 1-rating delta no longer pays an O(U·I) rebuild
         self.ratings_version = 0
         self.fit_seconds = 0.0
         self.last_update: Optional[UpdateStats] = None
@@ -355,12 +353,11 @@ class CFEngine:
                 self.index.fit(self.ratings, self.means)
                 self.scores, self.idx = self.index.query(
                     self.ratings, self.means, k=self.k,
-                    measure=self.measure, beta=self.pcc_sig_beta)
+                    measure=self.measure, beta=self.pcc_sig_beta,
+                    gather_src=self._gather_source(self.ratings))
             else:
                 with obs.span("fit.topk", backend=self.backend):
                     self.scores, self.idx = self._topk(self.ratings)
-            if self.item_index is not None:
-                self.item_index.fit(self.ratings, self.means)
             self.scores = jax.block_until_ready(self.scores)
             self._snapshot = (self.ratings, self.scores, self.idx,
                               self.means)
@@ -431,14 +428,15 @@ class CFEngine:
         with ``oracle_check`` the refreshed cache is verified bit-for-bit
         against a cold recompute (raises ``RuntimeError`` on any mismatch).
 
-        In approx mode the clustered index is refolded first (touched
-        proxies, centroid mass, and spill assignments repaired exactly —
-        see ``repro.index``), then the same certificate machinery repairs
-        the neighbor cache: certified rows merge the fresh touched-pair
-        scores (true similarities), uncertified and touched rows re-query
-        the index.  ``oracle_check`` then asserts the index consistency
-        invariant instead of bitwise cache equality, which is an
-        exact-mode concept.
+        The gather code, and the item stage's support tables, are patched
+        first, once, for every reader.  In approx mode the clustered index
+        is refolded next (touched proxies, centroid mass, and spill
+        assignments repaired exactly — see ``repro.index``), then the same
+        certificate machinery repairs the neighbor cache: certified rows
+        merge the fresh touched-pair scores (true similarities),
+        uncertified and touched rows re-query the index.  ``oracle_check``
+        then asserts the index consistency invariant instead of bitwise
+        cache equality, which is an exact-mode concept.
 
         The ``pallas`` backend refits in full instead of repairing: its
         cached scores carry the fused kernel's rounding, which the XLA
@@ -496,7 +494,8 @@ class CFEngine:
         pad_touch_j = jnp.asarray(pad_touch)
         self._cnt, self._tot, self.means = _refold_stats(
             self.ratings, self._cnt, self._tot, pad_touch_j)
-        # delta-patch the recommend gather operand along the version chain
+        # delta-patch the gather code and the item stage's support tables
+        # along the version chain, before either index reads them
         # (copy-on-write: concurrent snapshot readers keep the old operand;
         # single local read of the cache reference — see _gather_source)
         gather_cache = self._gather_cache
@@ -505,13 +504,12 @@ class CFEngine:
                 gather_cache[1], self.ratings, pad_touch_j))
         else:
             self._gather_cache = None
+        if self.item_index is not None:
+            self.item_index.patch(prev_ratings, self.ratings, self.means,
+                                  pad_touch_j)
         if self.neighbor_mode == "approx":
             self.index.refold(self.ratings, self.means, touched,
                               version=self.ratings_version)
-        if self.item_index is not None:
-            self.item_index.refold(self.ratings, self.means, touched,
-                                   np.unique(item_ids),
-                                   version=self.ratings_version)
 
         # the pallas backend's scores carry the fused kernel's rounding; the
         # XLA-scored repair path would mix incomparable floats into the
@@ -554,10 +552,10 @@ class CFEngine:
             rows[:len(affected)] = affected
             rows_j = jnp.asarray(rows)
             if self.neighbor_mode == "approx":
-                q_s, q_i = self.index.query(self.ratings, self.means,
-                                            affected, k=self.k,
-                                            measure=self.measure,
-                                            beta=self.pcc_sig_beta)
+                q_s, q_i = self.index.query(
+                    self.ratings, self.means, affected, k=self.k,
+                    measure=self.measure, beta=self.pcc_sig_beta,
+                    gather_src=self._gather_source(self.ratings))
                 new_s = np.full((a_pad, self.k), nb.NEG_INF, np.float32)
                 new_i = np.full((a_pad, self.k), -1, np.int32)
                 new_s[:len(affected)] = np.asarray(q_s)
@@ -588,10 +586,7 @@ class CFEngine:
         """Exact mode: assert cache == cold full recompute, bit for bit.
         Approx mode: the cache is defined by the index's candidate policy,
         so the oracle instead asserts the *index* invariant — assignments
-        and proxies equal a cold reassignment — plus exact means.  A
-        fitted item index is consistency-checked in either mode."""
-        if self.item_index is not None:
-            self.item_index.check_consistent(self.ratings, self.means)
+        and proxies equal a cold reassignment — plus exact means."""
         if self.neighbor_mode == "approx":
             ok = self.index.check_consistent(self.ratings, self.means)
             _, _, ref_m = _user_stats(self.ratings)
@@ -668,11 +663,12 @@ class CFEngine:
         fault that tears the model mid-update restores the last committed
         tree with :meth:`load_state`.
 
-        Every leaf is a fresh host copy (the index cores hand out live
+        Every leaf is a fresh host copy (the index core hands out live
         ledger references), so a captured tree can never alias state a
-        later update mutates in place.  Derived caches (gather operand,
+        later update mutates in place.  Derived caches (gather code,
         CSR/pair/support tables) are deliberately absent: they are keyed
-        by ratings-array identity and rebuild lazily after a restore.
+        by ratings-array identity and rebuild lazily after a restore.  The
+        item stage holds no other state, so it has no entry.
         """
         if not self.fitted:
             raise RuntimeError("call fit() first")
@@ -686,13 +682,11 @@ class CFEngine:
             "cnt": np.array(self._cnt),
             "tot": np.array(self._tot),
             "meta": np.asarray([self.ratings_version], np.int64),
-            # a fitted engine implies fitted indexes (fit() fits both), so
-            # presence alone decides the tree structure — state_template()
-            # must mirror it exactly for checkpoint.restore(like=...)
+            # a fitted engine implies a fitted index, so presence alone
+            # decides the tree structure — state_template() must mirror it
+            # exactly for checkpoint.restore(like=...)
             "index": copy(self.index.state())
             if self.index is not None else {},
-            "item_index": copy(self.item_index.state())
-            if self.item_index is not None else {},
         }
 
     def state_template(self) -> dict:
@@ -703,8 +697,6 @@ class CFEngine:
                               "cnt", "tot", "meta")}
         out["index"] = (type(self.index).state_template()
                         if self.index is not None else {})
-        out["item_index"] = (type(self.item_index).state_template()
-                             if self.item_index is not None else {})
         return out
 
     def load_state(self, tree: dict) -> "CFEngine":
@@ -714,7 +706,11 @@ class CFEngine:
         (identity-keyed, so they rebuild lazily and can never serve the
         torn model), and the snapshot is republished atomically — a
         concurrent reader flips to the restored model in one reference
-        swap, exactly like a successful update."""
+        swap, exactly like a successful update.
+
+        A tree written before the item stage became stateless also holds
+        an ``item_index`` entry of cluster state; it is ignored (restore
+        such a file with its own structure as ``like``)."""
         self.ratings = jnp.asarray(np.asarray(tree["ratings"], np.float32))
         scores = jnp.asarray(np.asarray(tree["scores"], np.float32))
         self.idx = jnp.asarray(np.asarray(tree["idx"], np.int32))
@@ -725,8 +721,6 @@ class CFEngine:
         self._gather_cache = None
         if self.index is not None and tree.get("index"):
             self.index.load_state(tree["index"])
-        if self.item_index is not None and tree.get("item_index"):
-            self.item_index.load_state(tree["item_index"])
         self.scores = jax.block_until_ready(scores)
         self._snapshot = (self.ratings, self.scores, self.idx, self.means)
         obs.registry().gauge("engine.ratings_version").set(
@@ -734,11 +728,12 @@ class CFEngine:
         return self
 
     def _gather_source(self, ratings):
-        """Gather operand of the recommend/predict gathers
-        (``predict.GatherSource``: an int8 code when the matrix round-trips
-        exactly) and the engine's rating scale, derived once per ratings
-        array and only widened by updates (cached per ratings array — a
-        rating update replaces the array, which invalidates by identity).
+        """The engine's one gather operand (``predict.GatherSource``: an
+        int8 code when the matrix round-trips exactly) and its rating
+        scale, read by predict, both recommend paths and the user index's
+        rerank: derived once per ratings array, patched by updates, the
+        scale only widened (cached per ratings array — a rating update
+        replaces the array, which invalidates by identity).
 
         Read the cache reference ONCE: the serving batcher calls this
         while ``update_ratings`` may swap ``_gather_cache`` on the writer
@@ -778,30 +773,28 @@ class CFEngine:
 
     def recommend(self, user_ids=None, n: int = 10, *,
                   mode: Optional[str] = None,
-                  n_probe: Optional[int] = None,
                   shortlist: Optional[int] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Top-n unseen items ``(scores, item ids)`` for ``user_ids``.
 
         ``mode`` overrides the engine's ``recommend_mode`` per call
-        (``"approx"`` requires a fitted item index).  ``n_probe`` and
-        ``shortlist`` are per-call candidate budgets forwarded to the
-        item index (approx mode only — the exact path has no candidate
-        stage, so passing them there raises instead of silently ignoring
-        a quality knob).  The serving degradation ladder uses them to
-        trade recall for latency per request class.  The exact path
-        streams user blocks × item tiles — peak memory O(UB·k·IB); the
-        approx path runs the two-stage item-index pipeline and returns
-        exact predicted ratings for an approximate candidate set.  Slots a
-        user cannot fill (fewer unseen items than ``n``) come back as item
-        -1 with score -inf in both modes; already-rated items are never
+        (``"approx"`` requires the item stage).  ``shortlist`` is a
+        per-call candidate budget forwarded to the item stage (approx
+        mode only — the exact path has no candidate stage, so passing it
+        there raises instead of silently ignoring a quality knob).  The
+        serving degradation ladder uses it to trade recall for latency
+        per request class.  The exact path streams user blocks × item
+        tiles — peak memory O(UB·k·IB); the approx path scores every item,
+        shortlists on the device and reranks exactly, returning exact
+        predicted ratings for an approximate candidate set.  Slots a user
+        cannot fill (fewer unseen items than ``n``) come back as item -1
+        with score -inf in both modes; already-rated items are never
         returned.
 
-        Model arrays come from the atomically-published snapshot, so a
-        concurrent ``update_ratings`` can never produce a torn read (the
-        item index's internal cluster state only shapes the *candidate*
-        set, never the returned scores, so index mutation mid-call is a
-        quality concern, not a correctness one).
+        Model arrays and the gather code come from the atomically
+        published snapshot and its ratings array, so a concurrent
+        ``update_ratings`` can never produce a torn read: the item stage's
+        support tables are keyed by that same ratings array.
         """
         if not self.fitted:
             raise RuntimeError("call fit() first")
@@ -811,11 +804,13 @@ class CFEngine:
         ratings, scores, idx, means = self.snapshot()
         uids = (np.arange(self.n_users, dtype=np.int32) if user_ids is None
                 else np.atleast_1d(np.asarray(user_ids, np.int32)))
+        src = self._gather_source(ratings)
         if mode == "approx":
-            if self.item_index is None or not self.item_index.fitted:
+            if self.item_index is None:
                 raise RuntimeError(
-                    "recommend(mode='approx') needs a fitted item index — "
-                    "construct with recommend_mode='approx' and fit()")
+                    "recommend(mode='approx') needs the item stage — "
+                    "construct with recommend_mode='approx'")
+            kw = dict(n=n, shortlist=shortlist, gather_src=src)
             # taste-cluster query order: users of one cluster share
             # neighbors, so the support scorer re-reads the same table
             # rows while they are still cache-resident; results are
@@ -824,20 +819,17 @@ class CFEngine:
                     and len(uids) > 4096:
                 perm = np.argsort(self.index.assign[uids], kind="stable")
                 s, i = self.item_index.recommend(
-                    ratings, means, scores, idx, uids[perm], n=n,
-                    n_probe=n_probe, shortlist=shortlist)
+                    ratings, means, scores, idx, uids[perm], **kw)
                 inv = np.empty_like(perm)
                 inv[perm] = np.arange(len(perm))
                 return s[jnp.asarray(inv)], i[jnp.asarray(inv)]
-            return self.item_index.recommend(
-                ratings, means, scores, idx, uids, n=n,
-                n_probe=n_probe, shortlist=shortlist)
+            return self.item_index.recommend(ratings, means, scores, idx,
+                                             uids, **kw)
 
-        if n_probe is not None or shortlist is not None:
+        if shortlist is not None:
             raise ValueError(
-                "n_probe/shortlist are approx-mode candidate budgets; the "
-                "exact path scores every item and cannot honor them")
-        src = self._gather_source(ratings)
+                "shortlist is an approx-mode candidate budget; the exact "
+                "path scores every item and cannot honor it")
         out_s = np.empty((len(uids), n), np.float32)
         out_i = np.empty((len(uids), n), np.int32)
         ub = min(USER_BLOCK, _bucket(len(uids), self.n_users))
@@ -859,8 +851,8 @@ class CFEngine:
                                   seed: int = 0) -> float:
         """Mean recall@n of approx recommendations against the exact
         blocked path on a seeded user sample — the recommend analogue of
-        ``recall_vs_exact``.  1.0 when the item index degenerates to full
-        probing with an uncapped shortlist."""
+        ``recall_vs_exact``.  1.0 when the item stage's shortlist is
+        uncapped."""
         if not self.fitted:
             raise RuntimeError("call fit() first")
         rng = np.random.default_rng(seed)

@@ -3,13 +3,11 @@
 ``ClusteredIndex`` partitions *users* with blocked spill k-means, then
 answers neighbor queries by probing the nearest clusters and *exactly*
 reranking only their members — true similarity scores at sublinear
-candidate-generation cost.  ``ItemClusteredIndex`` applies the same
-machinery to *item columns* and powers the two-stage recommend path:
-probe item clusters near the query's neighbor-taste profile, shortlist by
-proxy affinity, exactly rerank with the true neighbor-weighted
-prediction.  ``CFEngine(neighbor_mode="approx")`` /
-``CFEngine(recommend_mode="approx")`` are the integrated entry points;
-both indexes checkpoint through ``state()``/``load_state()``.
+candidate-generation cost; it checkpoints through
+``state()``/``load_state()``.  ``ItemClusteredIndex`` is the stateless
+recommend stage: the exact predictor's support scores over every item, a
+device shortlist, an exact rerank.  ``CFEngine(neighbor_mode="approx")``
+/ ``CFEngine(recommend_mode="approx")`` are the integrated entry points.
 """
 
 from repro.index.clustered import (ClusteredIndex, IndexConfig, QueryStats,

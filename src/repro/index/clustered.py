@@ -300,9 +300,6 @@ class RefoldStats:
                                    # from scratch on next use)
     refit: bool = False            # this call crossed the drift threshold
                                    # and performed a cold refit
-    profile_refold: bool = False   # item index only: this call re-folded
-                                   # the user taste profiles from scratch,
-                                   # zeroing accumulated Σ w·Δproxy error
 
 
 @functools.partial(jax.jit, static_argnames=("features", "spherical"))
@@ -825,16 +822,23 @@ def _whole_operand(r_gather):
                                  r_gather.scale)
 
 
-class _SpillClusterCore:
-    """Axis-agnostic core shared by the user- and item-side indexes.
+def _call_source(ratings, gather_src):
+    """The caller's gather code of ``ratings``, or one derived for this
+    call only (a standalone index call; nothing is cached)."""
+    return (gather_src if gather_src is not None
+            else pred_mod.make_gather_source(ratings))
 
-    Owns the spill-cluster bookkeeping over generic *rows* (user rows for
-    :class:`ClusteredIndex`, item columns for
-    :class:`repro.index.ItemClusteredIndex`): k-means fit + spill
-    assignment, the exact certificate-based refold of assignments and the
-    centroid-mass ledger, the auto-refit drift guard, and checkpointable
-    state.  Subclasses provide the feature map (``_proxy_rows``) and the
-    query semantics.
+
+class _SpillClusterCore:
+    """The spill-cluster core of :class:`ClusteredIndex`.
+
+    Owns the spill-cluster bookkeeping over generic *rows*: k-means fit +
+    spill assignment, the exact certificate-based refold of assignments
+    and the centroid-mass ledger, the auto-refit drift guard, the host CSR
+    and pair-table caches, and checkpointable state.  The subclass
+    provides the feature map (``_proxy_rows``) and the query semantics.
+    The gather code is the caller's (``CFEngine``), passed into each
+    query.
     """
 
     def __init__(self, cfg, mesh=None, mesh_axis: str = "data"):
@@ -870,7 +874,6 @@ class _SpillClusterCore:
         self.kmeans_stats: Optional[KMeansStats] = None
         self.last_refold: Optional[RefoldStats] = None
         self._reassigned_since_fit = 0
-        self._gather_cache: Optional[tuple] = None
         self._csr_cache: Optional[tuple] = None        # per-ratings CSR
         self._proxies_np_cache: Optional[tuple] = None # per-proxies host copy
         self._short_buf = None                         # torch GEMM output
@@ -975,31 +978,18 @@ class _SpillClusterCore:
         self._proxies_np_cache = (self.proxies, p_np)
         return p_np
 
-    def _gather_source(self, ratings):
-        """Rerank gather operand and rating scale
-        (``predict.make_gather_source``: an int8 code when exact), cached
-        per ratings array — a rating update replaces the array, which
-        invalidates by identity."""
-        if self._gather_cache is not None and \
-                self._gather_cache[0] is ratings:
-            return self._gather_cache[1]
-        src = pred_mod.make_gather_source(ratings)
-        self._gather_cache = (ratings, src)
-        return src
-
     # -- delta-aware cache maintenance -------------------------------------
     def _patch_row_caches(self, ratings, touched: np.ndarray,
-                          version: Optional[int], means=None) -> int:
+                          version: Optional[int]) -> int:
         """Advance the ratings version chain and delta-patch the derived
-        per-ratings caches (gather source, CSR, pair tables) for a row
-        delta, in place of the wholesale rebuild an identity miss forces.
+        per-ratings caches (CSR, pair tables) for a row delta, in place of
+        the wholesale rebuild an identity miss forces.
 
-        ``touched``: sorted unique changed row ids of the *row axis the
-        caches are keyed on* (users — both indexes derive their rerank
-        operands from user rows).  ``version``: the caller's ratings
-        version counter; when provided it must be exactly one past the
-        version this core last saw, else the chain is broken (an unknown
-        number of deltas passed by) and every cache is dropped.  Returns
+        ``touched``: sorted unique changed user ids.  ``version``: the
+        caller's ratings version counter; when provided it must be exactly
+        one past the version this core last saw, else the chain is broken
+        (an unknown number of deltas passed by) and every cache is
+        dropped.  Returns
         the number of caches patched.
         """
         old = self._ratings_key
@@ -1011,20 +1001,11 @@ class _SpillClusterCore:
                                  else self._ratings_version + 1)
         if not chain_ok:
             if ratings is not old:
-                self._gather_cache = None
                 self._csr_cache = None
-                self._drop_extra_row_caches()
             return 0
         patched = 0
-        touched_j = jnp.asarray(touched)
-        if self._gather_cache is not None and self._gather_cache[0] is old:
-            self._gather_cache = (ratings, pred_mod.patch_gather_source(
-                self._gather_cache[1], ratings, touched_j))
-            patched += 1
-        else:
-            self._gather_cache = None
         if self._csr_cache is not None and self._csr_cache[0] is old:
-            rows_new = np.asarray(ratings[touched_j])
+            rows_new = np.asarray(ratings[jnp.asarray(touched)])
             csr = _patch_csr(self._csr_cache[1], touched, rows_new)
             entry = (ratings, csr)
             patched += 1
@@ -1035,8 +1016,6 @@ class _SpillClusterCore:
             self._csr_cache = entry
         else:
             self._csr_cache = None
-        patched += self._patch_extra_row_caches(ratings, means, touched,
-                                                old)
         return patched
 
     def _patch_item_tables(self, old_tables, csr, touched: np.ndarray,
@@ -1064,14 +1043,6 @@ class _SpillClusterCore:
             tables[int(b)] = self._bucket_table(indptr, indices, data,
                                                 rows, int(b))
         return bucket_of, local_of, tables
-
-    def _patch_extra_row_caches(self, ratings, means, touched: np.ndarray,
-                                old) -> int:
-        """Subclass hook: delta-patch caches the core does not own."""
-        return 0
-
-    def _drop_extra_row_caches(self) -> None:
-        """Subclass hook: wholesale invalidation on a broken chain."""
 
     # -- resolution --------------------------------------------------------
     @property
@@ -1319,7 +1290,7 @@ class _SpillClusterCore:
         as an empty array so the tree structure is fixed."""
         if not self.fitted:
             raise RuntimeError("call fit() first")
-        out = {
+        return {
             "basis": (np.zeros((0, 0), np.float32) if self.basis is None
                       else np.asarray(self.basis)),
             "centroids": np.asarray(self.centroids),
@@ -1331,8 +1302,6 @@ class _SpillClusterCore:
             "spill_ids": self.spill_ids,
             "sums": self._sums,
         }
-        out.update(self._extra_state())
-        return out
 
     @classmethod
     def state_template(cls) -> dict:
@@ -1361,14 +1330,7 @@ class _SpillClusterCore:
         self._counts = np.array(tree["counts"], np.int64)
         self.kmeans_stats = None
         self._rebuild_members()
-        self._load_extra_state(tree)
         return self
-
-    def _extra_state(self) -> dict:
-        return {}
-
-    def _load_extra_state(self, tree: dict) -> None:
-        pass
 
 
 class ClusteredIndex(_SpillClusterCore):
@@ -1847,15 +1809,18 @@ class ClusteredIndex(_SpillClusterCore):
     def query(self, ratings: jnp.ndarray, means: jnp.ndarray,
               user_ids=None, *, k: int, measure: str = "pcc",
               n_probe: Optional[int] = None,
-              beta: Optional[float] = None
+              beta: Optional[float] = None, gather_src=None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Top-k true-similarity neighbors through the two-stage pipeline.
 
         Returns ``(scores, neighbor_ids)`` of shape ``(len(user_ids), k)``;
         sets ``self.last_query`` with work accounting and per-stage wall
         times.  ``beta`` is the ``pcc_sig`` shrink horizon (None → module
-        default).  With ``n_probe == n_clusters`` and ``rerank_frac == 0``
-        the result is bit-identical to the exact engines.
+        default).  ``gather_src``: the caller's gather code of
+        ``ratings`` (``CFEngine`` passes its own); a standalone call
+        without one derives it for this call only.  With
+        ``n_probe == n_clusters`` and ``rerank_frac == 0`` the result is
+        bit-identical to the exact engines.
 
         Pass 1 builds per-query shortlists through the resolved scan mode
         (``_scan_mode``); blocks whose candidate union already fits the
@@ -1937,13 +1902,13 @@ class ClusteredIndex(_SpillClusterCore):
                 n_probed, n_reranked, t_rerank = self._query_fused(
                     ratings, uids, out_s, out_i, k=k, measure=measure,
                     beta=beta, n_probe=n_probe, max_rerank=max_rerank,
-                    pool_all=pool_all, bq=bq)
+                    pool_all=pool_all, bq=bq, r_gather=gather_src)
             else:
                 n_probed, n_reranked, t_rerank = self._query_staged(
                     ratings, uids, out_s, out_i, k=k, measure=measure,
                     beta=beta, n_probe=n_probe, max_rerank=max_rerank,
                     scan=scan, pool_all=pool_all, bq=bq, p_np=p_np,
-                    sym_use=sym_use, mode=mode)
+                    sym_use=sym_use, mode=mode, r_gather=gather_src)
             qspan.set_attr("n_probed", n_probed)
             qspan.set_attr("n_reranked", n_reranked)
         finally:
@@ -1977,7 +1942,7 @@ class ClusteredIndex(_SpillClusterCore):
 
     def _query_staged(self, ratings, uids, out_s, out_i, *, k, measure,
                       beta, n_probe, max_rerank, scan, pool_all, bq,
-                      p_np, sym_use, mode):
+                      p_np, sym_use, mode, r_gather=None):
         """The two-pass host-orchestrated pipeline (shortlists round-trip
         through host memory between the scan and the exact rerank) —
         also the bit-exact oracle the fused chain is pinned against.
@@ -2098,17 +2063,19 @@ class ClusteredIndex(_SpillClusterCore):
                 if mode == "grouped":
                     self._rerank_grouped(ratings, norms, counts, q_all,
                                          shorts, pos, out_s, out_i, k=k,
-                                         measure=measure, beta=beta)
+                                         measure=measure, beta=beta,
+                                         r_gather=r_gather)
                 else:
                     self._rerank_gather(ratings, norms, counts, q_all,
                                         shorts, pos, out_s, out_i, k=k,
                                         measure=measure, beta=beta,
-                                        max_rerank=max_rerank)
+                                        max_rerank=max_rerank,
+                                        r_gather=r_gather)
             t_rerank += rsp.duration
         return n_probed, n_reranked, t_rerank
 
     def _query_fused(self, ratings, uids, out_s, out_i, *, k, measure,
-                     beta, n_probe, max_rerank, pool_all, bq):
+                     beta, n_probe, max_rerank, pool_all, bq, r_gather=None):
         """The fused query pipeline: per query block, proxy scan →
         canonical top-M shortlist → candidate-union gather → exact
         co-rated Gram rerank stream through device memory, with scores
@@ -2128,7 +2095,7 @@ class ClusteredIndex(_SpillClusterCore):
         use_pallas = self._use_kernel() or self.cfg.interpret
         interpret = self.cfg.interpret
         m = min(max_rerank, n)
-        r_gather = self._gather_source(ratings)
+        r_gather = _call_source(ratings, r_gather)
         norms, counts = _user_norms_counts(ratings)
         # a block's candidate union can reach every row: score the whole
         # matrix (the union ``bq·m`` ids can hold is at least ``n``)
@@ -2220,7 +2187,8 @@ class ClusteredIndex(_SpillClusterCore):
         return n_probed, n_reranked, t_rerank
 
     def _rerank_gather(self, ratings, norms, counts, q_all, shorts, pos,
-                       out_s, out_i, *, k, measure, beta, max_rerank):
+                       out_s, out_i, *, k, measure, beta, max_rerank,
+                       r_gather=None):
         """The CSR-batched gather walk (CPU fast path).
 
         Queries are ordered by rated-item support (their CSR row length)
@@ -2262,14 +2230,14 @@ class ClusteredIndex(_SpillClusterCore):
             data = q_rows[rr, cc].astype(np.float32)
             row_key = np.arange(len(q_all))
             heavy = np.empty(0, np.int64)
-        r_gather = self._gather_source(ratings)
+        r_gather = _call_source(ratings, r_gather)
         n_items = ratings.shape[1]
         bmax = max(_RERANK_BMAX, self.cfg.query_block)
 
         if len(heavy):
             self._rerank_pairs(ratings, norms, counts, q_all, shorts, pos,
                                out_s, out_i, heavy, nnz_user, k=k,
-                               measure=measure, beta=beta)
+                               measure=measure, beta=beta, r_gather=r_gather)
             light = np.nonzero(nnz <= _REHOME_NNZ)[0]
             order = light[np.argsort(nnz[light], kind="stable")]
         else:
@@ -2324,7 +2292,8 @@ class ClusteredIndex(_SpillClusterCore):
             pending = nxt
 
     def _rerank_pairs(self, ratings, norms, counts, q_all, shorts, pos,
-                      out_s, out_i, heavy, nnz_user, *, k, measure, beta):
+                      out_s, out_i, heavy, nnz_user, *, k, measure, beta,
+                      r_gather):
         """Pair-major min-side scoring for wide-support queries.
 
         Flattens the heavy queries' (query, candidate) pairs, picks the
@@ -2335,7 +2304,6 @@ class ClusteredIndex(_SpillClusterCore):
         shortlist slots, and selects the canonical top-k on the host.
         """
         bucket_of, local_of, tables = self._item_tables(ratings)
-        r_gather = self._gather_source(ratings)
         nh, m = len(heavy), shorts.shape[1]
         sh_h = shorts[heavy]
         q_h = q_all[heavy]
@@ -2416,7 +2384,7 @@ class ClusteredIndex(_SpillClusterCore):
         out_i[pos[heavy]] = top_i
 
     def _rerank_grouped(self, ratings, norms, counts, q_all, shorts, pos,
-                        out_s, out_i, *, k, measure, beta):
+                        out_s, out_i, *, k, measure, beta, r_gather=None):
         """The grouped union-Gram rerank (accelerator path).
 
         Queries are grouped by taste cluster, each group's candidate-union
@@ -2430,7 +2398,7 @@ class ClusteredIndex(_SpillClusterCore):
         rnp = None if use_kernel else np.asarray(ratings)
         norms_np = np.asarray(norms)
         counts_np = np.asarray(counts)
-        r_gather = self._gather_source(ratings)
+        r_gather = _call_source(ratings, r_gather)
         neg = np.float32(nb.NEG_INF)
         for glo in range(0, len(groups), self.cfg.rerank_batch):
             gs = groups[glo:glo + self.cfg.rerank_batch]
@@ -2509,7 +2477,7 @@ class ClusteredIndex(_SpillClusterCore):
             return self.last_refold
         with obs.span("index.refold", n_touched=int(touched.size)) as sp:
             patched = self._patch_row_caches(ratings, np.unique(touched),
-                                             version, means=means)
+                                             version)
             p_new_j = self._proxy_rows(ratings[jnp.asarray(touched)],
                                        means[jnp.asarray(touched)])
             changed, full_rows, reassigned = self._refold_rows(touched,

@@ -1,18 +1,16 @@
 """Fused support-scorer (segmented SpMM) Pallas TPU kernel.
 
-The item index's ``"support"`` shortlist scorer evaluates the *true*
-predictor num/den form for every item:
+The recommend stage's shortlist scorer evaluates the *true* predictor
+num/den form for every item:
 
     num[u, i] = Σ_k w[u,k] · dev[nb[u,k], i]
     den[u, i] = Σ_k w[u,k] · msk[nb[u,k], i]
     pred      = clip(r̄_u + num/den, lo, hi)   (r̄_u when den == 0)
 
 — a segmented SpMM between the k-sparse neighbor-weight matrix and the
-stacked deviation/mask table.  On CPU that pass runs row-major over a
-scipy CSR (PR 3); this kernel is its TPU twin, closing the recall gap the
-smooth proxy-GEMM shortlist cannot (measured: the exact top-n is
-dominated by items with a *median of one* supporting neighbor, which
-profile geometry cannot see).
+stacked deviation/mask table (the exact top-n is dominated by items with
+a *median of one* supporting neighbor, which no smooth taste geometry
+can see, so the shortlist scores the predictor itself).
 
 TPU formulation via *scalar prefetch* (the embedding-bag pattern): the
 (b, k) neighbor ids and weights and the (b,) query means are prefetched
@@ -32,8 +30,8 @@ takes at most ``BB`` query rows; larger batches loop over row blocks.
 
 Grid per row block: (b, I/bt, k) with the neighbor axis innermost (it
 carries the num/den accumulators).  Interpret mode runs on CPU and is
-validated against ``repro.kernels.ref.support_scores_ref``; the scipy CSR
-pass remains the production CPU path.
+validated against ``repro.kernels.ref.support_scores_ref``, which also
+evaluates the same tables wherever the kernel does not run.
 """
 
 from __future__ import annotations

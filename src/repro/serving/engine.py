@@ -11,9 +11,8 @@ facade owns the rating matrix and neighbor cache, so ``update_ratings``
 between batches is picked up by the very next batch because the model
 arrays are passed per call, not baked into the executable) or the legacy
 ``UserCF`` + ratings pair.  An engine built with
-``recommend_mode="approx"`` is served through its two-stage item-index
-path — candidate generation + exact rerank, the end-to-end sublinear
-configuration.
+``recommend_mode="approx"`` is served through its item stage — support
+scores over every item, a device shortlist, exact rerank.
 
 **Failure model.**  The batcher is a supervisor, not a bare loop: every
 batch runs isolated, so an exception resolves that batch's futures with
@@ -38,9 +37,9 @@ runs a health state machine HEALTHY → DEGRADED → SHEDDING fed by the
 *windowed* p99 / mean queue depth of its own histograms
 (``obs.delta_quantile`` over registry snapshots) and by
 ``StragglerWatchdog`` escalation on per-batch compute walls.  Pressure
-steps the approx engine down — ``query_mode`` fused→staged, smaller
-``n_probe``/``shortlist`` per request class (``bulk`` degrades one level
-before ``interactive``) — and calm windows step it back up.  Every
+steps the approx engine down — ``query_mode`` fused→staged, a smaller
+``shortlist`` per request class (``bulk`` degrades one level before
+``interactive``) — and calm windows step it back up.  Every
 transition is the ``serve.health`` gauge plus a
 ``serve.health.transition`` span carrying the reason, so the chrome
 trace shows exactly when and why quality was traded for latency.  In
@@ -125,10 +124,10 @@ class DegradationLadder:
     hysteretic: ``hold_windows`` consecutive windows under
     ``recover_p99_ms`` step down a single level.
 
-    Quality budgets are multiplicative per level: at level ``L`` the
-    approx engine runs ``n_probe ≈ base·n_probe_frac**L`` and
-    ``shortlist ≈ base·shortlist_frac**L`` (floored at 1 / top-n), and
-    ``bulk`` requests are served one level worse than ``interactive``.
+    The quality budget is multiplicative per level: at level ``L`` the
+    approx engine runs ``shortlist ≈ base·shortlist_frac**L`` (floored at
+    top-n), and ``bulk`` requests are served one level worse than
+    ``interactive``.
     The instance is owned by one server and mutated only on its batcher
     thread.
     """
@@ -138,22 +137,18 @@ class DegradationLadder:
     max_queue_depth: float = 64.0
     window: int = 8                 # batches per health evaluation
     hold_windows: int = 2           # calm windows per step *down*
-    n_probe_frac: float = 0.5
     shortlist_frac: float = 0.5
     staged_when_degraded: bool = True
     calm_windows: int = 0
 
-    def budget(self, level: int, base_n_probe: int, base_shortlist: int,
+    def budget(self, level: int, base_shortlist: int,
                n_min: int) -> Optional[dict]:
-        """Per-call candidate budgets for a request served at ``level``
+        """Per-call candidate budget for a request served at ``level``
         (None = config defaults, i.e. HEALTHY)."""
         if level <= HEALTHY:
             return None
-        return {
-            "n_probe": max(1, int(base_n_probe * self.n_probe_frac ** level)),
-            "shortlist": max(n_min, int(base_shortlist
-                                        * self.shortlist_frac ** level)),
-        }
+        return {"shortlist": max(n_min, int(base_shortlist
+                                            * self.shortlist_frac ** level))}
 
     def next_level(self, level: int, *, p99_ms: float, queue_depth: float,
                    straggler: bool) -> Tuple[int, str]:
@@ -212,10 +207,8 @@ class BatchingServer:
             # the engine's rating scale, kept with its gather operand
             self._scale = lambda r: cf_model._gather_source(r).scale
             if getattr(cf_model, "recommend_mode", "exact") == "approx":
-                # two-stage serving: candidate items from the item index,
-                # exact rerank — updates land between batches (the batcher
-                # is the only recommend caller, so it always sees a fully
-                # refolded index)
+                # the item stage: device shortlist, exact rerank — updates
+                # land between batches (each batch reads one snapshot)
                 self._approx_engine = cf_model
         else:
             # legacy UserCF + external ratings (static model)
@@ -257,13 +250,9 @@ class BatchingServer:
         self._window_n = 0
         self._prev_lat = None
         self._prev_depth = None
-        if self._approx_engine is not None:
-            ii = self._approx_engine.item_index
-            self._base_n_probe = int(ii.n_probe)
-            self._base_shortlist = int(ii.cfg.shortlist)
-        else:
-            self._base_n_probe = 0
-            self._base_shortlist = 0
+        self._base_shortlist = (
+            int(self._approx_engine.item_index.cfg.shortlist)
+            if self._approx_engine is not None else 0)
         # per-batch / per-request telemetry: histograms in a registry
         # (per-server by default so tests stay isolated; pass the process
         # registry to fold serving metrics into one dump).  The batcher
@@ -569,8 +558,8 @@ class BatchingServer:
         out = []
         for cls in sorted(groups):
             eff = level if cls == "interactive" else min(level + 1, SHEDDING)
-            out.append((self._ladder.budget(eff, self._base_n_probe,
-                                            self._base_shortlist, self.topn),
+            out.append((self._ladder.budget(eff, self._base_shortlist,
+                                            self.topn),
                         cls, groups[cls]))
         return out
 
@@ -616,8 +605,8 @@ class BatchingServer:
                       queue_depth=round(depth, 2)):
             # engine-side knob: force the cheaper staged user-index
             # pipeline while degraded, restore config resolution on
-            # recovery (per-call n_probe/shortlist budgets ride on each
-            # recommend call instead — see _plan)
+            # recovery (per-call shortlist budgets ride on each recommend
+            # call instead — see _plan)
             eng = self._approx_engine
             if eng is not None and getattr(eng, "index", None) is not None \
                     and self._ladder.staged_when_degraded:

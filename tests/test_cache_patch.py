@@ -1,8 +1,9 @@
 """Delta-aware cache maintenance: patched caches equal cold rebuilds.
 
 ``update_ratings`` used to invalidate every derived per-ratings cache
-(int8 gather operand, host CSR, bucketed pair tables, support-scorer
-operands) wholesale — even for a 1-rating delta.  These tests pin the
+(the engine's int8 gather code, the user index's host CSR and bucketed
+pair tables, the item stage's support tables) wholesale — even for a
+1-rating delta.  These tests pin the
 version-chain patching: after a stream of updates each cache must equal
 what a cold rebuild against the current ratings produces, and a broken
 chain (a ratings array the index never saw) must fall back to rebuilds.
@@ -16,6 +17,7 @@ from repro.core import similarity as sim
 from repro.core.facade import CFEngine
 from repro.index import (ClusteredIndex, IndexConfig, ItemClusteredIndex,
                          ItemIndexConfig)
+from repro.kernels.support import support_rows, support_width
 
 
 def _ratings(rng, u, d, density=0.4):
@@ -42,8 +44,9 @@ def _assert_csr_equal(got, want):
 
 
 def test_engine_caches_patched_across_updates(rng):
-    """Approx engine: CSR, pair tables, and gather operands survive a
-    stream of deltas by patching and stay bit-equal to cold rebuilds."""
+    """Approx engine: the index's CSR and pair tables and the engine's
+    gather code survive a stream of deltas by patching and stay
+    bit-equal to cold rebuilds."""
     r = _ratings(rng, 128, 64)
     eng = CFEngine(r, measure="cosine", k=6, neighbor_mode="approx",
                    index_cfg=IndexConfig(n_clusters=8, seed=0,
@@ -53,20 +56,19 @@ def test_engine_caches_patched_across_updates(rng):
     # warm every cache on the fitted ratings
     ix._ratings_csr(eng.ratings)
     ix._item_tables(eng.ratings)
-    ix._gather_source(eng.ratings)
     for _ in range(4):
         st = eng.update_ratings(*_delta(rng, 128, 64, 5))
         rf = ix.last_refold
-        assert rf.caches_patched >= 3, rf
+        assert rf.caches_patched >= 2, rf
         # patched caches are keyed to the *current* ratings array...
         assert ix._csr_cache[0] is eng.ratings
-        assert ix._gather_cache[0] is eng.ratings
+        assert eng._gather_cache[0] is eng.ratings
         # ...and bit-equal to cold rebuilds
         cold = ClusteredIndex(IndexConfig(n_clusters=8, seed=0,
                                           features="raw"))
         _assert_csr_equal(ix._csr_cache[1],
                           cold._ratings_csr(eng.ratings))
-        _assert_src_equal(ix._gather_cache[1],
+        _assert_src_equal(eng._gather_cache[1],
                           pred_mod.make_gather_source(eng.ratings))
         b_got, l_got, t_got = ix._csr_cache[2]
         b_want, l_want, t_want = cold._item_tables(eng.ratings)
@@ -113,36 +115,51 @@ def test_gather_patch_int8_fallout(rng):
 
 
 def test_item_index_support_caches_patched(rng):
-    """Item-index support-scorer operands (stacked CSR + dense kernel
-    tables) patch under updates and match cold rebuilds."""
+    """The item stage's (U, 1, W) support tables patch under updates by a
+    row scatter, equal a cold ``support_rows``, and answer as a stage
+    that builds its own tables and gather code."""
     r = _ratings(rng, 96, 48)
     eng = CFEngine(r, measure="pcc", k=6, recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(
-                       n_clusters=8, seed=0,
-                       refit_reassign_frac=0.0)).fit()
+                   item_index_cfg=ItemIndexConfig(shortlist=16)).fit()
     it = eng.item_index
-    it._support_table(eng.ratings, eng.means)
-    it._support_dense(eng.ratings, eng.means)
+    users = np.arange(16)
+    eng.recommend(users, n=5)               # builds the tables
     for _ in range(3):
         eng.update_ratings(*_delta(rng, 96, 48, 4))
-        assert it.last_refold.caches_patched >= 2, it.last_refold
-        cold = ItemClusteredIndex(ItemIndexConfig(n_clusters=8, seed=0))
-        cold.n_users, cold.n_rows = it.n_users, it.n_rows
-        want = cold._support_table(eng.ratings, eng.means)
-        got = it._support_cache[1]
-        if hasattr(want, "toarray"):
-            np.testing.assert_array_equal(got.toarray(), want.toarray())
-        else:
-            np.testing.assert_array_equal(got, want)
-        want_d = cold._support_dense(eng.ratings, eng.means)
-        got_d = it._support_dense_cache[1]
-        np.testing.assert_array_equal(np.asarray(got_d[0]),
-                                      np.asarray(want_d[0]))
-        np.testing.assert_array_equal(np.asarray(got_d[1]),
-                                      np.asarray(want_d[1]))
-        # behaviour check: recommendations from patched operands match a
-        # freshly-fitted engine's exactly (same model state)
-        s1, i1 = eng.recommend(np.arange(16), n=5, mode="approx")
+        key, (dev, msk) = it._tables
+        assert key is eng.ratings
+        want_d, want_m = support_rows(eng.ratings, eng.means,
+                                      support_width(48))
+        np.testing.assert_array_equal(np.asarray(dev), np.asarray(want_d))
+        np.testing.assert_array_equal(np.asarray(msk), np.asarray(want_m))
+        # behaviour check: recommendations from the patched tables and
+        # code match a stage that derives both cold
+        s1, i1 = eng.recommend(users, n=5, mode="approx")
+        s2, i2 = ItemClusteredIndex(it.cfg).recommend(
+            eng.ratings, eng.means, eng.scores, eng.idx, users, n=5)
+        np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+        np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
+
+
+def test_item_stage_tables_dropped_off_the_chain(rng):
+    """A patch from an array the tables were not built on drops them
+    (never patches a stranger's tables); the next recommend rebuilds
+    them cold against the current ratings."""
+    r = _ratings(rng, 64, 32)
+    eng = CFEngine(r, measure="cosine", k=5, recommend_mode="approx",
+                   item_index_cfg=ItemIndexConfig(shortlist=8)).fit()
+    it = eng.item_index
+    eng.recommend(np.arange(8), n=4)
+    foreign = jnp.asarray(np.asarray(r).copy())
+    ids = jnp.asarray([2, 64], jnp.int32)          # padded with U
+    assert not it.patch(foreign, eng.ratings, eng.means, ids)
+    assert it._tables is None
+    eng.recommend(np.arange(8), n=4)
+    key, tables = it._tables
+    assert key is eng.ratings
+    want = support_rows(eng.ratings, eng.means, support_width(32))
+    for got, w in zip(tables, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
 
 
 def test_broken_chain_drops_caches(rng):

@@ -42,7 +42,7 @@ def ratings(cs):
 CASES = {
     "auto": (None, ItemIndexConfig(shortlist=64), {
         "query_mode": "staged", "scan_mode": "pool", "select_mode": "host",
-        "rerank_mode": "grouped", "item_scorer": "support",
+        "rerank_mode": "grouped", "item_scorer": "ref",
         "index_interpret": False, "item_index_interpret": False}),
     "device_interpret": (
         IndexConfig(features="centered", use_kernel=True, interpret=True),

@@ -5,7 +5,7 @@ top-``m`` finite scores with ``lax.top_k`` at a static width and a
 traced ``m``; ``_select_rerank_items`` knocks out seen items and the
 kernel's pad columns, selects, and reranks in one program.  Both are
 pinned here against a plain numpy selection: a lexsort on (-score, id).
-End to end, the kernel and host scorers give the same answers, which
+End to end, the kernel and its reference give the same answers, which
 equal the exact path's wherever the shortlist holds every unseen item,
 and a smaller per-call budget compiles nothing new.
 """
@@ -21,11 +21,12 @@ from repro.core import neighbors as nb
 from repro.core import predict as pr
 from repro.core import similarity as sim
 from repro.index import ItemIndexConfig
-from repro.index.item_index import (_canonical_top_mask, _rerank_items,
-                                    _select_rerank_items, _shortlist_mask)
+from repro.index.item_index import (_canonical_top_mask, _rerank_masked,
+                                    _select_rerank_items)
 
 U, I, K, B, WIDTH, PAD, ITEM_BLOCK = 40, 60, 5, 8, 24, 36, 32
 _mask = jax.jit(_canonical_top_mask, static_argnames=("width",))
+_rerank = jax.jit(_rerank_masked, static_argnames=("n", "item_block"))
 
 
 def _np_top_mask(s, m):
@@ -114,41 +115,38 @@ def test_select_rerank_matches_host_selection(case, m, rng):
         item_block=ITEM_BLOCK)
     want_mask = _np_top_mask(np.where(r[q] > 0, -np.inf, s), m)
     assert want_mask[0].sum() == 5
-    short = np.full((B, I), I, np.int32)
-    for row in range(B):
-        ids = np.nonzero(want_mask[row])[0]
-        short[row, :len(ids)] = ids
-    want_s, want_i = _rerank_items(
+    want_s, want_i = _rerank(
         ratings, src, scores[jnp.asarray(q)], idx[jnp.asarray(q)], means,
-        jnp.asarray(qm), jnp.asarray(q_ids),
-        jnp.asarray(_shortlist_mask(short, I)), n=6, item_block=ITEM_BLOCK)
+        jnp.asarray(qm), jnp.asarray(want_mask), jnp.asarray(r[q] > 0),
+        n=6, item_block=ITEM_BLOCK)
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
     assert int(n_cand) == int(want_mask[:-1].sum())
 
 
-def _engine(r, shortlist, mode):
+def _engine(r, shortlist, scorer):
     return CFEngine(r, measure="cosine", k=6, recommend_mode="approx",
                     item_index_cfg=ItemIndexConfig(
-                        n_clusters=6, seed=0, shortlist=shortlist,
-                        shortlist_mode=mode, interpret=True)).fit()
+                        shortlist=shortlist, use_kernel=scorer == "kernel",
+                        interpret=True)).fit()
 
 
 def test_scorers_agree_and_full_shortlist_is_exact(rng):
-    """The kernel and host scorers give identical answers, and with a
-    shortlist that holds every unseen item both equal the exact path."""
+    """The kernel (interpret mode) and its reference give identical
+    answers on the same tables, and with a shortlist that holds every
+    unseen item both equal the exact path."""
     r = rng.integers(1, 6, (64, I)) * (rng.random((64, I)) < 0.4)
     r[:, :12] = rng.integers(1, 6, (64, 12))   # ≥ 12 rated per user
     r = jnp.asarray(r.astype(np.float32))
     shortlist = I - 12                          # every unseen item
     outs = {}
-    for mode in ("kernel", "support"):
-        eng = _engine(r, shortlist, mode)
+    for scorer in ("kernel", "ref"):
+        eng = _engine(r, shortlist, scorer)
         s, i = eng.recommend(n=8)
-        assert eng.item_index.last_recommend.scorer == mode
-        outs[mode] = (np.asarray(s), np.asarray(i))
-    np.testing.assert_array_equal(outs["kernel"][1], outs["support"][1])
-    np.testing.assert_array_equal(outs["kernel"][0], outs["support"][0])
+        assert eng.item_index.last_recommend.scorer == scorer
+        outs[scorer] = (np.asarray(s), np.asarray(i))
+    np.testing.assert_array_equal(outs["kernel"][1], outs["ref"][1])
+    np.testing.assert_array_equal(outs["kernel"][0], outs["ref"][0])
     ex_s, ex_i = eng.recommend(n=8, mode="exact")
     np.testing.assert_array_equal(outs["kernel"][1], np.asarray(ex_i))
     np.testing.assert_array_equal(outs["kernel"][0], np.asarray(ex_s))

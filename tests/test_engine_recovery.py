@@ -131,7 +131,7 @@ def test_per_call_quality_knobs(rng):
     eng = _approx_engine(rng)
     users = np.arange(8, dtype=np.int32)
     s_full, i_full = eng.recommend(users, n=5)
-    s_cheap, i_cheap = eng.recommend(users, n=5, n_probe=1, shortlist=8)
+    s_cheap, i_cheap = eng.recommend(users, n=5, shortlist=8)
     assert np.asarray(i_cheap).shape == np.asarray(i_full).shape
     # exact mode can't honor candidate budgets — loud, not silent
     exact = _engine(rng)

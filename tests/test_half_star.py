@@ -63,8 +63,7 @@ def _engine(r, measure, query_mode):
            if query_mode == "fused" else {}))
     return CFEngine(r, measure=measure, k=K, neighbor_mode="approx",
                     recommend_mode="approx", index_cfg=index_cfg,
-                    item_index_cfg=ItemIndexConfig(n_clusters=8, seed=0,
-                                                   shortlist=48)).fit()
+                    item_index_cfg=ItemIndexConfig(shortlist=48)).fit()
 
 
 def _f32_source(ratings):
@@ -88,12 +87,12 @@ def test_int8_code_bit_identical_to_f32(measure, query_mode, half_stars,
     step-0.5 int8 code equal those from the f32 matrix, bit for bit."""
     users = np.arange(0, U, 7, dtype=np.int32)
     code = _engine(half_stars, measure, query_mode)
-    assert code.index._gather_source(half_stars).step == 0.5
+    assert code._gather_source(half_stars).step == 0.5
     got = (code.scores, code.idx, code.predict(users),
            *code.recommend(users, n=10))
     monkeypatch.setattr(pr, "make_gather_source", _f32_source)
     f32 = _engine(half_stars, measure, query_mode)
-    assert f32.index._gather_source(half_stars).step == 0.0
+    assert f32._gather_source(half_stars).step == 0.0
     want = (f32.scores, f32.idx, f32.predict(users),
             *f32.recommend(users, n=10))
     for g, w in zip(got, want):
@@ -162,25 +161,24 @@ def test_whole_matrix_rerank_bit_identical_to_union(measure, use_pallas,
 
 def test_update_outside_the_scale_widens_it(half_stars):
     """The scale comes from the ratings held: an update below or above it
-    widens it, in every owner of a gather source; deleting the extreme
-    rating never narrows it back."""
+    widens it in the engine's gather code, the one every recommend path
+    reads; deleting the extreme rating never narrows it back."""
     r = np.asarray(half_stars).copy()
     r = np.clip(r, 0.0, 4.5)                 # scale [0.5, 4.5]
     eng = CFEngine(jnp.asarray(r), measure="pcc", k=K,
                    neighbor_mode="approx", recommend_mode="approx",
-                   index_cfg=IndexConfig(n_clusters=8, seed=0),
-                   item_index_cfg=ItemIndexConfig(n_clusters=8,
-                                                  seed=0)).fit()
-    eng.recommend(np.arange(4), n=5)         # the item index's source
-    owners = (eng, eng.index, eng.item_index)
+                   index_cfg=IndexConfig(n_clusters=8, seed=0)).fit()
+    eng.recommend(np.arange(4), n=5)
 
-    def scales():
-        return {o._gather_source(eng.ratings).scale for o in owners}
+    def scale():
+        return eng._gather_source(eng.ratings).scale
 
-    assert scales() == {(0.5, 4.5)}
+    assert scale() == (0.5, 4.5)
     eng.update_ratings([5], [7], [5.0])
-    assert scales() == {(0.5, 5.0)}
+    assert scale() == (0.5, 5.0)
     assert eng._gather_source(eng.ratings).step == 0.5
     eng.update_ratings([5], [7], [0.0])      # the only 5.0 deleted
-    assert scales() == {(0.5, 5.0)}
+    assert scale() == (0.5, 5.0)
+    s, _ = eng.recommend(np.arange(U), n=5)
+    assert float(jnp.max(s)) <= 5.0
     assert float(jnp.max(eng.predict(np.arange(U)))) <= 5.0

@@ -1,4 +1,4 @@
-"""The two-stage recommend path's exact rerank (``_rerank_items``).
+"""The recommend stage's exact rerank (``_rerank_masked``).
 
 The rerank predicts the batch's rows over every item with the blocked
 form and masks them to each row's shortlist.  Its answers are pinned
@@ -16,9 +16,18 @@ import pytest
 from repro.core import neighbors as nb
 from repro.core import predict as pr
 from repro.core import similarity as sim
-from repro.index.item_index import _rerank_items, _shortlist_mask
+from repro.index.item_index import _rerank_masked, _select_rerank_items
 
 U, I, K, M, N, ITEM_BLOCK = 48, 70, 6, 8, 10, 32
+_rerank = jax.jit(_rerank_masked, static_argnames=("n", "item_block"))
+
+
+def _mask(short):
+    """(M, L) item lists, padded with the ``I`` sentinel → (M, I)
+    candidate mask."""
+    mask = np.zeros((len(short), I + 1), bool)
+    mask[np.arange(len(short))[:, None], short] = True
+    return mask[:, :I]
 
 
 def _fma(a, b, acc):
@@ -102,7 +111,7 @@ def _case(name, rng):
         means = jnp.ones((U,), jnp.float32)
         q_means = jnp.full((M,), 5.0, jnp.float32)
         short = _lists(rng, 32, 30)
-    elif name == "broadcast":              # the proxy path's full list
+    elif name == "broadcast":              # every item, sentinel-padded
         short = np.broadcast_to(
             np.concatenate([np.arange(I), np.full(128 - I, I)])
             .astype(np.int32)[None, :], (M, 128))
@@ -133,9 +142,10 @@ def test_rerank_matches_point_gather_reference(case, src_dtype, rng):
     src = (pr.make_gather_source(ratings) if src_dtype == "int8" else
            pr.GatherSource(ratings, 0.0, pr.rating_scale(ratings)))
     assert src.code.dtype == jnp.dtype(src_dtype)
-    got_s, got_i = _rerank_items(
-        ratings, src, nbs, nbi, means, q_means, jnp.asarray(q_ids),
-        jnp.asarray(_shortlist_mask(short, I)), n=n, item_block=ITEM_BLOCK)
+    seen = np.asarray(ratings)[np.clip(q_ids, 0, U - 1)] > 0
+    got_s, got_i = _rerank(
+        ratings, src, nbs, nbi, means, q_means, jnp.asarray(_mask(short)),
+        jnp.asarray(seen), n=n, item_block=ITEM_BLOCK)
     ref_s, ref_i = _point_gather_rerank(src, ratings, nbs, nbi, means,
                                         q_means, q_ids, short, n)
     np.testing.assert_array_equal(np.asarray(got_i), ref_i)
@@ -144,14 +154,6 @@ def test_rerank_matches_point_gather_reference(case, src_dtype, rng):
         assert (ref_s[ref_i >= 0] == 5.0).all()
     if case in ("few", "n_over_items"):
         assert (ref_i == -1).any()
-
-
-def test_shortlist_mask_drops_the_sentinel():
-    short = np.array([[0, 3, 5, 5], [2, 5, 5, 5]], np.int32)
-    mask = _shortlist_mask(short, 5)
-    assert mask.shape == (2, 5)
-    np.testing.assert_array_equal(
-        mask, [[1, 0, 0, 1, 0], [0, 0, 1, 0, 0]])
 
 
 def _gathers(jaxpr):
@@ -166,16 +168,18 @@ def _gathers(jaxpr):
 @pytest.mark.parametrize("src_dtype", ["float32", "int8"])
 def test_rerank_has_no_element_gather_on_the_rating_matrix(src_dtype):
     """No gather whose slice is a single element of a (users, items)
-    operand: the rerank reads whole neighbor rows of an item tile."""
+    operand: the served select-and-rerank program reads whole neighbor
+    rows of an item tile."""
     ratings = jnp.zeros((U, I), jnp.float32)
     closed = jax.make_jaxpr(
-        lambda r, s, nbs, nbi, mu, qm, q, c: _rerank_items(
-            r, s, nbs, nbi, mu, qm, q, c, n=N, item_block=ITEM_BLOCK))(
+        lambda r, s, nbs, nbi, mu, q, sc, m: _select_rerank_items(
+            r, s, nbs, nbi, mu, q, sc, m, n=N, shortlist=24,
+            item_block=ITEM_BLOCK))(
         ratings, pr.GatherSource(ratings.astype(jnp.dtype(src_dtype)),
                                  float(src_dtype == "int8"), (1.0, 5.0)),
-        jnp.zeros((M, K), jnp.float32), jnp.zeros((M, K), jnp.int32),
-        jnp.zeros((U,), jnp.float32), jnp.zeros((M,), jnp.float32),
-        jnp.zeros((M,), jnp.int32), jnp.zeros((M, I), bool))
+        jnp.zeros((U, K), jnp.float32), jnp.zeros((U, K), jnp.int32),
+        jnp.zeros((U,), jnp.float32), jnp.zeros((M,), jnp.int32),
+        jnp.zeros((M, I), jnp.float32), jnp.int32(24))
     gathers = list(_gathers(closed.jaxpr))
     assert gathers, "the rerank gathers neighbor rows"
     for eqn in gathers:

@@ -1,7 +1,7 @@
 """Two-stage recommend path: blocked-predict bit-identity, the fused
 tile-predict kernel oracle, the recommendation contract (never return a
-rated item), degenerate exactness, item-index recall, checkpointing, and
-the auto-refit drift guard."""
+rated item), degenerate exactness, item-stage recall, one gather code
+under updates, checkpointing, and the auto-refit drift guard."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -12,10 +12,10 @@ from repro.core import neighbors as nb
 from repro.core import predict as pr
 from repro.core import similarity as sim
 from repro.distributed import checkpoint as ckpt
-from repro.index import (ClusteredIndex, IndexConfig, ItemClusteredIndex,
-                         ItemIndexConfig)
+from repro.index import ClusteredIndex, IndexConfig, ItemIndexConfig
 from repro.kernels.predict import fused_tile_predict
 from repro.kernels.ref import tile_predict_ref
+from repro.kernels.support import support_rows, support_width
 
 
 def _ratings(rng, u, d, density=0.4):
@@ -91,10 +91,7 @@ def _assert_unseen(items, ratings):
 @pytest.mark.parametrize("mode_kwargs", [
     dict(),                                               # exact
     dict(recommend_mode="approx",                         # support scorer
-         item_index_cfg=ItemIndexConfig(n_clusters=8, shortlist=16)),
-    dict(recommend_mode="approx",                         # proxy scorer
-         item_index_cfg=ItemIndexConfig(n_clusters=8, shortlist=16,
-                                        shortlist_mode="proxy")),
+         item_index_cfg=ItemIndexConfig(shortlist=16)),
 ])
 def test_recommend_never_returns_rated(mode_kwargs, rng):
     """No path may recommend an already-rated item — including right
@@ -118,18 +115,23 @@ def test_recommend_never_returns_rated(mode_kwargs, rng):
         assert i not in np.asarray(items)[u]
 
 
-def test_degenerate_approx_recommend_bit_identical(rng):
-    """Full probing + uncapped shortlist must reproduce the exact blocked
-    recommend path bit for bit (scores and canonically tie-broken ids)."""
+@pytest.mark.parametrize("shortlist", [0, 64, 71], ids=["0", "I", "I+7"])
+def test_degenerate_approx_recommend_bit_identical(shortlist, rng):
+    """An uncapped shortlist (0), or one of at least the catalog's 64
+    items, selects every unseen item: the approx path must reproduce the
+    exact blocked recommend path bit for bit (scores and canonically
+    tie-broken ids)."""
     r = _ratings(rng, 96, 64)
     ex = CFEngine(r, measure="cosine", k=6, block_size=32).fit()
     s_ex, i_ex = ex.recommend(n=8)
-    cfg = ItemIndexConfig(n_clusters=8, n_probe=8, shortlist=0)
+    cfg = ItemIndexConfig(shortlist=shortlist)
     ap = CFEngine(r, measure="cosine", k=6, block_size=32,
                   recommend_mode="approx", item_index_cfg=cfg).fit()
     s_ap, i_ap = ap.recommend(n=8)
     np.testing.assert_array_equal(np.asarray(s_ex), np.asarray(s_ap))
     np.testing.assert_array_equal(np.asarray(i_ex), np.asarray(i_ap))
+    seen = int((np.asarray(r) > 0).sum())
+    assert int(ap.item_index.last_recommend.n_reranked) == 96 * 64 - seen
     assert ap.recommend_recall_vs_exact(sample=48, n=8) == 1.0
 
 
@@ -138,8 +140,7 @@ def test_recommend_empty_user_list(rng):
     r = _ratings(rng, 32, 24)
     eng = CFEngine(r, measure="cosine", k=4, block_size=8,
                    recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(n_clusters=4,
-                                                  shortlist=8)).fit()
+                   item_index_cfg=ItemIndexConfig(shortlist=8)).fit()
     for mode in ("exact", "approx"):
         s, i = eng.recommend(user_ids=[], n=5, mode=mode)
         assert s.shape == (0, 5) and i.shape == (0, 5), mode
@@ -149,8 +150,6 @@ def test_recommend_mode_validation(rng):
     r = _ratings(rng, 16, 12)
     with pytest.raises(ValueError):
         CFEngine(r, recommend_mode="sparse")
-    with pytest.raises(ValueError):
-        ItemClusteredIndex(ItemIndexConfig(shortlist_mode="magic"))
     eng = CFEngine(r, k=3, block_size=8).fit()
     with pytest.raises(RuntimeError):
         eng.recommend(n=4, mode="approx")   # no item index fitted
@@ -167,8 +166,7 @@ def test_item_index_recall_floor_small():
     r = jnp.asarray(train)
     eng = CFEngine(r, measure="cosine", k=20, block_size=128,
                    recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(seed=0, shortlist=48)
-                   ).fit()
+                   item_index_cfg=ItemIndexConfig(shortlist=48)).fit()
     rec = eng.recommend_recall_vs_exact(sample=256, n=10)
     frac = eng.item_index.last_recommend.rerank_fraction
     assert rec >= 0.95, (rec, frac)
@@ -178,15 +176,15 @@ def test_item_index_recall_floor_small():
 # -- maintenance under updates ------------------------------------------------
 
 def test_item_index_update_stream_consistent(rng):
-    """A stream of updates must keep every item-index invariant (proxies,
-    spill lists, mass ledger, profiles, support table) cold-equal."""
+    """A stream of updates must keep the item stage's patched (U, 1, W)
+    support tables equal to a cold ``support_rows`` of the current
+    ratings and means (the means of pcc move with every touched row)."""
     r = _ratings(rng, 80, 48)
-    for feats in ("raw", "centered"):
-        eng = CFEngine(r, measure="cosine", k=5, block_size=16,
+    for measure in ("cosine", "pcc"):
+        eng = CFEngine(r, measure=measure, k=5, block_size=16,
                        recommend_mode="approx",
-                       item_index_cfg=ItemIndexConfig(
-                           n_clusters=6, features=feats, shortlist=16)
-                       ).fit()
+                       item_index_cfg=ItemIndexConfig(shortlist=16)).fit()
+        eng.recommend(np.arange(4), n=5)        # builds the tables
         for _ in range(3):
             m = int(rng.integers(1, 8))
             st = eng.update_ratings(
@@ -195,7 +193,54 @@ def test_item_index_update_stream_consistent(rng):
                 rng.integers(0, 6, m).astype(np.float32),
                 oracle_check=True)
             assert st.oracle_ok
-        assert eng.item_index.check_consistent(eng.ratings, eng.means)
+            key, tables = eng.item_index._tables
+            assert key is eng.ratings              # patched, not dropped
+            cold = support_rows(eng.ratings, eng.means, support_width(48))
+            for got, want in zip(tables, cold):
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
+
+
+def _stars(rng, u, d, step):
+    """Ratings on a ``step`` grid up to 5.0 (whole or half stars)."""
+    top = int(5 / step)
+    return jnp.asarray((rng.integers(1, top + 1, (u, d)) * step
+                        * (rng.random((u, d)) < 0.4)).astype(np.float32))
+
+
+@pytest.mark.parametrize("step", [1.0, 0.5], ids=["whole", "half"])
+def test_update_stream_keeps_one_gather_code(step, rng):
+    """After a stream of updates the approx engine holds one gather code,
+    the engine's, patched along the version chain (neither index keeps
+    one), and answers as an engine freshly fitted on the same ratings."""
+    r = _stars(rng, 80, 48, step)
+    kw = dict(measure="pcc", k=6, block_size=16, neighbor_mode="approx",
+              recommend_mode="approx",
+              index_cfg=IndexConfig(n_clusters=6, n_probe=6, seed=0,
+                                    rerank_frac=0.0),
+              item_index_cfg=ItemIndexConfig(shortlist=16))
+    eng = CFEngine(r, **kw).fit()
+    users = np.arange(0, 80, 3, dtype=np.int32)
+    eng.recommend(users, n=5)
+    for _ in range(4):
+        m = int(rng.integers(1, 6))
+        top = int(5 / step)
+        eng.update_ratings(rng.choice(80, m, replace=False).astype(np.int32),
+                           rng.integers(0, 48, m).astype(np.int32),
+                           (rng.integers(0, top + 1, m) * step
+                            ).astype(np.float32))
+        key, src = eng._gather_cache           # patched by the update
+        assert key is eng.ratings
+        assert src.code.dtype == jnp.int8 and src.step == step
+        got = eng.recommend(users, n=5)
+    for owner in (eng.index, eng.item_index):
+        assert not any("gather" in name for name in vars(owner)), owner
+    cold = pr.make_gather_source(eng.ratings)
+    np.testing.assert_array_equal(np.asarray(src.code),
+                                  np.asarray(cold.code))
+    want = CFEngine(eng.ratings, **kw).fit().recommend(users, n=5)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_refold_auto_refit_trigger(rng):
@@ -253,22 +298,40 @@ def test_user_index_checkpoint_roundtrip(rng, tmp_path):
     assert ix2.check_consistent(r, means)
 
 
-def test_item_index_checkpoint_roundtrip(rng, tmp_path):
+def test_legacy_engine_checkpoint_loads_and_serves(rng, tmp_path):
+    """An engine checkpoint written when the item index kept cluster
+    state (an ``item_index`` entry: basis, centroids, profiles, spill
+    lists, ...) restores with its own structure into a new engine, which
+    ignores that entry and serves the answers the saved engine served."""
     r = _ratings(rng, 64, 40)
-    eng = CFEngine(r, measure="cosine", k=5, block_size=16,
-                   recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(n_clusters=6,
-                                                  shortlist=12)).fit()
-    ckpt.save(tmp_path, 0, eng.item_index.state())
-    it2 = ItemClusteredIndex(ItemIndexConfig(n_clusters=6, shortlist=12))
-    it2.load_state(ckpt.restore(tmp_path, 0,
-                                like=ItemClusteredIndex.state_template()))
-    assert it2.check_consistent(eng.ratings, eng.means)
-    sa, ia = eng.item_index.recommend(eng.ratings, eng.means, eng.scores,
-                                      eng.idx, n=6)
-    sb, ib = it2.recommend(eng.ratings, eng.means, eng.scores, eng.idx, n=6)
-    np.testing.assert_array_equal(np.asarray(sa), np.asarray(sb))
-    np.testing.assert_array_equal(np.asarray(ia), np.asarray(ib))
+    kw = dict(measure="cosine", k=5, block_size=16, neighbor_mode="approx",
+              recommend_mode="approx",
+              index_cfg=IndexConfig(n_clusters=6, seed=0, features="raw"),
+              item_index_cfg=ItemIndexConfig(shortlist=12))
+    eng = CFEngine(r, **kw).fit()
+    eng.update_ratings([3, 9], [5, 7], [4.0, 2.0])
+    want = eng.recommend(n=6)
+    tree = eng.state()
+    c, p = 7, 16                          # ⌈√40⌉ clusters, proxy width
+    tree["item_index"] = {
+        "basis": np.zeros((64, p), np.float32),
+        "centroids": np.zeros((c, p), np.float32),
+        "counts": np.zeros((c,), np.int64),
+        "has_pos": np.ones((64,), bool),
+        "item_meta": np.asarray([64, 3], np.int64),
+        "meta": np.asarray([40, c, 4, 0], np.int64),
+        "profiles": np.zeros((64, p), np.float32),
+        "proxies": np.zeros((40, p), np.float32),
+        "spill_dist": np.zeros((40, 2), np.float32),
+        "spill_ids": np.zeros((40, 2), np.int32),
+        "sums": np.zeros((c, p), np.float32)}
+    ckpt.save(tmp_path, 0, tree)
+    restored = CFEngine(r, **kw)
+    restored.load_state(ckpt.restore(tmp_path, 0, like=tree))
+    assert restored.ratings_version == 1
+    got = restored.recommend(n=6)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # -- serving ------------------------------------------------------------------
@@ -280,8 +343,7 @@ def test_batching_server_approx_recommend(rng):
     r = _ratings(rng, 48, 32)
     eng = CFEngine(r, measure="cosine", k=4, block_size=16,
                    recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(n_clusters=6,
-                                                  shortlist=8)).fit()
+                   item_index_cfg=ItemIndexConfig(shortlist=8)).fit()
     server = BatchingServer(eng, max_batch=4, max_wait_ms=5.0, topn=5)
     server.start()
     try:

@@ -234,15 +234,12 @@ def test_ladder_state_machine_steps_and_hysteresis():
 
 
 def test_ladder_budget_scales_per_level():
-    lad = DegradationLadder(n_probe_frac=0.5, shortlist_frac=0.5)
-    assert lad.budget(HEALTHY, 8, 64, 10) is None
-    assert lad.budget(DEGRADED, 8, 64, 10) == {"n_probe": 4,
-                                               "shortlist": 32}
-    assert lad.budget(SHEDDING, 8, 64, 10) == {"n_probe": 2,
-                                               "shortlist": 16}
-    # floors: n_probe ≥ 1, shortlist ≥ top-n
-    assert lad.budget(SHEDDING, 1, 16, 10) == {"n_probe": 1,
-                                               "shortlist": 10}
+    lad = DegradationLadder(shortlist_frac=0.5)
+    assert lad.budget(HEALTHY, 64, 10) is None
+    assert lad.budget(DEGRADED, 64, 10) == {"shortlist": 32}
+    assert lad.budget(SHEDDING, 64, 10) == {"shortlist": 16}
+    # floor: shortlist ≥ top-n
+    assert lad.budget(SHEDDING, 16, 10) == {"shortlist": 10}
 
 
 def test_ladder_degrades_live_server_and_recovers(rng):

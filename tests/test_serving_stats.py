@@ -153,8 +153,7 @@ def test_served_rerank_span_counts_rows_and_gather_bytes(values, itemsize,
     r = rng.choice(np.asarray((0.0,) + values, np.float32), (64, 32))
     eng = CFEngine(jnp.asarray(r), measure="cosine", k=5, block_size=16,
                    recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(n_clusters=4, seed=0,
-                                                  shortlist=8)).fit()
+                   item_index_cfg=ItemIndexConfig(shortlist=8)).fit()
     server = BatchingServer(eng, max_batch=4, max_wait_ms=5.0, topn=3)
     obs.clear()
     server.start()

@@ -1,6 +1,6 @@
-"""Fused support-scorer kernel (shortlist SpMM), the item index's kernel
-shortlist mode, the periodic profile re-fold, and the engine-level
-``pcc_sig`` shrink-horizon (β) plumbing."""
+"""Fused support-scorer kernel (shortlist SpMM), the item stage's two
+evaluators of its tables, and the engine-level ``pcc_sig`` shrink-horizon
+(β) plumbing."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -10,10 +10,8 @@ from repro.core import CFEngine
 from repro.core import neighbors as nb
 from repro.core import similarity as sim
 from repro.core.predict import rating_scale
-from repro.index import (ClusteredIndex, IndexConfig, ItemClusteredIndex,
-                         ItemIndexConfig)
-from repro.index.item_index import (_affinity_weights,
-                                    _canonical_top_mask, _fold_profiles)
+from repro.index import ClusteredIndex, IndexConfig, ItemIndexConfig
+from repro.index.item_index import _canonical_top_mask
 from repro.kernels import ref
 from repro.kernels.support import (fused_support_scores, support_rows,
                                    support_width)
@@ -69,23 +67,54 @@ def test_support_kernel_all_masked_neighbors(rng):
                                atol=1e-6)
 
 
-# -- item index: kernel shortlist mode ---------------------------------------
+@pytest.mark.parametrize("b", [5, 300])
+def test_reference_evaluator_on_kernel_tables(b, rng):
+    """The item stage's off-TPU scorer evaluates the kernel's own
+    ``(U, 1, W)`` tables through ``support_scores_ref``, ``BB`` query rows
+    at a time: the same scores as the oracle on the unpadded
+    deviation/mask, across a block boundary too (300 > BB)."""
+    from repro.index.item_index import _support_scores_ref
+    u, i, k = 40, 70, 6
+    r = np.asarray(_ratings(rng, u, i))
+    means = rng.uniform(2, 4, u).astype(np.float32)
+    dev = np.where(r > 0, r - means[:, None], 0.0).astype(np.float32)
+    msk = (r > 0).astype(np.float32)
+    idx = rng.integers(0, u, (b, k)).astype(np.int32)
+    w = (rng.random((b, k)) * (rng.random((b, k)) < 0.8)).astype(np.float32)
+    qm = rng.uniform(2, 4, b).astype(np.float32)
+    scale = rating_scale(r)
+    want = ref.support_scores_ref(jnp.asarray(dev), jnp.asarray(msk),
+                                  jnp.asarray(idx), jnp.asarray(w),
+                                  jnp.asarray(qm), scale=scale)
+    dev_t, msk_t = support_rows(jnp.asarray(r), jnp.asarray(means),
+                                support_width(i, 32))
+    got = _support_scores_ref(dev_t, msk_t, jnp.asarray(idx),
+                              jnp.asarray(w), jnp.asarray(qm), scale=scale)
+    assert got.shape == (b, support_width(i, 32))
+    np.testing.assert_allclose(np.asarray(got)[:, :i], np.asarray(want),
+                               atol=1e-6)
+
+
+# -- item stage: the kernel and its reference on the same tables ------------
 
 def test_kernel_shortlist_mode_matches_support(rng):
-    """The Pallas segmented-SpMM scorer evaluates the same exact num/den
-    form as the scipy CSR pass, so the two-stage recommendations are
+    """The Pallas segmented-SpMM scorer (interpret mode) and its
+    reference ``support_scores_ref`` evaluate the same (U, 1, W) tables
+    in the same exact num/den form, so the recommendations are
     identical."""
     r = _ratings(rng, 180, 140)
     outs = {}
-    for mode in ("support", "kernel"):
+    for use_kernel in (False, True):
         eng = CFEngine(r, measure="cosine", k=8, recommend_mode="approx",
                        item_index_cfg=ItemIndexConfig(
-                           n_clusters=8, seed=0, shortlist=32,
-                           shortlist_mode=mode, interpret=True)).fit()
+                           shortlist=32, use_kernel=use_kernel,
+                           interpret=True)).fit()
         s, i = eng.recommend(n=5)
-        outs[mode] = (np.asarray(s), np.asarray(i))
-    np.testing.assert_array_equal(outs["support"][0], outs["kernel"][0])
-    np.testing.assert_array_equal(outs["support"][1], outs["kernel"][1])
+        assert eng.item_index.last_recommend.scorer == \
+            ("kernel" if use_kernel else "ref")
+        outs[use_kernel] = (np.asarray(s), np.asarray(i))
+    np.testing.assert_array_equal(outs[False][0], outs[True][0])
+    np.testing.assert_array_equal(outs[False][1], outs[True][1])
 
 
 def test_kernel_path_stage_spans_and_repair_counts(rng):
@@ -98,8 +127,8 @@ def test_kernel_path_stage_spans_and_repair_counts(rng):
     r = _ratings(rng, 180, 140)
     eng = CFEngine(r, measure="cosine", k=8, recommend_mode="approx",
                    item_index_cfg=ItemIndexConfig(
-                       n_clusters=8, seed=0, shortlist=32, rerank_block=64,
-                       shortlist_mode="kernel", interpret=True)).fit()
+                       shortlist=32, rerank_block=64, use_kernel=True,
+                       interpret=True)).fit()
     counter = obs.registry().counter("item_index.device_select_rows")
     before = counter.value
     obs.clear()
@@ -123,54 +152,6 @@ def test_kernel_path_stage_spans_and_repair_counts(rng):
     assert mask.sum(axis=1).tolist() == [32] * 5
     np.testing.assert_array_equal(np.nonzero(mask)[1].reshape(5, 32),
                                   np.tile(np.arange(32), (5, 1)))
-
-
-def test_shortlist_mode_validation():
-    with pytest.raises(ValueError):
-        ItemClusteredIndex(ItemIndexConfig(shortlist_mode="psychic"))
-
-
-# -- periodic profile re-fold -------------------------------------------------
-
-def test_profile_refold_zeroes_drift(rng):
-    """ROADMAP "profile drift": with the re-fold threshold armed, a long
-    update stream keeps the user taste profiles *exactly* equal to a cold
-    fold — the Σ w·Δproxy float error is periodically zeroed."""
-    r = _ratings(rng, 150, 120)
-    eng = CFEngine(r, measure="cosine", k=6, recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(
-                       n_clusters=8, seed=0, shortlist=32,
-                       profile_refold_frac=0.01,
-                       refit_reassign_frac=0.0)).fit()
-    saw = 0
-    for _ in range(8):
-        us = rng.choice(150, 4, replace=False).astype(np.int32)
-        eng.update_ratings(us, rng.integers(0, 120, 4).astype(np.int32),
-                           rng.integers(1, 6, 4).astype(np.float32),
-                           oracle_check=True)
-        saw += int(eng.item_index.last_refold.profile_refold)
-    assert saw >= 6          # the tiny threshold re-folds ~every update
-    w, _ = _affinity_weights(eng.ratings, eng.means)
-    cold = np.asarray(_fold_profiles(w, eng.item_index.proxies))
-    np.testing.assert_array_equal(cold,
-                                  np.asarray(eng.item_index.profiles))
-
-
-def test_profile_refold_disabled_keeps_tolerance_contract(rng):
-    """With the re-fold disabled the correction-only path still passes
-    the (tolerance-based) consistency check — the pre-existing
-    contract."""
-    r = _ratings(rng, 100, 80)
-    eng = CFEngine(r, measure="cosine", k=5, recommend_mode="approx",
-                   item_index_cfg=ItemIndexConfig(
-                       n_clusters=6, seed=0, shortlist=16,
-                       profile_refold_frac=0.0)).fit()
-    for _ in range(4):
-        us = rng.choice(100, 3, replace=False).astype(np.int32)
-        eng.update_ratings(us, rng.integers(0, 80, 3).astype(np.int32),
-                           rng.integers(1, 6, 3).astype(np.float32))
-        assert not eng.item_index.last_refold.profile_refold
-    assert eng.item_index.check_consistent(eng.ratings, eng.means)
 
 
 # -- pcc_sig shrink horizon (β) ----------------------------------------------
