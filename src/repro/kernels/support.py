@@ -12,31 +12,38 @@ stacked deviation/mask table (the exact top-n is dominated by items with
 a *median of one* supporting neighbor, which no smooth taste geometry
 can see, so the shortlist scores the predictor itself).
 
-TPU formulation via *scalar prefetch* (the embedding-bag pattern): the
-(b, k) neighbor ids and weights and the (b,) query means are prefetched
-to SMEM, so each grid step's BlockSpec index map selects which table row
-tile to DMA — the deviation/mask tables never leave HBM except for the
-touched rows, and each gathered tile is consumed by one VMEM
-multiply-accumulate with the division/fallback/clip epilogue in-register.
+TPU formulation: a gather inside the grid step.  The (b, k) neighbor
+ids are prefetched to SMEM (scalar prefetch), and the deviation/mask
+tables stay in HBM (``memory_space=pl.ANY``).  A grid step is a group of
+``G`` query rows × one item tile: it copies each row's k neighbor slices
+of both tables into a ``(k, G, 1, tile)`` VMEM buffer with one async copy
+per slice, double-buffered across steps (step s+1's copies start before
+step s waits for its own), then loops over k in the body, accumulating
+``acc += w[kk] · row[kk]`` in f32 on ``(G, ·)`` registers, with the
+division/fallback/clip epilogue in-register.  The tables never leave HBM
+except for the touched rows.
 
 Layout: the tables are ``(U, 1, I)`` (:func:`support_rows` builds them).
-Mosaic requires the last two block dims to divide by (8, 128) or equal
-the array dims; a one-row tile of a ``(U, I)`` table satisfies neither,
-while a ``(1, bt)`` tile of a ``(U, 1, I)`` table does, with the row dim
-squeezed.  The tables are stored in that layout once per ratings array
-(a per-call reshape would copy both, ~198 MB at the ML-1M shape).  SMEM
-holds 1 MiB and pads a 2-D operand's last dim to 128 lanes, so one call
-takes at most ``BB`` query rows; larger batches loop over row blocks.
+Mosaic slices VMEM only in whole (8, 128) tiles along the last two dims,
+so a one-row copy into a ``(k, G, tile)`` buffer is refused, while a
+``(1, tile)`` slice of a ``(U, 1, I)`` table into a ``(…, 1, tile)``
+buffer is whole in both.  The tables are stored in that layout once per
+ratings array (a per-call reshape would copy both, ~198 MB at the ML-1M
+shape).  SMEM holds 1 MiB and pads a 2-D operand's last dim to 128 lanes,
+so one call takes at most ``BB`` query rows; larger batches loop over
+row blocks.
 
-Grid per row block: (b, I/bt, k) with the neighbor axis innermost (it
-carries the num/den accumulators).  Interpret mode runs on CPU and is
-validated against ``repro.kernels.ref.support_scores_ref``, which also
+Grid per row block: (⌈b/G⌉, I/tile), both sequential (the copies cross
+steps).  The tile is the widest whole number of ``bt``-tiles whose
+double-buffered rows fit ``GATHER_VMEM``.  Interpret mode runs on CPU and
+is validated against ``repro.kernels.ref.support_scores_ref``, which also
 evaluates the same tables wherever the kernel does not run.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,10 +54,15 @@ from repro.core.predict import clip_to_scale
 
 _DEN_EPS = 1e-8
 
-BT = 512            # item-tile width: 2 tables · (1, bt) f32 per step
+BT = 512            # the tables' width is whole bt-tiles
+G = 8               # query rows a grid step: (8, ·) accumulators fill the sublanes
+CW = 512            # lanes of accumulators held in registers at once
+# the double-buffered (k, G, tile) neighbor rows of both tables
+GATHER_VMEM = 12 << 20
+VMEM_LIMIT = 32 << 20
 ROWS = 2048         # rating rows laid out per step of the table build
-# query rows per pallas_call: two (BB, k) SMEM operands padded to 128
-# lanes stay at 256 KiB of the 1 MiB SMEM
+# query rows per pallas_call: the (BB, k) neighbor ids, padded to 128
+# lanes in SMEM, stay at 128 KiB of the 1 MiB SMEM
 BB = 256
 
 
@@ -91,50 +103,97 @@ def support_rows(rows: jnp.ndarray, row_means: jnp.ndarray,
     return jax.lax.fori_loop(0, -(-n // blk), body, (empty, empty))
 
 
-def _support_kernel(idx_ref, w_ref, qm_ref, dev_ref, msk_ref, out_ref,
-                    acc_num, acc_den, *, k_len: int, scale: tuple):
-    b, kk = pl.program_id(0), pl.program_id(2)
+def _tile_width(width: int, k_len: int, bt: int) -> int:
+    """The widest whole number of ``bt``-tiles that divides ``width`` and
+    whose double-buffered neighbor rows fit ``GATHER_VMEM``."""
+    per_col = 2 * 2 * G * k_len * 4          # slots · tables · rows · f32
+    n = width // bt
+    fits = [t for t in range(1, n + 1)
+            if n % t == 0 and t * bt * per_col <= GATHER_VMEM]
+    return (fits[-1] if fits else 1) * bt
 
-    @pl.when(kk == 0)
-    def _zero():
-        acc_num[...] = jnp.zeros_like(acc_num)
-        acc_den[...] = jnp.zeros_like(acc_den)
 
-    del idx_ref
-    w = w_ref[b, kk]
-    acc_num[...] += w * dev_ref[...].astype(jnp.float32)
-    acc_den[...] += w * msk_ref[...].astype(jnp.float32)
+def _support_kernel(idx_ref, w_ref, qm_ref, dev_hbm, msk_hbm, out_ref,
+                    dev_buf, msk_buf, sems, *, k_len: int, bt: int,
+                    scale: tuple):
+    n_tiles = pl.num_programs(1)
+    step = pl.program_id(0) * n_tiles + pl.program_id(1)
+    slot = step % 2
 
-    @pl.when(kk == k_len - 1)
-    def _epilogue():
-        qm = qm_ref[b]
-        num, den = acc_num[...], acc_den[...]
+    def copies(s, slot, act):
+        """Start (or wait for) the copies of grid step ``s``: for each of
+        its ``G`` query rows, its k neighbors' ``(1, bt)`` slices of both
+        tables into ``slot`` of the row buffers."""
+        g, col = s // n_tiles, (s % n_tiles) * bt
+
+        def one(n, carry):
+            r, kk = n // k_len, n % k_len
+            nb = idx_ref[g * G + r, kk]
+            for t, (hbm, buf) in enumerate(((dev_hbm, dev_buf),
+                                            (msk_hbm, msk_buf))):
+                act(pltpu.make_async_copy(
+                    hbm.at[nb, :, pl.ds(col, bt)],
+                    buf.at[slot, kk, r], sems.at[slot, t]))
+            return carry
+
+        jax.lax.fori_loop(0, G * k_len, one, 0)
+
+    @pl.when(step == 0)
+    def _first():
+        copies(step, slot, lambda c: c.start())
+
+    @pl.when(step + 1 < pl.num_programs(0) * n_tiles)
+    def _prefetch():
+        copies(step + 1, 1 - slot, lambda c: c.start())
+
+    copies(step, slot, lambda c: c.wait())
+
+    qm = qm_ref[...]
+    cw = math.gcd(bt, CW)
+    for c in range(0, bt, cw):
+        def acc(kk, nd, c=c):
+            w = w_ref[kk]
+            return (nd[0] + w * dev_buf[slot, kk, :, 0, pl.ds(c, cw)],
+                    nd[1] + w * msk_buf[slot, kk, :, 0, pl.ds(c, cw)])
+
+        zero = jnp.zeros((G, cw), jnp.float32)
+        num, den = jax.lax.fori_loop(0, k_len, acc, (zero, zero))
         pred = qm + num / jnp.maximum(den, _DEN_EPS)
         pred = jnp.where(den > _DEN_EPS, pred, qm)
-        out_ref[...] = clip_to_scale(pred, scale)
+        out_ref[:, pl.ds(c, cw)] = clip_to_scale(pred, scale)
 
 
 def _support_call(dev, msk, nb_idx, nb_w, q_means, *, scale, bt, interpret):
     b, k_len = nb_idx.shape
     width = dev.shape[2]
-    row = lambda bb, j, kk, idx_ref, w_ref, qm_ref: (idx_ref[bb, kk], 0, j)
+    tile = _tile_width(width, k_len, bt)
+    pad = -b % G
+    nb_idx = jnp.pad(nb_idx, ((0, pad), (0, 0)))
+    w_cols = jnp.pad(nb_w, ((0, pad), (0, 0))).T[:, :, None]
+    qm_col = jnp.pad(q_means, (0, pad))[:, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, width // bt, k_len),
-        in_specs=[pl.BlockSpec((None, 1, bt), row),
-                  pl.BlockSpec((None, 1, bt), row)],
-        out_specs=pl.BlockSpec(
-            (None, 1, bt), lambda bb, j, kk, *_: (bb, 0, j)),
-        scratch_shapes=[pltpu.VMEM((1, bt), jnp.float32),
-                        pltpu.VMEM((1, bt), jnp.float32)],
+        num_scalar_prefetch=1,
+        grid=((b + pad) // G, width // tile),
+        in_specs=[pl.BlockSpec((k_len, G, 1), lambda g, j, _: (0, g, 0)),
+                  pl.BlockSpec((G, 1), lambda g, j, _: (g, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((G, tile), lambda g, j, _: (g, j)),
+        scratch_shapes=[pltpu.VMEM((2, k_len, G, 1, tile), jnp.float32),
+                        pltpu.VMEM((2, k_len, G, 1, tile), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2, 2))],
     )
     out = pl.pallas_call(
-        functools.partial(_support_kernel, k_len=k_len, scale=scale),
+        functools.partial(_support_kernel, k_len=k_len, bt=tile,
+                          scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, width), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b + pad, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(nb_idx, nb_w, q_means, dev, msk)
-    return out[:, 0, :]
+    )(nb_idx, w_cols, qm_col, dev, msk)
+    return out[:b]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "bt", "interpret"))

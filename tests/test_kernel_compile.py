@@ -137,14 +137,30 @@ def test_support_kernel_compiles_for_v5e(b, spec):
 def test_support_kernel_compiles_for_v5e_at_ml10m(spec):
     """The item scorer of a served ML-10M batch (16 rows) over the
     stored (U, 1, 10,752) f32 tables (3.0 GB each): the 16 MiB temp bound
-    of the ML-1M shape holds, since the kernel streams (1, 512) tiles and
-    its output is 16 × 10,752 × 4 B = 0.7 MB — nothing scales with U·I."""
+    of the ML-1M shape holds, since the kernel copies only the neighbor
+    rows' slices of the tables into VMEM (a (40, 8, 1,536) f32 buffer per
+    table and slot) and its output is 16 × 10,752 × 4 B = 0.7 MB —
+    nothing scales with U·I."""
     w = sup.support_width(I10)
     assert w == 21 * 512
     c = _compile(functools.partial(sup.fused_support_scores,
                                    scale=(0.5, 5.0)),
                  spec((U10, 1, w)), spec((U10, 1, w)),
                  spec((16, K), jnp.int32), spec((16, K)), spec((16,)))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_support_kernel_compiles_for_v5e_at_ml10m_bulk_block(spec):
+    """A bulk block of 1,024 rows at ML-10M width, as four ``BB``-row
+    calls: the same 16 MiB temp bound, since each call gathers into VMEM
+    and the four (256, 10,752) outputs are written in place of the
+    block's (1,024, 10,752) result."""
+    w = sup.support_width(I10)
+    c = _compile(functools.partial(sup.fused_support_scores,
+                                   scale=(0.5, 5.0)),
+                 spec((U10, 1, w)), spec((U10, 1, w)),
+                 spec((1024, K), jnp.int32), spec((1024, K)), spec((1024,)))
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 16 << 20
 

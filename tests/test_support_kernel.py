@@ -67,6 +67,130 @@ def test_support_kernel_all_masked_neighbors(rng):
                                atol=1e-6)
 
 
+def _dup_ids(idx, u):
+    idx[0] = 3                         # one neighbor k times
+    idx[1, ::2] = idx[1, 1]            # repeats among distinct ids
+    return idx
+
+
+def _end_ids(idx, u):
+    idx[:, 0], idx[:, -1] = 0, u - 1
+    idx[-1] = u - 1
+    return idx
+
+
+# (b, k, n_items, edit of the neighbor ids); run at one bt-tile a grid step
+EDGE_CASES = {
+    "duplicate_ids": (6, 8, 100, _dup_ids),
+    "ids_0_and_last": (9, 5, 100, _end_ids),
+    "b1": (1, 6, 70, None),
+    "b17": (17, 7, 70, None),
+    "b260": (260, 3, 40, None),
+    "k1": (9, 1, 90, None),
+    "k40": (10, 40, 64, None),
+    "21_tiles": (9, 3, 21 * 32 - 5, None),
+}
+
+
+@pytest.mark.parametrize("case", list(EDGE_CASES))
+def test_support_kernel_edge_cases(case, rng, monkeypatch):
+    """Odd batches (across the ``BB`` split and the ``G``-row groups),
+    repeated and boundary neighbor ids, one and 40 neighbors and a
+    21-tile width, with the neighbor rows gathered one ``bt``-tile a grid
+    step, so the double-buffered copies cross tiles and row groups."""
+    from repro.kernels import support as sup
+    b, k, i, edit = EDGE_CASES[case]
+    u = 30
+    r = np.asarray(_ratings(rng, u, i))
+    means = rng.uniform(2, 4, u).astype(np.float32)
+    dev = np.where(r > 0, r - means[:, None], 0.0).astype(np.float32)
+    msk = (r > 0).astype(np.float32)
+    idx = rng.integers(0, u, (b, k)).astype(np.int32)
+    if edit is not None:
+        idx = edit(idx, u)
+    w = (rng.random((b, k)) * (rng.random((b, k)) < 0.8)).astype(np.float32)
+    qm = rng.uniform(2, 4, b).astype(np.float32)
+    scale = rating_scale(r)
+    want = ref.support_scores_ref(jnp.asarray(dev), jnp.asarray(msk),
+                                  jnp.asarray(idx), jnp.asarray(w),
+                                  jnp.asarray(qm), scale=scale)
+    width = support_width(i, 32)
+    dev_t, msk_t = support_rows(jnp.asarray(r), jnp.asarray(means), width)
+    monkeypatch.setattr(sup, "GATHER_VMEM", 0)
+    assert sup._tile_width(width, k, 32) == 32
+    fused_support_scores.clear_cache()
+    try:
+        got = fused_support_scores(dev_t, msk_t, jnp.asarray(idx),
+                                   jnp.asarray(w), jnp.asarray(qm),
+                                   scale=scale, bt=32, interpret=True)
+    finally:
+        fused_support_scores.clear_cache()
+    assert got.shape == (b, width)
+    np.testing.assert_allclose(np.asarray(got)[:, :i], np.asarray(want),
+                               atol=1e-5)
+
+
+def test_support_kernel_lane_chunks(rng, monkeypatch):
+    """A tile of three ``bt``-tiles (seven a row) accumulated in lane
+    chunks narrower than the tile."""
+    from repro.kernels import support as sup
+    b, k, u, i = 11, 3, 30, 21 * 32
+    r = np.asarray(_ratings(rng, u, i))
+    means = rng.uniform(2, 4, u).astype(np.float32)
+    dev = np.where(r > 0, r - means[:, None], 0.0).astype(np.float32)
+    msk = (r > 0).astype(np.float32)
+    idx = rng.integers(0, u, (b, k)).astype(np.int32)
+    w = (rng.random((b, k)) * (rng.random((b, k)) < 0.8)).astype(np.float32)
+    qm = rng.uniform(2, 4, b).astype(np.float32)
+    scale = rating_scale(r)
+    want = ref.support_scores_ref(jnp.asarray(dev), jnp.asarray(msk),
+                                  jnp.asarray(idx), jnp.asarray(w),
+                                  jnp.asarray(qm), scale=scale)
+    dev_t, msk_t = support_rows(jnp.asarray(r), jnp.asarray(means), i)
+    monkeypatch.setattr(sup, "GATHER_VMEM", 2 * 2 * sup.G * k * 4 * 96)
+    monkeypatch.setattr(sup, "CW", 64)
+    assert sup._tile_width(i, k, 32) == 96
+    fused_support_scores.clear_cache()
+    try:
+        got = fused_support_scores(dev_t, msk_t, jnp.asarray(idx),
+                                   jnp.asarray(w), jnp.asarray(qm),
+                                   scale=scale, bt=32, interpret=True)
+    finally:
+        fused_support_scores.clear_cache()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_support_kernel_zero_weights_clip(rng):
+    """All-zero weights over several row groups: the query mean, clipped
+    into the rating scale, in every column."""
+    b, u, i = 11, 20, 96
+    dev = rng.normal(size=(u, 1, i)).astype(np.float32)
+    msk = np.ones((u, 1, i), np.float32)
+    idx = rng.integers(0, u, (b, 5)).astype(np.int32)
+    qm = np.linspace(0.2, 6.0, b).astype(np.float32)
+    got = np.asarray(fused_support_scores(
+        jnp.asarray(dev), jnp.asarray(msk), jnp.asarray(idx),
+        jnp.zeros((b, 5), jnp.float32), jnp.asarray(qm), scale=(1.0, 5.0),
+        bt=32, interpret=True))
+    want = np.clip(qm, 1.0, 5.0)[:, None]
+    np.testing.assert_array_equal(got, np.broadcast_to(want, got.shape))
+
+
+def test_support_tile_width_fits_budget():
+    """Both deployments' rows fit as whole row groups of tiles under the
+    gather budget: the widest whole number of 512-tiles that fits."""
+    from repro.kernels import support as sup
+    for n_items in (3952, 10677):
+        width = sup.support_width(n_items)
+        tile = sup._tile_width(width, 40, sup.BT)
+        assert width % tile == 0 and tile % sup.BT == 0
+        assert 2 * 2 * sup.G * 40 * tile * 4 <= sup.GATHER_VMEM
+        wider = [t for t in range(tile + sup.BT, width + 1, sup.BT)
+                 if width % t == 0]
+        assert all(2 * 2 * sup.G * 40 * t * 4 > sup.GATHER_VMEM
+                   for t in wider)
+
+
 @pytest.mark.parametrize("b", [5, 300])
 def test_reference_evaluator_on_kernel_tables(b, rng):
     """The item stage's off-TPU scorer evaluates the kernel's own
